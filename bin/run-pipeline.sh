@@ -7,7 +7,8 @@
 #
 # Environment:
 #   KEYSTONE_DEVICES=cpu8   run on 8 virtual CPU devices (test mesh)
-#   JAX_PLATFORMS           respected as usual (defaults to the TPU runtime)
+#   JAX_PLATFORMS           obeyed when set (cpu is how to ask for the CPU);
+#                           unset means TPU, and no TPU is a non-zero exit
 set -euo pipefail
 DIR="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
 export PYTHONPATH="$DIR${PYTHONPATH:+:$PYTHONPATH}"

@@ -478,9 +478,9 @@ def _parse(argv: list[str]) -> tuple[str, dict]:
 def main(argv: list[str] | None = None) -> None:
     argv = list(sys.argv[1:] if argv is None else argv)
     state_path, args = _parse(argv)
-    from keystone_tpu.core.runtime import enable_compilation_cache
+    from keystone_tpu.core.runtime import init_backend
 
-    enable_compilation_cache()
+    init_backend()
     try:
         daemon = RefitDaemon(
             state_path,

@@ -38,7 +38,7 @@ def _open(path: str):
 def load_idx(path: str) -> np.ndarray:
     """One IDX file → ndarray with the header's shape and dtype.
 
-    Transient read errors (flaky NFS/tunnel, the ``idx.read`` fault
+    Transient read errors (flaky NFS, the ``idx.read`` fault
     site) retry under ``IO_POLICY``; a malformed file (bad magic, short
     payload) is a ValueError that passes straight through — corruption
     is not transient."""

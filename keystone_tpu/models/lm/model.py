@@ -172,7 +172,10 @@ class TransformerLM:
     blocks: tuple  # of LMBlock
     num_heads: int = static_field(default=8)
     # attention strategy: "local" (dense or Pallas flash on TPU),
-    # "ring" / "ulysses" (sequence-parallel over `seq_axis` of `mesh`)
+    # "ring" / "ulysses" (sequence-parallel over `seq_axis` of `mesh`).
+    # A "local" model whose batch or heads are split over a mesh carries
+    # that mesh too: the flash kernel is shard_mapped over it (GSPMD
+    # cannot partition a Mosaic kernel)
     seq_mode: str = static_field(default="local")
     mesh: object = static_field(default=None)
     seq_axis: str = static_field(default="data")
@@ -282,7 +285,34 @@ class TransformerLM:
                     flash_attention_trainable,
                 )
 
-                out = flash_attention_trainable(q, k, v, True)
+                def attend(q, k, v):
+                    return flash_attention_trainable(q, k, v, True)
+
+                if self.mesh is not None:
+                    # GSPMD cannot partition a Mosaic kernel ("Mosaic
+                    # kernels cannot be automatically partitioned" — the
+                    # four-chip run, PR 21): under a mesh the batch
+                    # (data axis) and the heads (model axis) are split
+                    # by hand, each device running the kernel on its
+                    # own shard. A dim an axis does not divide stays
+                    # whole on every device.
+                    from jax.sharding import PartitionSpec as P
+
+                    sizes = dict(self.mesh.shape)
+                    spec = P(
+                        "data" if n % sizes.get("data", n + 1) == 0 else None,
+                        "model" if h % sizes.get("model", h + 1) == 0 else None,
+                        None,
+                        None,
+                    )
+                    attend = jax.shard_map(
+                        attend,
+                        mesh=self.mesh,
+                        in_specs=(spec, spec, spec),
+                        out_specs=spec,
+                        check_vma=False,  # pallas_call outputs carry no vma
+                    )
+                out = attend(q, k, v)
             else:
                 out = dense_attention(q, k, v, causal=True)
         proj = model_mm(self)(

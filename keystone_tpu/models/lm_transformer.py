@@ -136,7 +136,9 @@ def run(conf: LMConfig, mesh=None) -> dict:
         depth=conf.depth,
         num_heads=conf.num_heads,
         seq_mode=conf.seq_mode,
-        mesh=mesh if conf.seq_mode != "local" else None,
+        # local attention needs the mesh too: on a TPU its Pallas flash
+        # kernel is shard_mapped over the mesh the batch is split on
+        mesh=mesh,
         compute_dtype=conf.compute_dtype,
         moe_every=conf.moe_every,
         num_experts=conf.num_experts,

@@ -56,12 +56,7 @@ def analyze(fn: Callable, *args: Any, **kwargs: Any) -> dict:
     """
     try:
         compiled = jax.jit(fn).lower(*args, **kwargs).compile()
-        cost = compiled.cost_analysis()
-        # jax returns one dict per computation on some versions, a bare
-        # dict on others; the entry computation comes first
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        cost = cost or {}
+        cost = compiled.cost_analysis() or {}
         profile: dict[str, Any] = {
             "flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
@@ -138,12 +133,9 @@ class CostProfileRegistry:
         return out
 
     def _note_devices(self) -> None:
-        try:
-            devs = jax.devices()
-            self.device_kind = devs[0].device_kind
-            self.num_devices = len(devs)
-        except Exception:  # noqa: BLE001 — backend init failure
-            pass
+        devs = jax.devices()
+        self.device_kind = devs[0].device_kind
+        self.num_devices = len(devs)
 
     def snapshot(self) -> dict:
         with self._lock:

@@ -42,14 +42,10 @@ def _device_stats(dev: Any) -> dict | None:
 def sample_device_memory() -> list[dict]:
     """One point-in-time sample: a dict per device that reports stats
     (``[]`` on backends without allocator stats)."""
-    try:
-        import jax
+    import jax
 
-        devs = jax.devices()
-    except Exception:  # noqa: BLE001 — backend init failure
-        return []
     out: list[dict] = []
-    for d in devs:
+    for d in jax.devices():
         stats = _device_stats(d)
         if not stats:
             continue

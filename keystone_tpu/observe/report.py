@@ -32,9 +32,9 @@ from keystone_tpu.plan.costs import (  # noqa: F401 — re-exports
 #: legacy aliases (pre-single-sourcing callers): bf16 peaks per chip and
 #: the v5e HBM stream rate, both views of DEVICE_PEAKS
 PEAK_FLOPS = {
-    kind: peaks[0] for kind, peaks in DEVICE_PEAKS.items() if kind != "cpu"
+    kind: peaks.flops for kind, peaks in DEVICE_PEAKS.items() if kind != "cpu"
 }
-HBM_BYTES_PER_S = DEVICE_PEAKS["v5 lite"][1]
+HBM_BYTES_PER_S = DEVICE_PEAKS["v5 lite"].hbm_bw
 
 
 def summarize(events: list[dict]) -> dict[str, Any]:

@@ -49,24 +49,24 @@ STEPS_FILE = "steps.jsonl"
 _MAX_MEMORY_STEPS = 4096
 
 _bind_lock = threading.Lock()
-_peak_cache: list = []  # [(device_kind, peak_total_flops | None)] memo
+_peak_cache: list = []  # [peak_total_flops | None] memo
 
 
 def _peak_flops_total() -> float | None:
     """Cluster-visible peak FLOP/s: per-device peak from the planner's
-    roofline table × local device count. Memoized; None when the backend
-    can't even be asked (MFU is then omitted, never wrong)."""
+    roofline table × local device count. Memoized. None on the CPU (a
+    CPU run records no MFU); an accelerator the table does not hold
+    raises rather than being priced as some other device."""
     if _peak_cache:
         return _peak_cache[0]
-    try:
-        import jax
+    import jax
 
-        from keystone_tpu.plan.costs import device_peaks
+    from keystone_tpu.plan.costs import peak_flops_for
 
-        devs = jax.devices()
-        peak = device_peaks(devs[0].device_kind)[0] * len(devs)
-    except Exception:  # noqa: BLE001 — backend init failure
-        peak = None
+    devs = jax.devices()
+    peak = peak_flops_for(devs[0].device_kind)
+    if peak is not None:
+        peak *= len(devs)
     _peak_cache.append(peak)
     return peak
 

@@ -19,9 +19,9 @@ falls back to the exact fp32 Gram and records the decision. The
 ``KEYSTONE_GRAM_OP`` env knob (``auto`` | ``fp32`` | ``int8``)
 overrides.
 
-Like ``mm_fused``, the Pallas kernel runs compiled on TPU and falls
-back to an XLA int8→int32 dot elsewhere (CPU tests, interpret mode is
-opt-in) — same numerics either way.
+Like ``mm_fused``, the Pallas kernel runs compiled on TPU; on the CPU
+:func:`ata_int8` takes the XLA int8→int32 dot instead (interpret mode
+is opt-in for tests) — same numerics either way.
 """
 
 from __future__ import annotations
@@ -36,13 +36,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from keystone_tpu.ops.quantization import symmetric_int8
-
-# jax renamed TPUCompilerParams → CompilerParams across the versions
-# this repo meets; resolve whichever this runtime has so the kernel
-# (unlike the decode-only mm_fused) stays testable on both
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
 ENV_GRAM_OP = "KEYSTONE_GRAM_OP"
 ENV_INT8_MAX_ERR = "KEYSTONE_GRAM_INT8_MAX_ERR"
@@ -147,9 +140,9 @@ def ata_int8_pallas(
     ``int8_matmul.mm_fused``. ``a``: (N, D) float; returns (D, D) f32.
     """
     if interpret is None:
-        from keystone_tpu.ops.flash_attention import on_tpu
+        from keystone_tpu.ops.flash_attention import interpret_default
 
-        interpret = not on_tpu()
+        interpret = interpret_default()
     n, d = a.shape
     q, scale = _quantize_cols(a)
     # int8 tiles are (32, 128)-granular; rows pad to the k block (zero
@@ -171,7 +164,7 @@ def ata_int8_pallas(
         # the two D-tile axes are independent; k is the sequential
         # accumulator dim — declaring it lets Mosaic pipeline the int8
         # HBM loads across steps (same contract as mm_fused)
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -181,13 +174,18 @@ def ata_int8_pallas(
 
 
 def ata_int8(a) -> jnp.ndarray:
-    """The planner-selectable int8 Gram operator: Pallas on TPU, the
-    XLA int32 dot elsewhere — identical numerics, chosen at trace time
-    (``gram_fn`` is jit-static, so each backend compiles its own
-    form)."""
+    """The planner-selectable int8 Gram operator: the Pallas kernel on a
+    one-chip TPU, the XLA int32 dot on the CPU and wherever there is
+    more than one device — identical numerics, chosen at trace time
+    (``gram_fn`` is jit-static, so each backend compiles its own form).
+    Under a mesh the chunk arrives row-sharded, and GSPMD can partition
+    the XLA dot but not a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"); until the kernel is shard_mapped over
+    the data axis (per-shard qᵀq, then psum) many devices take the XLA
+    form."""
     from keystone_tpu.ops.flash_attention import on_tpu
 
-    if on_tpu():
+    if on_tpu() and jax.device_count() == 1:
         return ata_int8_pallas(a)
     return ata_int8_xla(a)
 

@@ -11,7 +11,7 @@ the accumulator.
 Decode shapes are tall-K, tiny-M (B·1 activations against (K, N)
 weights), so the kernel grids over N with K streamed sequentially per
 tile and the f32 accumulator carried in VMEM scratch. Runs compiled on
-TPU and in Pallas interpret mode elsewhere (CPU tests).
+TPU and in Pallas interpret mode on the CPU (tests).
 
 The serving entry point stays :func:`keystone_tpu.ops.quantization.mm`;
 ``mm_fused`` here is the measured alternative — ``tools/mfu_sweep.py``
@@ -89,9 +89,9 @@ def mm_fused(
     y: (..., K) float; w.q: (K, N) int8 with (1, N) f32 scales. Returns
     (..., N) in y's dtype (f32 accumulation, like ``mm``)."""
     if interpret is None:
-        from keystone_tpu.ops.flash_attention import on_tpu
+        from keystone_tpu.ops.flash_attention import interpret_default
 
-        interpret = not on_tpu()
+        interpret = interpret_default()
     if w.scale.shape != (1, w.q.shape[1]):
         raise ValueError(
             f"mm_fused needs (1, N) per-output-channel scales; got "

@@ -63,7 +63,7 @@ def initialize(
     bounds the join: a missing peer or dead coordinator fails in
     seconds with the coordinator address in the message instead of
     hanging the launch forever — on a preempted slice rejoin, the
-    hang IS the failure mode (see tunnel_watch.log). Non-coordinator
+    hang IS the failure mode. Non-coordinator
     processes preflight the coordinator's TCP port under this timeout
     (a clean, catchable RuntimeError names the address); the in-barrier
     wait is then bounded by jax's own ``initialization_timeout``, whose
@@ -135,7 +135,7 @@ def initialize(
         jax.device_count(),
     )
     # every multihost worker start warm-starts from the persistent XLA
-    # cache (KEYSTONE_COMPILE_CACHE_DIR): a relaunched/rejoining host's
+    # cache (core/runtime.py): a relaunched/rejoining host's
     # cold-start cost is compilation, and the supervisor's whole loss
     # budget assumes rejoin takes seconds, not minutes
     from keystone_tpu.core.runtime import enable_compilation_cache

@@ -100,11 +100,8 @@ def default_budget_bytes() -> int:
     return _DEFAULT_BUDGET_BYTES
 
 
-def _device_kind() -> str | None:
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — backend init failure
-        return None
+def _device_kind() -> str:
+    return jax.devices()[0].device_kind
 
 
 def plan_pipeline(

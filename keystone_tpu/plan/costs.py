@@ -20,7 +20,7 @@ prices a 1M-row execution.
 from __future__ import annotations
 
 import time
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import numpy as np
@@ -28,72 +28,77 @@ import numpy as np
 from keystone_tpu.observe import cost as _cost
 from keystone_tpu.plan.ir import NodeCost, PlanNode
 
+class DevicePeaks(NamedTuple):
+    """One device kind's published peaks, per chip."""
+
+    flops: float  # bf16 MXU FLOP/s
+    hbm_bw: float  # HBM bytes/s
+    h2d_bw: float  # host→device bytes/s over PCIe
+    ici_bw: float  # collective bytes/s over ICI
+    int8_ops: float  # int8 MXU OP/s
+    # Mosaic scoped-VMEM limit the Pallas kernels request; None keeps
+    # the compiler's default (16 MiB)
+    vmem_limit: int | None = None
+
+
 # Roofline peaks per device kind — THE single home (``observe/report.py``
 # and ``plan/ir.py`` re-export from here, so the report's vs_peak column
 # and the planner's recompute/transfer estimates can never quote
-# different chips): (bf16 MXU peak FLOP/s, HBM bytes/s, host→device
-# bytes/s over PCIe, collective bytes/s over ICI), keyed by a
-# ``device_kind`` substring. Basis: ROOFLINE.md (one v5e chip ≈ 197 TF/s
-# bf16, HBM ≈ 819 GB/s; the f32 MXU rate is lower, so f32 workloads
-# report conservative MFU). The "cpu" row is a coarse fallback: the
-# planner only compares relative magnitudes there, and the report shows
-# ``-`` for vs_peak (``peak_flops_for`` returns None off-TPU).
-DEVICE_PEAKS: dict[str, tuple[float, float, float, float]] = {
-    "cpu": (5e10, 2e10, 2e10, 2e10),
-    "v4": (2.75e14, 1.2e12, 3.2e10, 3e11),
-    "v5 lite": (1.97e14, 8.19e11, 3.2e10, 1.6e11),
-    "v5e": (1.97e14, 8.19e11, 3.2e10, 1.6e11),
-    "v5p": (4.59e14, 2.76e12, 3.2e10, 4.8e11),
+# different chips), keyed by a ``device_kind`` substring. Source of the
+# TPU rows: Google Cloud TPU documentation, system-architecture pages
+# ("TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s HBM, 1,600
+# Gbit/s ICI; "TPU v4": 275 TFLOP/s bf16 or int8, 1,200 GB/s; "TPU v5p":
+# 459 TFLOP/s bf16, 918 TOP/s int8, 2,765 GB/s). The one-chip v5e
+# reports ``device_kind`` "TPU v5 lite" (chip run, PR 21); only that row
+# has been checked against hardware. The f32 MXU rate is lower, so f32
+# workloads report conservative MFU. The "cpu" row is coarse: the
+# planner only compares relative magnitudes there, and nothing prints a
+# utilization against it (``peak_flops_for`` returns None off-TPU). A
+# device that is not in the table is an error, never priced as a CPU.
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "cpu": DevicePeaks(5e10, 2e10, 2e10, 2e10, 5e10),
+    "v4": DevicePeaks(2.75e14, 1.2e12, 3.2e10, 3e11, 2.75e14),
+    "v5 lite": DevicePeaks(
+        1.97e14, 8.19e11, 3.2e10, 2e11, 3.93e14, vmem_limit=96 << 20
+    ),
+    "v5p": DevicePeaks(4.59e14, 2.765e12, 3.2e10, 4.8e11, 9.18e14),
 }
 
 
-def device_peaks(
-    device_kind: str | None,
-) -> tuple[float, float, float, float]:
-    """The peak tuple for a jax ``device_kind`` string (substring match,
-    case-insensitive); unknown kinds fall back to the coarse "cpu" row."""
-    if device_kind:
-        kind = device_kind.lower()
-        for key, peaks in DEVICE_PEAKS.items():
-            if key in kind:
-                return peaks
-    return DEVICE_PEAKS["cpu"]
+def device_peaks(device_kind: str | None = None) -> DevicePeaks:
+    """The peaks row for a jax ``device_kind`` string (substring match,
+    case-insensitive); ``None`` means the device this process runs on.
+    Raises for a kind the table does not hold — add its row with the
+    source of the figures."""
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    kind = device_kind.lower()
+    for key, peaks in DEVICE_PEAKS.items():
+        if key in kind:
+            return peaks
+    raise ValueError(
+        f"device kind {device_kind!r} is not in "
+        "keystone_tpu.plan.costs.DEVICE_PEAKS; add a row with its "
+        "published peaks and their source"
+    )
 
 
-# int8 MXU rate relative to bf16, per device kind — the Gram-operator
-# selection's cost basis (plan/fused_fit.py). TPU int8 passes run ~2×
-# the bf16 rate; CPUs (and unknown chips) get 1.0, so the planner never
-# chooses the quantized Gram where it can't win.
-INT8_GRAM_SPEEDUP: dict[str, float] = {
-    "cpu": 1.0,
-    "v4": 2.0,
-    "v5 lite": 2.0,
-    "v5e": 2.0,
-    "v5p": 2.0,
-}
-
-
-def int8_gram_speedup(device_kind: str | None) -> float:
-    """int8-vs-bf16 rate for a ``device_kind`` (substring match,
-    case-insensitive); unknown kinds report 1.0 (no advantage)."""
-    if device_kind:
-        kind = device_kind.lower()
-        for key, speedup in INT8_GRAM_SPEEDUP.items():
-            if key in kind:
-                return speedup
-    return 1.0
+def int8_gram_speedup(device_kind: str | None = None) -> float:
+    """int8-vs-bf16 MXU rate for a ``device_kind`` — the Gram-operator
+    selection's cost basis (plan/fused_fit.py): 2× on v5e/v5p, 1× on v4
+    and the CPU, so the planner never chooses the quantized Gram where
+    it cannot win."""
+    peaks = device_peaks(device_kind)
+    return peaks.int8_ops / peaks.flops
 
 
 def peak_flops_for(device_kind: str | None) -> float | None:
-    """bf16 peak FLOP/s for a known accelerator ``device_kind``, or None
-    (CPU, new chip generations) — the report's roofline basis."""
-    if not device_kind:
+    """bf16 peak FLOP/s for an accelerator ``device_kind``, or None for
+    the CPU and for a run that recorded no kind — the report's roofline
+    basis."""
+    if not device_kind or "cpu" in device_kind.lower():
         return None
-    kind = device_kind.lower()
-    for key, peaks in DEVICE_PEAKS.items():
-        if key != "cpu" and key in kind:
-            return peaks[0]
-    return None
+    return device_peaks(device_kind).flops
 
 
 def _rows(batch: Any) -> int:

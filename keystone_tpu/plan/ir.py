@@ -29,9 +29,7 @@ from keystone_tpu.observe import events as _events
 # ``costs`` imports this module at module level, so the hop back is
 # function-local; the module ``__getattr__`` below keeps the historical
 # ``plan.ir.DEVICE_PEAKS`` / ``plan.ir.device_peaks`` names importable.
-def _device_peaks(
-    device_kind: str | None,
-) -> tuple[float, float, float, float]:
+def _device_peaks(device_kind: str | None):
     from keystone_tpu.plan.costs import device_peaks
 
     return device_peaks(device_kind)
@@ -78,26 +76,26 @@ class NodeCost:
         """Estimated seconds to (re)compute this node over ``rows`` rows."""
         if self.wall_s is not None:
             return self.wall_s * rows
-        peak_flops, peak_bw, _, _ = _device_peaks(device_kind)
+        peaks = _device_peaks(device_kind)
         return max(
-            self.flops * rows / peak_flops,
-            self.bytes_accessed * rows / peak_bw,
+            self.flops * rows / peaks.flops,
+            self.bytes_accessed * rows / peaks.hbm_bw,
         )
 
     def h2d_s(self, rows: float, device_kind: str | None = None) -> float:
         """Estimated seconds to move this node's input host→device
         (PCIe) for ``rows`` rows — the staging transfer the executor
         tries to hide behind compute."""
-        _, _, h2d_bw, _ = _device_peaks(device_kind)
-        return self.input_bytes * rows / h2d_bw
+        return self.input_bytes * rows / _device_peaks(device_kind).h2d_bw
 
     def collective_s(
         self, rows: float, device_kind: str | None = None
     ) -> float:
         """Estimated seconds this node spends in cross-shard collectives
         (ICI psum) when executed sharded over ``rows`` rows."""
-        _, _, _, ici_bw = _device_peaks(device_kind)
-        return self.collective_bytes * rows / ici_bw
+        return (
+            self.collective_bytes * rows / _device_peaks(device_kind).ici_bw
+        )
 
 
 @dataclasses.dataclass

@@ -1,7 +1,7 @@
 """Hang watchdogs: a step-time monitor thread for training loops and
 the diagnostics it prints when a step stops completing.
 
-A hung ``jax.distributed.initialize`` or a wedged device tunnel doesn't
+A hung ``jax.distributed.initialize`` or a wedged device doesn't
 raise — it just stops. The watchdog turns "stops" into evidence: when
 no :meth:`Watchdog.pet` arrives within ``timeout_s``, it logs a WARNING
 with every thread's current stack, emits a ``resilience`` event, bumps

@@ -8,8 +8,8 @@ substrate the earlier subsystems laid down:
 - :mod:`.export` — a fitted pipeline or LM as an **AOT-compiled**
   apply: plan-optimized (``plan/`` operator selection), lowered and
   compiled per batch *bucket* ahead of traffic, warm-started from the
-  persistent compilation cache (``KEYSTONE_COMPILE_CACHE_DIR``) so a
-  server cold-starts in seconds, not minutes.
+  persistent compilation cache (``core/runtime.py``) so a server
+  cold-starts in seconds, not minutes.
 - :mod:`.queue` — an async request queue with **SLO-aware
   micro-batching**: requests coalesce up to a latency deadline
   (``KEYSTONE_SERVE_DEADLINE_MS``), pad to the nearest compiled bucket,

@@ -422,8 +422,8 @@ class DecodeLoop:
     def warm(self) -> float:
         """Compile every program the loop can need — the pooled step,
         each prefill bucket, the slot merge, the first-token pick —
-        before traffic arrives. With ``KEYSTONE_COMPILE_CACHE_DIR`` set
-        the executables come back from the persistent cache, so a
+        before traffic arrives. The executables come back from the
+        persistent compilation cache (``core/runtime.py``), so a
         relaunched server warms in seconds. Returns wall seconds."""
         t0 = time.perf_counter()
         reg = _metrics.get_registry()

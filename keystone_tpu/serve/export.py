@@ -10,8 +10,8 @@ fixes both:
   of batch *buckets* (``jit(...).lower().compile()``); requests pad to
   the nearest bucket, so every request size maps to an existing
   executable,
-- the persistent compilation cache (``KEYSTONE_COMPILE_CACHE_DIR``,
-  :func:`keystone_tpu.core.runtime.enable_compilation_cache`) backs the
+- the persistent compilation cache
+  (:func:`keystone_tpu.core.runtime.enable_compilation_cache`) backs the
   build: a relaunched server reloads executables in seconds instead of
   recompiling for minutes — the elastic-rejoin fix doing double duty as
   the serving cold-start fix.
@@ -45,10 +45,10 @@ class ExportedApply:
     ``__call__`` pads a (n, ...) batch up to the smallest compiled
     bucket, runs the stored executable, and trims back to n rows; a
     batch larger than the biggest bucket streams through it in
-    bucket-size chunks. Any shape/placement the AOT executable refuses
-    falls back to the shared ``jit_apply`` path (counted — the serving
-    panel shows ``serve_aot_fallback`` if it ever happens in steady
-    state).
+    bucket-size chunks. An input the AOT executable refuses is an
+    error (the server answers 500 and logs it): a quiet fall-through to
+    the jit path would recompile per request and hide a placement or
+    layout bug on the device.
     """
 
     def __init__(
@@ -58,7 +58,6 @@ class ExportedApply:
         *,
         buckets: Sequence[int] | None = None,
         optimize: bool = True,
-        compile_now: bool = True,
     ):
         sample = np.asarray(sample)
         if sample.ndim < 1 or sample.shape[0] < 1:
@@ -81,8 +80,7 @@ class ExportedApply:
         self.pipe = pipe
         self._compiled: dict[int, Any] = {}
         self.cold_start_s = 0.0
-        if compile_now:
-            self.compile()
+        self.compile()
 
     def compile(self) -> float:
         """Lower + compile one executable per bucket (idempotent).
@@ -117,19 +115,8 @@ class ExportedApply:
 
     def _run_bucket(self, batch) -> Any:
         """Dispatch one exactly-bucket-sized batch through its AOT
-        executable (fallback: the shared jit cache)."""
-        b = batch.shape[0]
-        compiled = self._compiled.get(b)
-        if compiled is not None:
-            try:
-                return compiled(self.pipe, batch)
-            except Exception as e:  # noqa: BLE001 — placement/layout
-                # refusals from the AOT path must degrade, not 500
-                _metrics.get_registry().counter("serve_aot_fallback").inc()
-                logger.warning(
-                    "AOT executable refused bucket %d (%r); jit fallback", b, e
-                )
-        return jit_apply(self.pipe, batch)
+        executable."""
+        return self._compiled[batch.shape[0]](self.pipe, batch)
 
     def __call__(self, rows) -> Any:
         """(n, ...) rows → row-indexed outputs, any n >= 1."""

@@ -1362,16 +1362,25 @@ def main(argv: list[str] | None = None) -> None:
         else:
             raise SystemExit(f"unknown option {a!r}\n{USAGE}")
     n = int(args.get("replicas", replicas_from_env()))
+    # replicas inherit this environment: the platform the launcher
+    # selected, and the compile cache every incarnation shares (the
+    # variable if set, else the in-checkout directory each replica
+    # resolves for itself — core/runtime.py), so a relaunch is warm
     env = dict(os.environ)
-    # replica cold start is seconds only when every incarnation shares
-    # one persistent compile cache — give the fleet one if the operator
-    # didn't (same knob enable_compilation_cache honors)
-    env.setdefault(
-        "KEYSTONE_COMPILE_CACHE_DIR",
-        os.path.join(
-            os.environ.get("TMPDIR", "/tmp"), "keystone-fleet-cache"
-        ),
-    )
+    if n > 1 and env.get("JAX_PLATFORMS", "").split(",")[0] != "cpu":
+        # a chip belongs to one process and every replica is a process
+        # that takes the default device: replica 2 of a one-chip machine
+        # cannot have the chip, and on a larger host all N would contend
+        # for chip 0. Refuse, don't balance a live replica against N-1
+        # crash-looping ones.
+        raise SystemExit(
+            f"fleet: --replicas {n} needs one accelerator per replica "
+            "process, and this router assigns none (a chip belongs to "
+            "one process). Run --replicas 1 on the chip, or ask for the "
+            "CPU with JAX_PLATFORMS=cpu for a routing/failover drill. "
+            "Replicas as devices of ONE process is the ROADMAP item "
+            "that lifts this."
+        )
     cmd = [
         sys.executable, "-m", "keystone_tpu", "serve", target,
         "--port", "{port}", *passthrough,

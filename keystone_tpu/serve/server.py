@@ -294,8 +294,18 @@ class ServeApp:
         snap = reg.snapshot()
         t = snap.get("serve_request_seconds") or {}
         th = snap.get("serve_http_seconds") or {}
+        import jax
+
+        # the backend is up by the time an app exists (the model is
+        # compiled); jax.devices() is a cached lookup
+        devs = jax.devices()
         out = {
             "status": "draining" if self._stop.is_set() else "ok",
+            # what this replica computes on, as jax reports it — a
+            # router or smoke test checks the device from outside
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
             # explicit boolean the fleet router keys routing off: set the
             # MOMENT SIGTERM drain begins (before the batcher drains, long
             # before the socket closes) so an upstream router stops
@@ -810,9 +820,9 @@ def build_app(target: str, args: dict) -> ServeApp:
 def main(argv: list[str] | None = None) -> None:
     argv = list(sys.argv[1:] if argv is None else argv)
     target, args = _parse(argv)
-    from keystone_tpu.core.runtime import enable_compilation_cache
+    from keystone_tpu.core.runtime import init_backend
 
-    enable_compilation_cache()
+    init_backend()
     t0 = time.perf_counter()
     app = build_app(target, args)
     cold = time.perf_counter() - t0

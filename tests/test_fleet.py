@@ -766,7 +766,6 @@ def mnist_fleet(tmp_path_factory):
         **os.environ,
         "JAX_PLATFORMS": "cpu",
         "KEYSTONE_OBSERVE_DIR": str(obs),
-        "KEYSTONE_COMPILE_CACHE_DIR": str(base / "cache"),
         "KEYSTONE_SERVE_DEADLINE_MS": "5",
     }
     fleet = Fleet(

@@ -746,3 +746,30 @@ def test_local_attn_env_knob_selects_path(monkeypatch):
     monkeypatch.setenv("KST_LOCAL_ATTN", "fused")
     with pytest.raises(ValueError, match="KST_LOCAL_ATTN"):
         model(toks)
+
+
+def test_local_flash_is_shard_mapped_under_a_mesh(monkeypatch, mesh4x2):
+    """On a multi-chip TPU GSPMD refuses to partition the Mosaic flash
+    kernel ("cannot be automatically partitioned" — the four-chip run,
+    PR 21), so a local-mode model that carries a mesh shard_maps the
+    kernel over it: batch over ``data``, heads over ``model``. Same
+    logits as the unsharded model, and a batch the data axis does not
+    divide still runs (whole on every device)."""
+    from keystone_tpu.parallel.mesh import data_sharding
+
+    monkeypatch.setenv("KST_LOCAL_ATTN", "flash")  # interpret mode here
+    kw = dict(vocab=31, max_seq=16, dim=16, depth=1, num_heads=2)
+    plain = lm.TransformerLM.create(jax.random.key(0), **kw)
+    meshed = lm.shard_params(
+        lm.TransformerLM.create(jax.random.key(0), mesh=mesh4x2, **kw),
+        mesh4x2,
+    )
+    toks = jnp.asarray(np.random.default_rng(0).integers(0, 31, size=(4, 16)))
+    want = np.asarray(plain(toks))
+    got = jax.jit(lambda m, t: m(t))(
+        meshed, jax.device_put(toks, data_sharding(mesh4x2, ndim=2))
+    )
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(meshed(toks[:3])), want[:3], atol=2e-5
+    )

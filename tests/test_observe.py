@@ -446,7 +446,7 @@ def test_steplog_writes_steps_jsonl_and_derives_rates(tmp_path):
     assert recs[0]["loss"] == 2.5
     assert recs[0]["tokens_per_s"] == pytest.approx(2000.0)
     assert recs[0]["tflops_per_s"] == pytest.approx(2e-3)
-    assert recs[0]["mfu"] > 0  # priced off plan.costs.DEVICE_PEAKS
+    assert "mfu" not in recs[0]  # a CPU run records no utilization
     assert recs[1]["hbm_peak_bytes"] == 123456
     assert all(r["run"] == recs[0]["run"] for r in recs)
     # the stream also feeds the metrics registry for dashboards
@@ -496,8 +496,8 @@ def test_lm_train_emits_step_telemetry(tmp_path):
     assert [r["step"] for r in recs] == [1, 2, 3]
     assert [r["loss"] for r in recs] == pytest.approx(losses)
     assert all(
-        r["tokens"] == 64 and r["tokens_per_s"] > 0 and r["mfu"] > 0
-        and r["wall_s"] > 0
+        r["tokens"] == 64 and r["tokens_per_s"] > 0 and r["wall_s"] > 0
+        and "mfu" not in r  # a CPU run records no utilization
         for r in recs
     )
 

@@ -782,8 +782,11 @@ def test_node_cost_comms_terms():
     assert cost.collective_s(1000) == pytest.approx(10.0 * 1000 / 2e10)
     from keystone_tpu.plan.ir import device_peaks
 
-    assert len(device_peaks("TPU v4")) == 4
-    assert len(device_peaks(None)) == 4
+    # no kind given means the device this process runs on (the CPU here)
+    assert device_peaks(None) == device_peaks("cpu")
+    assert device_peaks("TPU v5 lite").int8_ops == pytest.approx(3.93e14)
+    with pytest.raises(ValueError, match="DEVICE_PEAKS"):
+        device_peaks("TPU v9")
 
 
 # ---------------------------------------------------------------------------

@@ -139,7 +139,7 @@ def test_transient_classifier():
     # neither does a typo'd path: the user needs the real error, fast
     assert not is_transient(FileNotFoundError("no such file"))
     assert not is_transient(PermissionError("denied"))
-    assert is_transient(RuntimeError("UNAVAILABLE: tunnel dropped"))
+    assert is_transient(RuntimeError("UNAVAILABLE: connection dropped"))
     assert is_transient(RuntimeError("DEADLINE_EXCEEDED: barrier"))
     assert not is_transient(RuntimeError("RESOURCE_EXHAUSTED: OOM"))
     assert not is_transient(ValueError("shape mismatch"))
@@ -537,7 +537,7 @@ def test_accelerator_drop_injected_into_chained_fit(rng):
     with pytest.raises(AcceleratorDrop, match="UNAVAILABLE"):
         est.fit(a, y)
     # the injected error reads as transient to the retry classifier,
-    # exactly like a real dead-tunnel XlaRuntimeError
+    # exactly like a real lost-device XlaRuntimeError
     faults.configure("accel.fit:@0:0")
     try:
         est.fit(a, y)

@@ -733,7 +733,8 @@ def test_bench_check_passes_and_fails(tmp_path):
 def test_bench_check_missing_file_exits_2(tmp_path):
     bench = _load_bench()
 
-    assert bench.main(["--check", str(tmp_path / "nope.json")]) == 2
+    nope = str(tmp_path / "nope.json")
+    assert bench.main(["--check", nope, "--against", nope]) == 2
 
 
 @pytest.mark.slow

@@ -10,7 +10,8 @@ configuration (the workload whose S² term the blocks govern —
 bench.bench_lm_longctx's shape) and writes FLASH_SWEEP.json with
 tokens/s per config and the winner.
 
-Run ON CHIP (no JAX_PLATFORMS pin). ~1-2 min/config, default grid 6.
+Run ON CHIP, in one call of the chip tool (the parent stays off jax so
+each child owns the chip; unset JAX_PLATFORMS means TPU). ~1-2 min/config, default grid 6.
 """
 
 from __future__ import annotations
@@ -40,6 +41,8 @@ _CHILD = r"""
 import sys, json
 sys.path.insert(0, {repo!r})
 import bench
+from keystone_tpu.core.runtime import init_backend
+init_backend()  # the platform rule: unset JAX_PLATFORMS means TPU
 r = bench._lm_train_step_rate(
     seq=bench.LM_LONG_SEQ, dim=bench.LM_LONG_DIM,
     depth=bench.LM_LONG_DEPTH, heads=8, batch=1, pos_encoding="rope",

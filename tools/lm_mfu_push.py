@@ -13,7 +13,8 @@ which bench.bench_lm_train picks up automatically, so the chip
 session's closing bench.py run records the tuned number without a
 human in the loop.
 
-Run ON CHIP (no JAX_PLATFORMS pin). ~1-3 min/config, grid of 9.
+Run ON CHIP, in one call of the chip tool (the parent stays off jax so
+each child owns the chip; unset JAX_PLATFORMS means TPU). ~1-3 min/config, grid of 9.
 """
 
 from __future__ import annotations
@@ -50,6 +51,8 @@ _CHILD = r"""
 import sys, json
 sys.path.insert(0, {repo!r})
 import bench
+from keystone_tpu.core.runtime import init_backend
+init_backend()  # the platform rule: unset JAX_PLATFORMS means TPU
 r = bench._lm_train_step_rate(
     seq=bench.LM_SEQ, dim=bench.LM_DIM, depth=bench.LM_DEPTH,
     heads=bench.LM_HEADS, batch={batch}, iters=3,

@@ -34,6 +34,8 @@ _CHILD = r"""
 import sys, json
 sys.path.insert(0, {repo!r})
 import bench
+from keystone_tpu.core.runtime import init_backend
+init_backend()  # the platform rule: unset JAX_PLATFORMS means TPU
 r = bench._lm_train_step_rate(
     seq=bench.LM_SEQ, dim=bench.LM_DIM, depth=bench.LM_DEPTH,
     heads=bench.LM_HEADS, batch={batch}, iters=3,

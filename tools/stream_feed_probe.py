@@ -29,6 +29,9 @@ import numpy as np
 def main() -> None:
     import jax
 
+    from keystone_tpu.core.runtime import init_backend
+
+    init_backend()
     dev = jax.devices()[0]
     import jax.numpy as jnp
 
