@@ -106,7 +106,11 @@ def main(argv: list[str] | None = None) -> None:
             f" event log there, rendered by `observe <dir>`, tailed live by\n"
             f" `observe top <dir>` (a base dir tails EVERY run dir — the\n"
             f" fleet view), and compared across runs by\n"
-            f" `observe diff <dirA> <dirB>`; `observe collect <out>` runs\n"
+            f" `observe diff <dirA> <dirB>`; --profile DIR writes a\n"
+            f" jax.profiler trace of the run there, and `observe idle DIR`\n"
+            f" puts each idle gap of the device down to the host span that\n"
+            f" was open over it (spans are recorded under either --observe\n"
+            f" or --profile); `observe collect <out>` runs\n"
             f" the fleet collector (scrapes every /metrics, tails run dirs,\n"
             f" evaluates SLO burn rates), `observe slo <out>` renders its\n"
             f" verdicts + exemplars, and `observe serve <out> --port N` is\n"

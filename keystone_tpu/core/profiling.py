@@ -4,9 +4,11 @@ The reference's observability is Spark's UI plus wall-clock brackets and
 ``RDD.setName`` tags (SURVEY.md §5). Here the same two ideas map to:
 
 - :func:`trace` — capture an XLA/TPU profile (tensorboard-viewable) around
-  a code block (``jax.profiler``),
-- :func:`annotate` — name a region so it shows up in the trace timeline
-  (the ``setName`` analog),
+  a code block (``jax.profiler``); ``python -m keystone_tpu observe idle
+  <dir>`` reduces it to device idle time by host span,
+- ``keystone_tpu.observe.spans.span`` — name a host region: while a
+  profile is being captured it shows up in the trace timeline under its
+  name (the ``setName`` analog),
 - :func:`log_time` (re-exported from core.logging) — wall-clock brackets.
 
 ``KEYSTONE_TRACE_DIR`` gates :func:`trace`: unset, the explicit
@@ -74,8 +76,3 @@ def trace(log_dir: str | None = None):
                 logger.info("profile written to %s", log_dir)
             except Exception as e:  # noqa: BLE001
                 logger.warning("profiler stop_trace failed: %r", e)
-
-
-def annotate(name: str):
-    """Named region in profiler timelines (the RDD.setName analog)."""
-    return jax.profiler.TraceAnnotation(name)

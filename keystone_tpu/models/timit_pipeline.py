@@ -20,6 +20,7 @@ from keystone_tpu.core.treenode import treenode
 from keystone_tpu.evaluation import MulticlassClassifierEvaluator
 from keystone_tpu.loaders.labeled import LabeledData
 from keystone_tpu.loaders.timit import NUM_CLASSES, TIMIT_DIMENSION, load_timit_split
+from keystone_tpu.observe.spans import force, span
 from keystone_tpu.ops.linear import BlockLeastSquaresEstimator
 from keystone_tpu.ops.stats import CosineRandomFeatures, StandardScaler
 from keystone_tpu.ops.util import ClassLabelIndicators, MaxClassifier
@@ -101,26 +102,52 @@ def _load(conf: TimitConfig, which: str) -> LabeledData:
 
 
 def run(conf: TimitConfig, mesh=None) -> dict:
+    """One fit. While spans are on (``--observe`` or ``--profile``; see
+    ``observe/spans.py``) the call is one ``fit`` root span with a child
+    per layer, and its three phase boundaries (``featurize_s``,
+    ``fit_s``, the end) wait for their device work, so those keys are
+    sound then; with spans off nothing is forced that was not before and
+    ``featurize_s`` is the time to enqueue."""
     if mesh is None and len(jax.devices()) > 1:
         mesh = create_mesh()
+    with span(
+        "fit",
+        parent=None,
+        blocks=conf.num_cosines,
+        epochs=conf.num_epochs,
+        chips=mesh.size if mesh is not None else 1,
+    ):
+        return _fit(conf, mesh)
+
+
+def _fit(conf: TimitConfig, mesh) -> dict:
     t0 = time.perf_counter()
-    train, test = _load(conf, "train"), _load(conf, "test")
+    with span("fit.load", bucket="wait_host"):
+        train, test = _load(conf, "train"), _load(conf, "test")
     n_train, n_test = len(train), len(test)
 
-    keys = jax.random.split(jax.random.key(conf.seed), conf.num_cosines)
-    featurizers = [
-        CosineRandomFeatures.create(
-            TIMIT_DIMENSION,
-            conf.cosine_features,
-            keys[i],
-            gamma=conf.gamma,
-            distribution=conf.rf_type,
-        )
-        for i in range(conf.num_cosines)
-    ]
+    with span("fit.featurize_init"):
+        keys = jax.random.split(jax.random.key(conf.seed), conf.num_cosines)
+        featurizers = [
+            CosineRandomFeatures.create(
+                TIMIT_DIMENSION,
+                conf.cosine_features,
+                keys[i],
+                gamma=conf.gamma,
+                distribution=conf.rf_type,
+            )
+            for i in range(conf.num_cosines)
+        ]
 
-    x_train = shard_batch(train.data, mesh)
-    x_test = shard_batch(test.data, mesh)
+    with span(
+        "fit.h2d",
+        bucket="wait_host",
+        rows=n_train,
+        bytes=train.data.nbytes + test.data.nbytes,
+    ):
+        x_train = shard_batch(train.data, mesh)
+        x_test = shard_batch(test.data, mesh)
+        force((x_train, x_test))
 
     from keystone_tpu import plan as plan_mod
 
@@ -135,97 +162,125 @@ def run(conf: TimitConfig, mesh=None) -> dict:
     streamed_fit = plan_mod.enabled() and not (
         conf.lam_sweep or conf.checkpoint_dir
     )
-    apply_node = jax.jit(lambda node, b: node(b))
+
+    # re-made (so traced and compiled again) in every call of run(); the
+    # names are what the device programs are called in a profile
+    @jax.jit
+    def cosine_features(node, b):
+        return node(b)
+
+    @jax.jit
+    def standard_scale(node, b):
+        return node(b)
+
     # per-batch cosine features, standard-scaled (fit on train)
     train_blocks, scalers = [], []
-    for f in featurizers:
-        raw = apply_node(f, x_train)
-        scaler = StandardScaler().fit(raw, n_valid=n_train)
-        scalers.append(scaler)
-        if not streamed_fit:
-            train_blocks.append(apply_node(scaler, raw))
-        del raw
+    for i, f in enumerate(featurizers):
+        with span("fit.featurize", bank=i):
+            with span("featurize.cosine"):
+                raw = cosine_features(f, x_train)
+            with span("featurize.scale_fit"):
+                scaler = StandardScaler().fit(raw, n_valid=n_train)
+            scalers.append(scaler)
+            if not streamed_fit:
+                with span("featurize.scale_apply"):
+                    train_blocks.append(standard_scale(scaler, raw))
+            del raw
 
-    y = np.zeros(x_train.shape[0], np.int32)
-    y[:n_train] = train.labels
-    indicators = ClassLabelIndicators(num_classes=NUM_CLASSES)(y)
+    with span("fit.labels"):
+        y = np.zeros(x_train.shape[0], np.int32)
+        y[:n_train] = train.labels
+        indicators = ClassLabelIndicators(num_classes=NUM_CLASSES)(y)
+    # the featurize phase ends when its device work has: only a recorded
+    # fit waits here (the bank spans above time the enqueue alone)
+    with span("fit.featurize_wait", bucket="wait_device"):
+        force((train_blocks, scalers, indicators))
     t_feat = time.perf_counter()
 
-    lam = conf.lam
-    if conf.lam_sweep:
-        from keystone_tpu.evaluation.model_selection import (
-            holdout_lambda_sweep,
-        )
+    with span("fit.solve", bucket="compute"):
+        lam = conf.lam
+        if conf.lam_sweep:
+            from keystone_tpu.evaluation.model_selection import (
+                holdout_lambda_sweep,
+            )
 
-        # selection at one BCD pass (like MNIST): cheap relative to the
-        # final multi-epoch fit, and the final fit stays under the
-        # --checkpoint-dir preemption protection
-        report = holdout_lambda_sweep(
-            BlockLeastSquaresEstimator(
-                block_size=conf.cosine_features, num_iter=1
-            ),
-            train_blocks,
-            indicators,
-            y,
-            conf.lam_sweep,
-            n_train=n_train,
-            num_classes=NUM_CLASSES,
-        )
-        lam = report["best_lam"]
-        logger.info(
-            "lambda sweep %s -> val errors %s; refitting at best lam=%g",
-            report["lams"],
-            [round(e, 4) for e in report["val_errors"]],
-            lam,
-        )
-    est = BlockLeastSquaresEstimator(
-        block_size=conf.cosine_features, num_iter=conf.num_epochs, lam=lam
-    )
-    bank = ScaledCosineBank(
-        chains=tuple(
-            Pipeline.of(f, s) for f, s in zip(featurizers, scalers)
-        )
-    )
-    if streamed_fit:
-        from keystone_tpu.core.pipeline import ChainedLabelEstimator
-
-        fitted = plan_mod.fit_streaming(
-            ChainedLabelEstimator(prefix=bank, est=est),
-            x_train,
-            indicators,
-            n_valid=n_train,
-            mesh=mesh,
-        )
-        model = jax.block_until_ready(fitted[-1])
-    else:
-        from keystone_tpu.core.checkpoint import checkpointed_fit
-
-        model = jax.block_until_ready(
-            checkpointed_fit(
-                est,
+            # selection at one BCD pass (like MNIST): cheap relative to
+            # the final multi-epoch fit, and the final fit stays under
+            # the --checkpoint-dir preemption protection
+            report = holdout_lambda_sweep(
+                BlockLeastSquaresEstimator(
+                    block_size=conf.cosine_features, num_iter=1
+                ),
                 train_blocks,
                 indicators,
-                checkpoint_dir=conf.checkpoint_dir,
-                every=conf.checkpoint_every,
-                n_valid=n_train,
+                y,
+                conf.lam_sweep,
+                n_train=n_train,
+                num_classes=NUM_CLASSES,
+            )
+            lam = report["best_lam"]
+            logger.info(
+                "lambda sweep %s -> val errors %s; refitting at best lam=%g",
+                report["lams"],
+                [round(e, 4) for e in report["val_errors"]],
+                lam,
+            )
+        est = BlockLeastSquaresEstimator(
+            block_size=conf.cosine_features, num_iter=conf.num_epochs, lam=lam
+        )
+        bank = ScaledCosineBank(
+            chains=tuple(
+                Pipeline.of(f, s) for f, s in zip(featurizers, scalers)
             )
         )
+        if streamed_fit:
+            from keystone_tpu.core.pipeline import ChainedLabelEstimator
+
+            fitted = plan_mod.fit_streaming(
+                ChainedLabelEstimator(prefix=bank, est=est),
+                x_train,
+                indicators,
+                n_valid=n_train,
+                mesh=mesh,
+            )
+            model = jax.block_until_ready(fitted[-1])
+        else:
+            from keystone_tpu.core.checkpoint import checkpointed_fit
+
+            model = jax.block_until_ready(
+                checkpointed_fit(
+                    est,
+                    train_blocks,
+                    indicators,
+                    checkpoint_dir=conf.checkpoint_dir,
+                    every=conf.checkpoint_every,
+                    n_valid=n_train,
+                )
+            )
     t_fit = time.perf_counter()
 
-    classify = MaxClassifier()
-    evaluator = MulticlassClassifierEvaluator(NUM_CLASSES)
-    score = jax.jit(lambda b: model(bank(b)))
-    # classic path: the blocks are already resident — don't re-featurize
-    train_scores = (
-        score(x_train) if streamed_fit else model(train_blocks)
-    )
-    train_eval = evaluator(classify(train_scores), y, n_valid=n_train)
+    with span("fit.score"):
+        classify = MaxClassifier()
+        evaluator = MulticlassClassifierEvaluator(NUM_CLASSES)
 
-    y_test = np.zeros(x_test.shape[0], np.int32)
-    y_test[:n_test] = test.labels
-    test_eval = evaluator(
-        classify(score(x_test)), y_test, n_valid=n_test
-    )
+        @jax.jit
+        def score(b):
+            return model(bank(b))
+
+        with span("score.train"):
+            # classic path: the blocks are already resident — don't
+            # re-featurize
+            train_scores = (
+                score(x_train) if streamed_fit else model(train_blocks)
+            )
+            train_eval = evaluator(classify(train_scores), y, n_valid=n_train)
+        with span("score.test"):
+            y_test = np.zeros(x_test.shape[0], np.int32)
+            y_test[:n_test] = test.labels
+            # the evaluator reads the confusion matrix back: forced
+            test_eval = evaluator(
+                classify(score(x_test)), y_test, n_valid=n_test
+            )
 
     result = {
         "train_error": train_eval.error,

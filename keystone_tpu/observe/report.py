@@ -869,6 +869,11 @@ def main(argv: list[str] | None = None) -> None:
         from keystone_tpu.observe import spans as _spans
 
         return _spans.main(argv[1:])
+    if argv and argv[0] == "idle":
+        # device idle time by host span: `observe idle <profile-dir> [<dir>]`
+        from keystone_tpu.observe import idle as _idle
+
+        return _idle.main(argv[1:])
     if argv and argv[0] == "collect":
         # the fleet collector daemon: scrape + tail → time-series store
         from keystone_tpu.observe import collector as _collector
@@ -891,6 +896,8 @@ def main(argv: list[str] | None = None) -> None:
             " [--interval S]\n"
             "       python -m keystone_tpu observe trace <run-dir>"
             " [--request ID] [--limit N]\n"
+            "       python -m keystone_tpu observe idle <profile-dir>"
+            " [<run-dir>]\n"
             "       python -m keystone_tpu observe diff <dirA> <dirB>\n"
             "       python -m keystone_tpu observe collect <out-dir>"
             " [--router URL] [--watch DIR] [--once]\n"
@@ -902,7 +909,11 @@ def main(argv: list[str] | None = None) -> None:
             "KEYSTONE_OBSERVE_DIR (the newest run under it is rendered;\n"
             "`top` on a base dir tails EVERY run dir, live);\n"
             "`trace` renders spans.jsonl as per-trace span trees with a\n"
-            "critical-path summary and the goodput bucket breakdown;\n"
+            "critical-path summary and the goodput bucket breakdown\n"
+            "(spans are recorded under --observe DIR or --profile DIR);\n"
+            "`idle` reads what --profile DIR wrote and puts each idle gap\n"
+            "of the device down to the host span open over it (with the\n"
+            "run's --observe dir also to the jit.* compile spans);\n"
             "`diff` renders side-by-side goodput shares, step-time\n"
             "percentiles, and event-counter deltas between two runs;\n"
             "`collect` runs the fleet collector (scrapes /metrics,\n"

@@ -10,9 +10,35 @@ request, a train step, or a planned pass can then be rendered as a tree
 into *where the time went* buckets — the per-stage stall/goodput signal
 the self-tuning planner (ROADMAP item 3) needs.
 
-Activation mirrors :mod:`.telemetry` exactly: a :class:`SpanLog` exists
-only while an event sink is active, and :func:`active_span_log` /
-:func:`span` cost ONE global read returning None on the disabled path.
+Activation: spans are on while an event sink is active (``--observe
+DIR`` / ``KEYSTONE_OBSERVE_DIR``; the run's :class:`SpanLog` writes
+``spans.jsonl``) **or while a ``jax.profiler`` session is on**
+(``--profile DIR``, :class:`~keystone_tpu.observe.tracing.StepTracer`,
+anyone's ``jax.profiler.start_trace``). In the second case they go to a
+memory-only :class:`SpanLog` that :func:`profiled_spans` still returns
+after the session stops; a new session starts a new list (the profiler
+has no public session id, so "new" means: seen on after it was seen off
+— see :func:`profiled_spans`). With neither, :func:`active_span_log` /
+:func:`span` cost two reads (the event sink,
+``TraceAnnotation.is_enabled()``), build nothing and yield None.
+
+The shared clock: while a profiler session is on, a live :func:`span`
+also enters ``jax.profiler.TraceAnnotation(name, span=, parent=,
+trace=)``, so the span lands in the ``/host:CPU`` plane of the same
+``.xplane.pb`` as the device planes, under its own name with its ids as
+stats. Host spans and device ops are then on one timeline
+(``python -m keystone_tpu observe idle <profile-dir>`` reads it), and
+``t0_ns`` of a record maps onto it through the offset of any span that
+both hold.
+
+Which call recompiled: the first time spans come on, one
+``jax.monitoring`` listener is registered. While spans are on, every
+jaxpr trace, lowering and backend compile becomes a ``jit.trace`` /
+``jit.lower`` / ``jit.backend_compile`` child (attr ``fun``) of whatever
+span is ambient; a backend compile that the persistent cache answered
+is recorded as ``jit.cache_read`` instead (jax times the cache read
+inside the backend-compile bracket: one request, one span). Outside any
+span nothing is recorded: a child needs a parent.
 
 Span record schema (one JSON object per line; extra fields free-form):
 
@@ -23,8 +49,13 @@ Span record schema (one JSON object per line; extra fields free-form):
 ``span``        this span's id
 ``parent``      parent span id (absent for roots)
 ``name``        span name, dotted by subsystem (``serve.queue_wait``,
-                ``plan.segment``, ``staging.h2d``, ``train.step``)
+                ``plan.segment``, ``staging.h2d``, ``train.step``,
+                ``fit`` / ``fit.load`` / ``fit.h2d`` / ``fit.featurize``
+                / ``featurize.cosine`` / ``fit.solve`` / ``fit.score``
+                of the classic fit path, ``jit.backend_compile``)
 ``wall_s``      wall-clock duration
+``t0_ns``       start and end on ``time.perf_counter_ns()``: one clock
+``t1_ns``       per process, so spans of a run order and nest by these
 ``bucket``      goodput bucket (see :data:`BUCKETS`), absent on
                 structural spans whose children carry the time
 ``status``      ``failed`` when the bracket raised (absent = ok)
@@ -62,6 +93,9 @@ import threading
 import time
 import uuid
 from typing import Any, Iterator, NamedTuple
+
+import jax
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from keystone_tpu.observe import events as _events
 
@@ -120,6 +154,16 @@ def current() -> SpanContext | None:
     return _current.get()
 
 
+def force(x):
+    """``jax.block_until_ready(x)`` when, and only when, a span is being
+    recorded around the caller; returns ``x``. A phase boundary calls it
+    on the phase's outputs so that a recorded span ends when its device
+    work has, while an untraced run keeps its asynchronous dispatch."""
+    if _current.get() is not None:
+        jax.block_until_ready(x)
+    return x
+
+
 class SpanLog:
     """One run's span sink: ``spans.jsonl`` (size-rotated under
     ``KEYSTONE_OBSERVE_MAX_MB``) plus a bounded in-memory mirror.
@@ -156,6 +200,7 @@ class SpanLog:
         name: str,
         *,
         wall_s: float,
+        end_ns: int | None = None,
         bucket: str | None = None,
         parent: SpanContext | None = None,
         trace: str | None = None,
@@ -165,9 +210,13 @@ class SpanLog:
     ) -> SpanContext:
         """Emit one already-measured span and return its context.
 
-        ``ctx`` reuses pre-allocated ids (:func:`make_context`);
-        otherwise the trace comes from ``trace``, else the ``parent``,
-        else a fresh one (a root)."""
+        ``end_ns`` is when it ended on ``time.perf_counter_ns()`` (now,
+        if not given); the start is that less ``wall_s``. ``ctx`` reuses
+        pre-allocated ids (:func:`make_context`); otherwise the trace
+        comes from ``trace``, else the ``parent``, else a fresh one (a
+        root)."""
+        if end_ns is None:
+            end_ns = time.perf_counter_ns()
         if ctx is None:
             ctx = make_context(parent, trace)
         rec: dict[str, Any] = {
@@ -176,6 +225,8 @@ class SpanLog:
             "span": ctx.span,
             "name": name,
             "wall_s": round(float(wall_s), 6),
+            "t0_ns": end_ns - int(round(float(wall_s) * 1e9)),
+            "t1_ns": end_ns,
         }
         if self.run_id:
             rec["run"] = self.run_id
@@ -201,16 +252,61 @@ class SpanLog:
                 self._sink = None
 
 
-def active_span_log() -> SpanLog | None:
-    """The :class:`SpanLog` riding the active event sink, or None.
+# the memory-only log of the newest profiler session, and whether that
+# session is still the one that is on
+_session_log: SpanLog | None = None
+_session_open = False
 
-    The ONLY check the hot paths make: with no sink active this is
-    exactly one global read (``events.active()``) and constructs
-    nothing — the same overhead contract as
+
+def _profiler_span_log() -> SpanLog | None:
+    """The memory-only :class:`SpanLog` of the ``jax.profiler`` session
+    that is on, or None. ``TraceAnnotation.is_enabled()`` is the one
+    public, static way to ask, and it names no session: one seen on
+    after it was seen off (here or by :func:`profiled_spans`) is a new
+    one and gets a new list. Two sessions with no such look between
+    them share a list."""
+    global _session_log, _session_open
+    if not _TraceAnnotation.is_enabled():
+        _session_open = False
+        return None
+    if not _session_open:
+        with _bind_lock:
+            if not _session_open:
+                _session_log = SpanLog()
+                _session_open = True
+                _listen_for_compiles()
+    return _session_log
+
+
+def profiled_spans() -> list[dict]:
+    """The span records of the newest profiler session, during it and
+    after it stopped ([] if none ran, or if an event sink was active:
+    the records then went to that run's ``spans.jsonl``). The one way
+    into the session's spans from outside this module (the benchmark's
+    per-layer readers use it).
+
+    Call it (or any :func:`span`) between two sessions: that look while
+    the profiler is off is what makes the next session's list a new one.
+    Without it the second session appends to the first's records."""
+    global _session_open
+    if not _TraceAnnotation.is_enabled():
+        _session_open = False
+    sl = _session_log
+    return list(sl.records) if sl is not None else []
+
+
+def active_span_log() -> SpanLog | None:
+    """The :class:`SpanLog` riding the active event sink, else the one
+    of the profiler session that is on, else None.
+
+    The ONLY check the hot paths make: with neither this is one global
+    read (``events.active()``) and one static call
+    (``TraceAnnotation.is_enabled()``) and constructs nothing — the
+    same overhead contract as
     :func:`keystone_tpu.observe.telemetry.active_step_log`."""
     log = _events.active()
     if log is None:
-        return None
+        return _profiler_span_log()
     sl = log.__dict__.get("_spanlog")
     if sl is None:
         with _bind_lock:
@@ -218,7 +314,53 @@ def active_span_log() -> SpanLog | None:
             if sl is None:
                 sl = SpanLog(log.run_dir, log.run_id)
                 log._spanlog = sl
+                _listen_for_compiles()
     return sl
+
+
+# ------------------------------------------------- which call recompiled
+
+_JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.backend_compile",
+}
+# emitted on a persistent-cache hit only, inside the backend-compile
+# bracket of the same request and before that bracket's own event
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_listening = False
+_cache_hit = threading.local()
+
+
+def _on_jit_duration(event: str, duration: float, **kw: Any) -> None:
+    """The ``jax.monitoring`` duration listener: inside a recorded span,
+    one post-hoc child span per compile step (start = now - duration);
+    nothing (one context read) outside one."""
+    if event != _CACHE_READ_EVENT and event not in _JIT_EVENTS:
+        return
+    parent = _current.get()
+    if parent is None:  # spans off, or no span to parent on
+        return
+    sl = active_span_log()
+    if sl is None:
+        return
+    if event == _CACHE_READ_EVENT:
+        _cache_hit.pending = True
+        return
+    name = _JIT_EVENTS[event]
+    if name == "jit.backend_compile" and getattr(_cache_hit, "pending", False):
+        _cache_hit.pending = False
+        name = "jit.cache_read"
+    sl.record_span(name, wall_s=duration, parent=parent, fun=kw.get("fun_name"))
+
+
+def _listen_for_compiles() -> None:
+    """Register the compile listener, once per process (jax keeps
+    listeners for good, hence the gate inside the callback)."""
+    global _listening
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_event_duration_secs_listener(_on_jit_duration)
 
 
 @contextlib.contextmanager
@@ -236,9 +378,11 @@ def span(
     ambient context for the duration, and emits on exit (``status:
     failed`` rides a raised exception out).
 
-    With no sink active this yields None after exactly one global read
-    — pass ``log=`` (a :class:`SpanLog` or None) to skip even that when
-    the caller already looked it up once for a whole batch/stream.
+    While a profiler session is on the block is also bracketed by a
+    ``TraceAnnotation`` of the same name (module docstring). With no
+    sink and no session this yields None after two reads — pass ``log=``
+    (a :class:`SpanLog` or None) to skip even those when the caller
+    already looked it up once for a whole batch/stream.
     """
     sl = active_span_log() if log is _UNSET else log
     if sl is None:
@@ -247,7 +391,15 @@ def span(
     pctx = _current.get() if parent is _UNSET else parent
     ctx = make_context(pctx, trace)
     token = _current.set(ctx)
-    t0 = time.perf_counter()
+    twin = None
+    if _TraceAnnotation.is_enabled():
+        # the span's twin on the profiler's clock: same name, ids as stats
+        ids = {"span": ctx.span, "trace": ctx.trace}
+        if pctx is not None:
+            ids["parent"] = pctx.span
+        twin = _TraceAnnotation(name, **ids)
+        twin.__enter__()
+    t0 = time.perf_counter_ns()
     status = None
     try:
         yield ctx
@@ -255,10 +407,14 @@ def span(
         status = "failed"
         raise
     finally:
+        t1 = time.perf_counter_ns()
+        if twin is not None:
+            twin.__exit__(None, None, None)
         _current.reset(token)
         sl.record_span(
             name,
-            wall_s=time.perf_counter() - t0,
+            wall_s=(t1 - t0) / 1e9,
+            end_ns=t1,
             bucket=bucket,
             parent=pctx,
             ctx=ctx,

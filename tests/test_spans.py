@@ -744,3 +744,325 @@ def test_observe_trace_cli_usage():
         spans_cli.main([])
     with pytest.raises(SystemExit):
         spans_cli.main(["--help"])
+
+
+# ---------------------------------------------------------------------------
+# PR 26: spans of the fit path, on the profiler's clock. None of these
+# times the CPU: they hold ids, nesting, one clock against another, and
+# hand-worked tables.
+
+from types import SimpleNamespace as NS  # noqa: E402
+
+FIT_SPANS = {
+    # name -> parent's name
+    "fit.load": "fit",
+    "fit.h2d": "fit",
+    "fit.featurize_init": "fit",
+    "fit.featurize": "fit",
+    "featurize.cosine": "fit.featurize",
+    "featurize.scale_fit": "fit.featurize",
+    "featurize.scale_apply": "fit.featurize",
+    "fit.labels": "fit",
+    "fit.featurize_wait": "fit",
+    "fit.solve": "fit",
+    "fit.score": "fit",
+    "score.train": "fit.score",
+    "score.test": "fit.score",
+}
+
+
+def _toy_fit():
+    from keystone_tpu.models.timit_pipeline import TimitConfig, run
+
+    return run(TimitConfig(synthetic=256, num_cosines=2, cosine_features=32,
+                           num_epochs=2))
+
+
+def _start_profile(directory):
+    import jax
+
+    options = jax.profiler.ProfileOptions()  # the benchmark harness's own
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(directory), profiler_options=options)
+
+
+def _planes(directory):
+    import jax
+
+    from keystone_tpu.observe import idle
+
+    return list(
+        jax.profiler.ProfileData.from_file(idle.newest_profile(str(directory))).planes
+    )
+
+
+@pytest.fixture(scope="module")
+def profiled_fit(tmp_path_factory):
+    """One toy fit inside a profiler session and no event sink: (what
+    run() returned, the session's span records, the profile's dir)."""
+    import jax
+
+    directory = tmp_path_factory.mktemp("profile")
+    assert events.active() is None
+    _toy_fit()  # warm the eager ops, so the profiled fit is a steady one
+    _start_profile(directory)
+    try:
+        out = _toy_fit()
+    finally:
+        jax.profiler.stop_trace()
+    return out, spans_mod.profiled_spans(), directory
+
+
+def test_fit_path_off_records_nothing_and_forces_nothing_extra(monkeypatch, profiled_fit):
+    """No sink, no session: span() yields None after two reads, builds
+    nothing, and run() waits for the device exactly where it did before
+    it had spans (the solve's own block_until_ready)."""
+    import jax
+
+    assert events.active() is None and not jax.profiler.TraceAnnotation.is_enabled()
+    before = spans_mod.profiled_spans()
+    forced: list[int] = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: forced.append(1) or real(x))
+    monkeypatch.setattr(
+        spans_mod.SpanLog, "__init__",
+        lambda *a, **k: (_ for _ in ()).throw(AssertionError("built a SpanLog")),
+    )
+    with spans_mod.span("anything", rows=1) as ctx:
+        assert ctx is None and spans_mod.current() is None
+        assert spans_mod.force(7) == 7
+    assert forced == []
+    _toy_fit()
+    assert forced == [1]
+    assert spans_mod.active_span_log() is None
+    assert spans_mod.profiled_spans() == before
+
+
+def test_fit_spans_on_by_profiler_session_alone(profiled_fit):
+    out, recs, _dir = profiled_fit
+    by_id = {r["span"]: r for r in recs}
+    (root,) = [r for r in recs if r["name"] == "fit"]
+    assert "parent" not in root
+    import jax
+
+    assert {k: root[k] for k in ("blocks", "epochs", "chips")} == {
+        "blocks": 2, "epochs": 2, "chips": len(jax.devices())}
+    assert {r["trace"] for r in recs} == {root["trace"]}
+    names = [r["name"] for r in recs]
+    for name, parent in FIT_SPANS.items():
+        mine = [r for r in recs if r["name"] == name]
+        assert mine, name
+        assert {by_id[r["parent"]]["name"] for r in mine} == {parent}, name
+    assert names.count("fit.featurize") == 2
+    assert sorted(r["bank"] for r in recs if r["name"] == "fit.featurize") == [0, 1]
+    (h2d,) = [r for r in recs if r["name"] == "fit.h2d"]
+    # rows ride the first span that opens once the load has said them
+    assert (h2d["rows"], h2d["bytes"]) == (256, (256 + 51) * 440 * 4)
+    for r in recs:
+        assert r["t0_ns"] <= r["t1_ns"]
+        assert r["wall_s"] == pytest.approx((r["t1_ns"] - r["t0_ns"]) / 1e9, abs=1e-6)
+        if r["name"].startswith("jit."):
+            continue  # post-hoc: start = emission - duration, may round out
+        p = by_id.get(r.get("parent"))
+        if p is not None:
+            assert p["t0_ns"] <= r["t0_ns"] and r["t1_ns"] <= p["t1_ns"], r["name"]
+    # the phase keys come from the same boundaries as the spans
+    wait = next(r for r in recs if r["name"] == "fit.featurize_wait")
+    assert out["featurize_s"] == pytest.approx(
+        (wait["t1_ns"] - root["t0_ns"]) / 1e9, abs=5e-3)
+    # the three per-call programs are re-made and compiled in every fit,
+    # under their own names and under the span that called them
+    compiled = {(r["fun"], by_id[r["parent"]]["name"]) for r in recs
+                if r["name"] == "jit.backend_compile"}
+    assert compiled == {
+        ("jit(cosine_features)", "featurize.cosine"),
+        ("jit(standard_scale)", "featurize.scale_apply"),
+        ("jit(score)", "score.test"),
+    }
+    # a session that is over records nothing more, and stays readable
+    _toy_fit()
+    assert spans_mod.profiled_spans() == recs
+
+
+def test_live_spans_have_twins_on_the_profilers_clock(profiled_fit):
+    """Every live span has a host-plane event of its name whose ``span``
+    stat is its id; anchored on the root, starts and durations agree
+    within 1 ms: the .xplane.pb is the shared clock."""
+    from keystone_tpu.observe import idle
+
+    _out, recs, directory = profiled_fit
+    twins = {s["span"]: s for s in idle.host_spans(_planes(directory))}
+    live = [r for r in recs if not r["name"].startswith("jit.")]
+    (root,) = [r for r in live if r["name"] == "fit"]
+    offset = twins[root["span"]]["start"] - root["t0_ns"]
+    assert len(live) >= len(FIT_SPANS) + 1
+    for r in live:
+        twin = twins[r["span"]]
+        assert twin["label"] == r["name"]
+        assert twin["parent"] == r.get("parent", "")
+        assert abs(twin["start"] - (r["t0_ns"] + offset)) < 1e6, r["name"]
+        assert abs((twin["end"] - twin["start"]) - (r["t1_ns"] - r["t0_ns"])) < 1e6
+    # post-hoc spans have no twin; `observe idle` places them by the offset
+    assert not any(r["span"] in twins for r in recs if r["name"].startswith("jit."))
+    placed = {s["span"]: s for s in idle.place_recorded(list(twins.values()), recs)}
+    compile_ = next(r for r in recs if r["name"] == "jit.backend_compile")
+    assert placed[compile_["span"]]["label"] == f"jit.backend_compile fun={compile_['fun']}"
+    assert placed[compile_["span"]]["start"] == pytest.approx(
+        compile_["t0_ns"] + offset, abs=1e6)
+
+
+def test_compile_listener_names_the_call_that_compiled(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    def fresh(tag):
+        def twice_plus(x):
+            return x * 2.0 + tag
+        twice_plus.__name__ = f"twice_plus_{tag}"
+        return jax.jit(twice_plus)
+
+    x = jnp.ones(3)
+    fresh(1)(x)  # off: nothing recorded
+    with events.run(str(tmp_path)) as log:
+        with spans_mod.span("caller") as ctx:
+            fresh(2)(x)
+        fresh(3)(x)  # on, but under no span: a child needs a parent
+        run_dir = log.run_dir
+    fresh(4)(x)  # off again
+    recs = spans_mod.read_spans(run_dir)
+    jit = [r for r in recs if r["name"].startswith("jit.")]
+    assert len(jit) == len(recs) - 1
+    assert {r["name"] for r in jit} == {"jit.trace", "jit.lower", "jit.backend_compile"}
+    assert all(r["parent"] == ctx.span and r["trace"] == ctx.trace for r in jit)
+    # (the jnp ops inside it may be traced too, as its own children in time)
+    assert {"twice_plus_2", "jit(twice_plus_2)"} <= {r["fun"] for r in jit}
+    assert [r["fun"] for r in jit if r["name"] == "jit.backend_compile"] == [
+        "jit(twice_plus_2)"]
+    caller = next(r for r in recs if r["name"] == "caller")
+    for r in jit:  # start = emission - duration, inside the caller
+        assert caller["t0_ns"] <= r["t0_ns"] <= r["t1_ns"] <= caller["t1_ns"]
+
+
+def test_a_cache_answered_compile_is_one_cache_read_span(tmp_path):
+    """jax emits the cache-retrieval event inside the backend-compile
+    bracket: the pair becomes one ``jit.cache_read`` with the ``fun``."""
+    with events.run(str(tmp_path)) as log:
+        with spans_mod.span("caller"):
+            spans_mod._on_jit_duration(spans_mod._CACHE_READ_EVENT, 0.25)
+            spans_mod._on_jit_duration(
+                "/jax/core/compile/backend_compile_duration", 0.3, fun_name="jit(f)")
+            spans_mod._on_jit_duration(
+                "/jax/core/compile/backend_compile_duration", 0.1, fun_name="jit(g)")
+            spans_mod._on_jit_duration("/jax/some/other_duration", 9.0)
+        run_dir = log.run_dir
+    got = [(r["name"], r.get("fun"), r["wall_s"]) for r in spans_mod.read_spans(run_dir)]
+    assert got == [("jit.cache_read", "jit(f)", 0.3),
+                   ("jit.backend_compile", "jit(g)", 0.1), ("caller", None, got[-1][2])]
+
+
+def test_a_new_profiler_session_starts_a_new_list(tmp_path, profiled_fit):
+    """New means seen on after seen off: the look between the sessions
+    (here profiled_spans()) is what resets; without one they share."""
+    import jax
+
+    def session(directory, name):
+        _start_profile(directory)
+        try:
+            with spans_mod.span(name):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+
+    _out, recs, _dir = profiled_fit
+    assert spans_mod.profiled_spans() == recs
+    session(tmp_path / "2", "second.session")
+    during = spans_mod.profiled_spans()
+    assert [r["name"] for r in during] == ["second.session"]
+    session(tmp_path / "3", "third.session")
+    session(tmp_path / "4", "no.look.before.this.one")
+    assert [r["name"] for r in spans_mod.profiled_spans()] == [
+        "third.session", "no.look.before.this.one"]
+    assert spans_mod._session_log.records.maxlen == spans_mod._MAX_MEMORY_SPANS == 8192
+
+
+def _xplane(name, lines):
+    return NS(name=name, lines=[
+        NS(name=ln, events=[
+            NS(name=n, start_ns=s, duration_ns=d, stats=list(stats.items()))
+            for n, s, d, stats in evs])
+        for ln, evs in lines.items()])
+
+
+def test_observe_idle_on_a_hand_built_trace(capsys):
+    """A 1000 ns window. Host: fit 0-1000 holding fit.load 0-300 and
+    fit.score 600-950, which holds score.test 700-900. Chip 0 runs ops
+    300-400, 450-600 and 800-850; chip 1 runs 0-500."""
+    from keystone_tpu.observe import idle
+
+    host = _xplane("/host:CPU", {"python": [
+        ("fit", 0, 1000, {"span": "r", "trace": "t"}),
+        ("fit.load", 0, 300, {"span": "a", "parent": "r", "trace": "t"}),
+        ("fit.score", 600, 350, {"span": "b", "parent": "r", "trace": "t"}),
+        ("score.test", 700, 200, {"span": "c", "parent": "b", "trace": "t"}),
+        ("not a span", 0, 1000, {}),
+    ]})
+    chip0 = _xplane("/device:TPU:0", {
+        "XLA Modules": [("jit_x(1)", 300, 300, {})],
+        "XLA Ops": [("fusion.1", 300, 100, {}), ("fusion.2", 450, 150, {}),
+                    ("fusion.3", 800, 50, {})]})
+    chip1 = _xplane("/device:TPU:1", {"XLA Ops": [("fusion.1", 0, 500, {})]})
+    one = idle.idle_by_span([host, chip0])
+    assert (one["chips"], one["window_s"]) == (1, pytest.approx(1000e-9))
+    assert one["busy_s"] == pytest.approx(300e-9) and one["idle_s"] == pytest.approx(700e-9)
+    # gaps: 0-300 fit.load; 400-450 fit; 600-800 = fit.score 100 + score.test
+    # 100; 850-1000 = score.test 50 + fit.score 50 + fit 50
+    assert {k: (round(s * 1e9), g) for k, s, g in one["rows"]} == {
+        "fit.load": (300, 1), "fit.score": (150, 2), "score.test": (150, 2), "fit": (100, 2)}
+    assert [r[0] for r in one["rows"]][0] == "fit.load"
+    # a compile recorded after the fact, placed through the shared spans:
+    # the process clock runs 5000 ns ahead of the profile's
+    records = [
+        {"name": "fit", "span": "r", "trace": "t", "t0_ns": 5000, "t1_ns": 6000},
+        {"name": "score.test", "span": "c", "parent": "b", "trace": "t",
+         "t0_ns": 5700, "t1_ns": 5900},
+        {"name": "jit.backend_compile", "span": "j", "parent": "c", "trace": "t",
+         "fun": "jit(score)", "t0_ns": 5710, "t1_ns": 5790},
+        {"name": "jit.trace", "span": "k", "parent": "zz", "trace": "other",
+         "t0_ns": 5000, "t1_ns": 6000},
+        {"name": "older record", "span": "old", "trace": "t"},
+    ]
+    with_jit = idle.idle_by_span([host, chip0], records)
+    rows = {k: round(s * 1e9) for k, s, _g in with_jit["rows"]}
+    assert rows["jit.backend_compile fun=jit(score)"] == 80
+    assert rows["score.test"] == 70 and "jit.trace" not in rows
+    # two chips: chip 1 idles 500-1000 (fit 100, fit.score 150, score.test
+    # 200, and 50 of fit after 950); the table is the mean
+    two = idle.idle_by_span([host, chip0, chip1])
+    assert two["chips"] == 2 and two["busy_s"] == pytest.approx(400e-9)
+    rows = {k: round(s * 1e9) for k, s, _g in two["rows"]}
+    assert rows == {"fit.load": 150, "fit.score": 150, "score.test": 175, "fit": 125}
+    # what no span covers is the last row
+    bare = idle.idle_by_span([_xplane("/host:CPU", {"python": [
+        ("fit.load", 0, 300, {"span": "a", "trace": "t"})]}), chip0])
+    assert [(k, round(s * 1e9)) for k, s, _g in bare["rows"]] == [
+        ("fit.load", 300), ("(no span)", 250)]
+    out = idle.render(bare)
+    assert "idle 0.0000 s (64.71 %)" in out and out.splitlines()[-1].startswith("(no span)")
+    assert idle.idle_by_span([host]) is None
+
+
+def test_observe_idle_cli_on_a_cpu_trace_says_it_has_no_device_plane(
+        profiled_fit, tmp_path, capsys):
+    from keystone_tpu.observe import report
+
+    _out, _recs, directory = profiled_fit
+    report.main(["idle", str(directory)])
+    out = capsys.readouterr().out
+    assert "no /device:TPU:<n> plane" in out and "fit.solve" in out
+    for bad in ([], ["--help"], [str(tmp_path)], [str(directory), str(tmp_path / "nope")]):
+        with pytest.raises(SystemExit):
+            report.main(["idle", *bad])
+    with pytest.raises(SystemExit) as e:
+        report.main(["--help"])
+    assert "observe idle <profile-dir>" in str(e.value)
