@@ -101,6 +101,25 @@ def _load(conf: TimitConfig, which: str) -> LabeledData:
     return load_timit_split(conf.test_data_location, conf.test_labels_location)
 
 
+# The fit's three device programs, made once per process: every fitted
+# node is a pytree argument, so jax's own cache answers each later fit
+# (new weights, a new seed) with the executable of the first. The names
+# are what the device programs are called in a profile.
+@jax.jit
+def cosine_features(node, b):
+    return node(b)
+
+
+@jax.jit
+def standard_scale(node, b):
+    return node(b)
+
+
+@jax.jit
+def score(model, bank, b):
+    return model(bank(b))
+
+
 def run(conf: TimitConfig, mesh=None) -> dict:
     """One fit. While spans are on (``--observe`` or ``--profile``; see
     ``observe/spans.py``) the call is one ``fit`` root span with a child
@@ -162,16 +181,6 @@ def _fit(conf: TimitConfig, mesh) -> dict:
     streamed_fit = plan_mod.enabled() and not (
         conf.lam_sweep or conf.checkpoint_dir
     )
-
-    # re-made (so traced and compiled again) in every call of run(); the
-    # names are what the device programs are called in a profile
-    @jax.jit
-    def cosine_features(node, b):
-        return node(b)
-
-    @jax.jit
-    def standard_scale(node, b):
-        return node(b)
 
     # per-batch cosine features, standard-scaled (fit on train)
     train_blocks, scalers = [], []
@@ -263,15 +272,13 @@ def _fit(conf: TimitConfig, mesh) -> dict:
         classify = MaxClassifier()
         evaluator = MulticlassClassifierEvaluator(NUM_CLASSES)
 
-        @jax.jit
-        def score(b):
-            return model(bank(b))
-
         with span("score.train"):
             # classic path: the blocks are already resident — don't
             # re-featurize
             train_scores = (
-                score(x_train) if streamed_fit else model(train_blocks)
+                score(model, bank, x_train)
+                if streamed_fit
+                else model(train_blocks)
             )
             train_eval = evaluator(classify(train_scores), y, n_valid=n_train)
         with span("score.test"):
@@ -279,7 +286,7 @@ def _fit(conf: TimitConfig, mesh) -> dict:
             y_test[:n_test] = test.labels
             # the evaluator reads the confusion matrix back: forced
             test_eval = evaluator(
-                classify(score(x_test)), y_test, n_valid=n_test
+                classify(score(model, bank, x_test)), y_test, n_valid=n_test
             )
 
     result = {

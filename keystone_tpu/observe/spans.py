@@ -128,7 +128,12 @@ class SpanContext(NamedTuple):
 
 
 def _new_id() -> str:
-    return uuid.uuid4().hex[:12]
+    """12 hex characters, the first a letter: an id must never read as a
+    number, because the profiler guesses the type of a TraceAnnotation's
+    stats and hands ``68401e457669`` back as ``inf`` and ``000123456789``
+    as ``123456789`` (one id in 250 is of those forms)."""
+    h = uuid.uuid4().hex
+    return "abcdef"[int(h[11], 16) % 6] + h[:11]
 
 
 def make_context(
