@@ -49,6 +49,8 @@ PIPELINES = {
     ),
     "vit-ridge": ("keystone_tpu.models.vit_ridge", None),
     "lm-transformer": ("keystone_tpu.models.lm_transformer", None),
+    # the same entry by its short name: `lm --config laguna_xs2`
+    "lm": ("keystone_tpu.models.lm_transformer", None),
 }
 
 # non-pipeline subcommands: short name → module whose ``main(argv)`` runs

@@ -20,24 +20,13 @@ import numpy as np
 
 @functools.partial(jax.jit, static_argnames=("logit_chunk",))
 def _ce(model, toks, logit_chunk: int = 0):
-    """Pure cross-entropy (module-level so the jit cache persists across
-    evaluate_perplexity calls): next_token_loss adds the MoE load-balance
-    aux, which is a training regularizer, not model quality.
-    ``logit_chunk`` mirrors the training option — at long eval sequences
-    the (B, S, V) f32 logits are the same HBM object to avoid."""
-    from keystone_tpu.models.lm_transformer import (
-        chunked_token_cross_entropy,
-        token_cross_entropy,
-    )
+    """The training loss itself, which is the cross-entropy alone
+    (module-level so the jit cache persists across evaluate_perplexity
+    calls). ``logit_chunk`` mirrors the training option — at long eval
+    sequences the (B, S, V) f32 logits are the same HBM object to avoid."""
+    from keystone_tpu.models.lm.losses import next_token_loss
 
-    if logit_chunk:
-        x, _ = model.backbone(toks[:, :-1])
-        return chunked_token_cross_entropy(
-            x, model.embed, toks[:, 1:],
-            jnp.dtype(model.compute_dtype), logit_chunk,
-        )
-    logits, _ = model.forward_with_aux(toks[:, :-1])
-    return token_cross_entropy(logits, toks[:, 1:])
+    return next_token_loss(model, toks, logit_chunk)
 
 
 def evaluate_perplexity(

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from keystone_tpu.core.treenode import treenode
 from keystone_tpu.models.lm.model import (
-    LMBlock,
     TransformerLM,
     _block_apply,
     _embed,
@@ -64,6 +63,19 @@ def _kv_quant(t):
     return symmetric_int8(t, (-1,))
 
 
+def refuse_unservable(model: TransformerLM) -> None:
+    """The KV-cache path holds one (L, B, KV, S_max, hd) buffer pair and
+    attends over all of it: a model with window layers, a head count per
+    layer, gated heads, another rotary scheme or an untied head is
+    refused by name, not served as something else (ROADMAP: the window
+    layers' cache)."""
+    why = model.uniform_decode_reason()
+    if why is not None:
+        raise NotImplementedError(
+            f"prefill/decode cannot serve this model: {why}"
+        )
+
+
 def prefill(model: TransformerLM, tokens, s_max: int,
             kv_dtype: str | None = None, lengths=None):
     """Run the prompt through the model once, capturing per-layer K/V into
@@ -83,6 +95,7 @@ def prefill(model: TransformerLM, tokens, s_max: int,
     :func:`decode_step`'s validity mask)."""
     if model.seq_mode != "local":
         raise ValueError("prefill/decode require seq_mode='local'")
+    refuse_unservable(model)
     if kv_dtype not in (None, "int8"):
         raise ValueError(f"kv_dtype={kv_dtype!r}; expected None|'int8'")
     cdt = jnp.dtype(model.compute_dtype)
@@ -90,11 +103,10 @@ def prefill(model: TransformerLM, tokens, s_max: int,
     x = _embed(model, tokens, cdt)
 
     ks, vs = [], []
-    for i, blk in enumerate(model.blocks):
+    for blk in model.blocks:
         x, (k, v), _ = _block_apply(
             x, blk, cdt,
             lambda y, b: model._attention(y, b, return_kv=True),
-            moe=model._moe(i),
             mm_fn=model_mm(model),
         )
         ks.append(k)
@@ -135,6 +147,7 @@ def decode_step(model: TransformerLM, token, cache: KVCache):
     The vector path writes via a one-hot select over the position axis
     (O(S_max) per layer — the same order as the attention read that
     follows, so nothing asymptotically new)."""
+    refuse_unservable(model)
     cdt = jnp.dtype(model.compute_dtype)
     d = model.embed.shape[-1]
     h = model.num_heads
@@ -225,9 +238,7 @@ def decode_step(model: TransformerLM, token, cache: KVCache):
 
     mm_fn = model_mm(model)
     for i, blk in enumerate(model.blocks):
-        x, _, _ = _block_apply(
-            x, blk, cdt, cached_attn(i), moe=model._moe(i), mm_fn=mm_fn
-        )
+        x, _, _ = _block_apply(x, blk, cdt, cached_attn(i), mm_fn=mm_fn)
     logits = _tied_logits(x, model.embed, cdt)[:, 0]
     # past-capacity poison: at pos >= S_max the cache write would clamp
     # onto S_max-1 and return plausible-but-wrong logits; pos is traced,
@@ -382,9 +393,10 @@ def quantize_for_decode(model: TransformerLM) -> TransformerLM:
     def qmat(w):
         return quantize_int8(w) if w.size else w
 
+    refuse_unservable(model)
     blocks = tuple(
-        LMBlock(
-            wq=qmat(b.wq), wk=qmat(b.wk), wv=qmat(b.wv), wo=qmat(b.wo),
+        dataclasses.replace(
+            b, wq=qmat(b.wq), wk=qmat(b.wk), wv=qmat(b.wv), wo=qmat(b.wo),
             w1=qmat(b.w1), w2=qmat(b.w2),
         )
         for b in model.blocks

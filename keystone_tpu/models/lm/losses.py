@@ -12,7 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from keystone_tpu.models.lm.model import TransformerLM, _tied_logits
+from keystone_tpu.models.lm.model import TransformerLM, output_logits
 
 
 def token_cross_entropy(logits, targets) -> jnp.ndarray:
@@ -24,8 +24,9 @@ def token_cross_entropy(logits, targets) -> jnp.ndarray:
     return jnp.mean(logz - gold)
 
 
-def chunked_token_cross_entropy(x, embed, targets, cdt, chunk: int):
-    """Mean next-token CE from final hidden states without ever holding
+def chunked_token_cross_entropy(x, model, targets, cdt, chunk: int):
+    """Mean next-token CE from final hidden states (through the model's
+    final norm and its head, tied or its own) without ever holding
     the (B, S, V) f32 logits: positions are processed in S-chunks — each
     chunk's logits are built, reduced to ``logsumexp − gold``, and
     dropped (``jax.checkpoint`` recomputes them in the backward),
@@ -42,7 +43,7 @@ def chunked_token_cross_entropy(x, embed, targets, cdt, chunk: int):
 
     @jax.checkpoint
     def chunk_sum(xx, tt):
-        logits = _tied_logits(xx, embed, cdt)  # (B, chunk, V) f32
+        logits = output_logits(model, xx, cdt)  # (B, chunk, V) f32
         # token_cross_entropy stays the single source of the CE form;
         # mean × count turns it back into this chunk's sum exactly
         return token_cross_entropy(logits, tt) * tt.size
@@ -58,18 +59,27 @@ def chunked_token_cross_entropy(x, embed, targets, cdt, chunk: int):
 def next_token_loss(
     model: TransformerLM, tokens, logit_chunk: int = 0
 ) -> jnp.ndarray:
-    """Mean cross-entropy of predicting ``tokens[:, 1:]`` from the prefix
-    (the model runs on the first S tokens of an S+1 window), plus the
-    weighted MoE load-balance auxiliary when the model routes.
+    """The scalar of :func:`next_token_loss_and_counters`."""
+    return next_token_loss_and_counters(model, tokens, logit_chunk)[0]
+
+
+def next_token_loss_and_counters(
+    model: TransformerLM, tokens, logit_chunk: int = 0
+):
+    """(mean cross-entropy of predicting ``tokens[:, 1:]`` from the
+    prefix, the expert layers' counters): the model runs on the first S
+    tokens of an S+1 window. The loss is the cross-entropy alone: no
+    auxiliary term the model's description does not name.
     ``logit_chunk > 0`` computes the CE in S-chunks so the full (B, S, V)
     f32 logits never materialize (see chunked_token_cross_entropy)."""
     if logit_chunk:
         cdt = jnp.dtype(model.compute_dtype)
-        x, aux = model.backbone(tokens[:, :-1])
-        ce = chunked_token_cross_entropy(
-            x, model.embed, tokens[:, 1:], cdt, logit_chunk
-        )
-        return ce + model.moe_aux_weight * aux
-    logits, aux = model.forward_with_aux(tokens[:, :-1])
-    ce = token_cross_entropy(logits, tokens[:, 1:])
-    return ce + model.moe_aux_weight * aux
+        x, counters = model.backbone(tokens[:, :-1])
+        with jax.named_scope("loss"):
+            ce = chunked_token_cross_entropy(
+                x, model, tokens[:, 1:], cdt, logit_chunk
+            )
+        return ce, counters
+    logits, counters = model.forward_with_aux(tokens[:, :-1])
+    with jax.named_scope("loss"):
+        return token_cross_entropy(logits, tokens[:, 1:]), counters

@@ -24,6 +24,7 @@ This is a beyond-reference capability in the same spirit as
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
@@ -36,24 +37,97 @@ from keystone_tpu.ops.attention import (
     ring_attention,
     ulysses_attention,
 )
+from keystone_tpu.ops.moe import COUNTERS, MoELayer, ffn
 from keystone_tpu.ops.quantization import QTensor, mm
 from keystone_tpu.ops.vit import _layer_norm
 
 
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """One rotary scheme, static: ``theta``; how much of each head is
+    rotated; and YaRN's ``(factor, original positions, beta_fast,
+    beta_slow, attention_factor)`` or None for plain rotary."""
+
+    theta: float = 10_000.0
+    partial: float = 1.0
+    yarn: tuple | None = None
+
+    def inv_freq(self, head_dim: int) -> tuple[np.ndarray, float]:
+        """(inverse frequencies of the rotated pairs, the factor on cos
+        and sin). YaRN blends each pair's ``inv_freq`` with
+        ``inv_freq / factor`` by a linear ramp between the pairs that
+        turn ``beta_fast`` and ``beta_slow`` times over the original
+        positions (the published convention: the ramp's ends are
+        floored and ceiled to whole pairs)."""
+        dim = int(head_dim * self.partial)
+        pos_freqs = self.theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+        inv = 1.0 / pos_freqs
+        if self.yarn is None:
+            return inv.astype(np.float32), 1.0
+        factor, original, beta_fast, beta_slow, attention_factor = self.yarn
+
+        def pair_that_turns(times):
+            return (
+                dim * math.log(original / (times * 2 * math.pi))
+                / (2 * math.log(self.theta))
+            )
+
+        low = max(math.floor(pair_that_turns(beta_fast)), 0)
+        high = min(math.ceil(pair_that_turns(beta_slow)), dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+        blended = inv / factor * ramp + inv * (1.0 - ramp)
+        return blended.astype(np.float32), float(attention_factor)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What one layer's attention is, static: its head counts, its
+    causal window (0 = every earlier key) and its rotary scheme (None =
+    no rotation: learned positions). Layers of one model may differ."""
+
+    num_heads: int
+    num_kv_heads: int
+    window: int = 0
+    rope: RopeSpec | None = None
+
+
 @treenode
 class LMBlock:
-    wq: jnp.ndarray  # (d, d)
-    wk: jnp.ndarray
+    """One decoder block: attention, then a dense FFN or routed experts.
+    The one block definition: the toy presets of :meth:`TransformerLM.
+    create` and a public ``config.json`` (:meth:`TransformerLM.
+    from_config`) fill the same fields."""
+
+    wq: jnp.ndarray  # (d, H·hd)
+    wk: jnp.ndarray  # (d, KV·hd)
     wv: jnp.ndarray
-    wo: jnp.ndarray
-    w1: jnp.ndarray  # (d, ff)
+    wo: jnp.ndarray  # (H·hd, d)
+    w1: jnp.ndarray  # (d, ff); zero-width under routed experts
     w2: jnp.ndarray  # (ff, d)
+    w3: jnp.ndarray | None = None  # (d, ff): SwiGLU's second input
+    wg: jnp.ndarray | None = None  # (d, H): one sigmoid gate a head
+    norm1: jnp.ndarray | None = None  # learned RMSNorm scales, or None
+    norm2: jnp.ndarray | None = None  # for the parameter-free LayerNorm
+    moe: object | None = None  # ops.moe.MoELayer in place of the FFN
+    spec: LayerSpec | None = static_field(default=None)
 
 
 def _ln(x, cdt):
     # normalization stats in f32 even under a bf16 policy: the
     # mean/variance cancellation is exactly what bf16 loses
     return _layer_norm(x.astype(jnp.float32)).astype(cdt)
+
+
+def _norm(x, scale, eps: float, cdt):
+    """Learned RMSNorm when the block carries a scale, else the
+    parameter-free LayerNorm; statistics in f32 either way."""
+    if scale is None:
+        return _ln(x, cdt)
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * scale.astype(jnp.float32)).astype(cdt)
 
 
 def model_mm(model):
@@ -92,42 +166,43 @@ def _split_heads(y, w, h, mm_fn=mm):
     return out.reshape(n, s, h, out.shape[-1] // h).transpose(0, 2, 1, 3)
 
 
-def _rope(x, positions, base: float = 10_000.0):
-    """Rotary position embedding. x: (..., S, hd), hd even; positions:
-    (S,) int32 global token positions — or (B, S) when sequences in the
-    batch sit at different positions (the serving decode pool: each slot
-    carries its own sequence, so each rotates at its own phase). Angles
-    in f32 (bf16 loses phase accuracy fast at long context), rotated
-    result back in x.dtype."""
-    hd = x.shape[-1]
-    half = hd // 2
-    inv = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+def _rope(x, positions, rope: RopeSpec = RopeSpec()):
+    """Rotary position embedding. x: (..., S, hd); positions: (S,) int32
+    global token positions — or (B, S) when sequences in the batch sit
+    at different positions (the serving decode pool: each slot carries
+    its own sequence, so each rotates at its own phase). The first
+    ``partial`` of each head is rotated in halves, the rest passes
+    through. Angles in f32 (bf16 loses phase accuracy fast at long
+    context), rotated result back in x.dtype."""
+    inv, factor = rope.inv_freq(x.shape[-1])
+    half = inv.shape[0]
     freqs = positions.astype(jnp.float32)[..., None] * inv  # (..., S, half)
-    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    cos, sin = jnp.cos(freqs) * factor, jnp.sin(freqs) * factor
     if freqs.ndim == 3:
         # (B, S, half) phases meet (B, H, S, hd/2) halves: insert the
         # head axis so each batch row broadcasts over its own heads
         cos, sin = cos[:, None], sin[:, None]
     x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
+    x2 = x[..., half : 2 * half].astype(jnp.float32)
     return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos, x[..., 2 * half :]],
+        axis=-1,
     ).astype(x.dtype)
 
 
-def _block_apply(x, blk: LMBlock, cdt, attn, moe=None, mm_fn=mm):
-    """Pre-LN residual block shared by training forward, prefill, and
-    decode: ``attn(y, blk) -> (attention output (N,S,d), aux)``. When
-    ``moe`` is given it replaces the dense FFN; returns
-    (x, attn_aux, moe_aux_loss)."""
-    a, aux = attn(_ln(x, cdt), blk)
+def _block_apply(x, blk: LMBlock, cdt, attn, mm_fn=mm, eps: float = 1e-6):
+    """Pre-norm residual block shared by training forward, prefill, and
+    decode: ``attn(y, blk) -> (attention output (N,S,d), aux)``. Routed
+    experts (``blk.moe``) take the dense FFN's place; returns
+    (x, attn_aux, the expert layer's counters or None)."""
+    a, aux = attn(_norm(x, blk.norm1, eps, cdt), blk)
     x = x + a
-    y = _ln(x, cdt)
-    if moe is not None:
-        f, moe_aux = moe(y)
-        return x + f, aux, moe_aux
-    hdn = mm_fn(y, blk.w1, cdt)
-    return x + mm_fn(jax.nn.gelu(hdn), blk.w2, cdt), aux, jnp.float32(0)
+    y = _norm(x, blk.norm2, eps, cdt)
+    if blk.moe is not None:
+        f, counters = blk.moe(y)
+        return x + f, aux, counters
+    with jax.named_scope("dense_ffn"):
+        return x + ffn(y, blk.w1, blk.w2, blk.w3, cdt, mm_fn), aux, None
 
 
 def _gather_embed(embed, tokens):
@@ -142,8 +217,9 @@ def _embed(model, tokens, cdt):
     """Token embedding + optional learned positions, cast to the compute
     dtype — the one preamble shared by training forward, prefill, and the
     pipeline-parallel forward."""
-    d = model.embed.shape[-1]
-    x = _gather_embed(model.embed, tokens) * math.sqrt(d)
+    x = _gather_embed(model.embed, tokens)
+    if model.embed_scale:
+        x = x * math.sqrt(model.embed.shape[-1])
     if model.pos_encoding == "learned":
         x = x + model.pos_embed[: tokens.shape[1]]
     return x.astype(cdt)
@@ -163,13 +239,32 @@ def _tied_logits(x, embed, cdt):
     )
 
 
+def output_logits(model, x, cdt):
+    """Final norm, then the output head: the model's own ``head`` (d, V)
+    when it has one, else the embedding transposed (tied). f32 out."""
+    if model.head is None:
+        return _tied_logits(x, model.embed, cdt)
+    xn = _norm(x, model.final_norm, model.norm_eps, cdt)
+    return jnp.matmul(
+        xn, model.head.astype(cdt), preferred_element_type=jnp.float32
+    )
+
+
 @treenode
 class TransformerLM:
-    """Pre-LN decoder-only LM; logits tied to the token embedding."""
+    """Pre-norm decoder-only LM. Two ways in, one block definition:
+    :meth:`create` (toy presets: parameter-free LayerNorm, GELU, tied
+    logits, one head count) and :meth:`from_config` (a public
+    ``config.json``: learned RMSNorm, SwiGLU, an untied head, a head
+    count and an attention kind per layer, routed experts)."""
 
     embed: jnp.ndarray  # (V, d)
     pos_embed: jnp.ndarray  # (S_max, d)
     blocks: tuple  # of LMBlock
+    # the output head (d, V) and the final RMSNorm's scale; None = logits
+    # tied to the embedding behind the parameter-free LayerNorm
+    head: jnp.ndarray | None = None
+    final_norm: jnp.ndarray | None = None
     num_heads: int = static_field(default=8)
     # attention strategy: "local" (dense or Pallas flash on TPU),
     # "ring" / "ulysses" (sequence-parallel over `seq_axis` of `mesh`).
@@ -192,13 +287,9 @@ class TransformerLM:
     remat_policy: str = static_field(default="full")
     # mixed precision: params/optimizer state stay float32; activations
     # and the matmul operands run in this dtype ("bfloat16" halves HBM
-    # traffic and feeds the MXU its native input width). LayerNorm stats
+    # traffic and feeds the MXU its native input width). Norm statistics
     # and the loss reduction stay float32 regardless.
     compute_dtype: str = static_field(default="float32")
-    # expert parallelism: per-block MoE layers (None entries keep the
-    # dense FFN). Tuple parallel to `blocks`; empty = no MoE anywhere.
-    moe_layers: tuple = ()
-    moe_aux_weight: float = static_field(default=0.01)
     # "learned" = trained absolute table (pos_embed, capped at max_seq);
     # "rope" = rotary q/k phases — no table, no length cap beyond memory,
     # the right pairing for the blockwise long-context backward
@@ -212,53 +303,97 @@ class TransformerLM:
     # int8 via the fused kernel (ops/int8_matmul.mm_fused) — the A/B the
     # bench measures e2e (ROOFLINE.md §6 decode note)
     int8_kernel: str = static_field(default="xla")
+    # the toy presets scale embeddings by sqrt(d); public configs do not
+    embed_scale: bool = static_field(default=True)
+    norm_eps: float = static_field(default=1e-6)
 
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    def layer_spec(self, blk: LMBlock) -> LayerSpec:
+        """The block's own attention spec; a block built field by field
+        (tests, old pickles) follows the model-wide head counts."""
+        if blk.spec is not None:
+            return blk.spec
+        return LayerSpec(
+            self.num_heads,
+            self.kv_heads,
+            rope=RopeSpec() if self.pos_encoding == "rope" else None,
+        )
+
+    def uniform_decode_reason(self) -> str | None:
+        """None when every layer is the kind the KV-cache path computes
+        (one head count, no window, no head gate, plain rotary or learned
+        positions, tied logits); else what it cannot serve, by name: the
+        first layer of each kind it has no cache or step for."""
+        why: dict[str, str] = {}
+        for i, blk in enumerate(self.blocks):
+            spec = self.layer_spec(blk)
+            if spec.window:
+                why.setdefault(
+                    "window", f"layer {i} attends through a window of {spec.window}"
+                )
+            if (spec.num_heads, spec.num_kv_heads) != (
+                self.num_heads, self.kv_heads
+            ):
+                why.setdefault(
+                    "heads",
+                    f"layer {i} has {spec.num_heads} heads, the cache is "
+                    f"laid out for {self.num_heads}",
+                )
+            if blk.wg is not None:
+                why.setdefault("gate", f"layer {i} gates its heads' outputs")
+            if spec.rope is not None and spec.rope != RopeSpec():
+                why.setdefault("rope", f"layer {i} rotates by {spec.rope}")
+        if self.head is not None:
+            why["head"] = "the output head is untied"
+        return "; ".join(why.values()) or None
 
     def _qkv_heads(self, x, blk: LMBlock, positions=None):
         """(q with H heads, k/v with KV heads, rope applied).
         ``positions`` defaults to 0..S-1 (full-sequence forward); decode
         passes the single global position of its new token."""
         mm_fn = model_mm(self)
-        q = _split_heads(x, blk.wq, self.num_heads, mm_fn)
-        k = _split_heads(x, blk.wk, self.kv_heads, mm_fn)
-        v = _split_heads(x, blk.wv, self.kv_heads, mm_fn)
-        if self.pos_encoding == "rope":
+        spec = self.layer_spec(blk)
+        q = _split_heads(x, blk.wq, spec.num_heads, mm_fn)
+        k = _split_heads(x, blk.wk, spec.num_kv_heads, mm_fn)
+        v = _split_heads(x, blk.wv, spec.num_kv_heads, mm_fn)
+        if spec.rope is not None:
             if positions is None:
                 positions = jnp.arange(x.shape[1])
-            q = _rope(q, positions)
-            k = _rope(k, positions)
+            q = _rope(q, positions, spec.rope)
+            k = _rope(k, positions, spec.rope)
         return q, k, v
 
     def _attention(self, x, blk: LMBlock, return_kv: bool = False):
-        n, s, d = x.shape
-        h = self.num_heads
+        n, s, _ = x.shape
+        spec = self.layer_spec(blk)
+        h, window = spec.num_heads, spec.window
 
         # x is always the full (global) sequence here — the
         # sequence-parallel paths shard inside ring/ulysses_attention
         q, k, v = self._qkv_heads(x, blk)
-        kv_raw = (k, v)  # pre-broadcast: what the decode cache stores
-        if self.kv_heads != h:
-            # training/prefill compute broadcasts K/V up to H heads
-            # (activation-sized, the standard GQA training treatment);
-            # the grouped decode path never materializes this
-            g = h // self.kv_heads
-            k = jnp.repeat(k, g, axis=1)
-            v = jnp.repeat(v, g, axis=1)
+        kv_raw = (k, v)  # what the decode cache stores
         # sequence-parallel training runs the custom-VJP bodies: the ring
         # backward circulates dk/dv accumulators around the ring (the
         # per-hop Pallas forward kernels are forward-only), Ulysses
         # differentiates the flash trainable wrapper through all_to_all.
         # use_flash auto-selects: Pallas-rate on TPU, jnp off it.
-        if self.seq_mode == "ring":
-            out = ring_attention(
-                q, k, v, self.mesh, seq_axis=self.seq_axis, causal=True,
-                trainable=True,
+        if self.seq_mode in ("ring", "ulysses"):
+            if window:
+                raise ValueError(
+                    f"seq_mode={self.seq_mode!r} has no windowed attention"
+                )
+            if spec.num_kv_heads != h:
+                # the sequence-parallel bodies want a K and V per head
+                g = h // spec.num_kv_heads
+                k = jnp.repeat(k, g, axis=1)
+                v = jnp.repeat(v, g, axis=1)
+            attend = (
+                ring_attention if self.seq_mode == "ring" else ulysses_attention
             )
-        elif self.seq_mode == "ulysses":
-            out = ulysses_attention(
+            out = attend(
                 q, k, v, self.mesh, seq_axis=self.seq_axis, causal=True,
                 trainable=True,
             )
@@ -278,14 +413,19 @@ class TransformerLM:
                     f"KST_LOCAL_ATTN={mode!r}; expected auto|flash|dense"
                 )
             use_flash = on_tpu() if mode == "auto" else mode == "flash"
+            # the scope names the layer's kind in the trace's op names
+            scope = "attn_window" if window else "attn_full"
             if use_flash:
                 # fused Pallas forward with a recompute VJP — training
-                # never materializes the (S, S) probabilities
+                # never materializes the (S, S) probabilities; grouped K
+                # and V go in as they are, never repeated up to H heads
                 from keystone_tpu.ops.flash_attention import (
                     flash_attention_trainable,
                 )
 
                 def attend(q, k, v):
+                    if window:
+                        return flash_attention_trainable(q, k, v, True, window)
                     return flash_attention_trainable(q, k, v, True)
 
                 if self.mesh is not None:
@@ -299,24 +439,38 @@ class TransformerLM:
                     from jax.sharding import PartitionSpec as P
 
                     sizes = dict(self.mesh.shape)
-                    spec = P(
+                    n_model = sizes.get("model", h + 1)
+                    by_head = (
+                        "model"
+                        if h % n_model == 0 and spec.num_kv_heads % n_model == 0
+                        else None
+                    )
+                    pspec = P(
                         "data" if n % sizes.get("data", n + 1) == 0 else None,
-                        "model" if h % sizes.get("model", h + 1) == 0 else None,
+                        by_head,
                         None,
                         None,
                     )
                     attend = jax.shard_map(
                         attend,
                         mesh=self.mesh,
-                        in_specs=(spec, spec, spec),
-                        out_specs=spec,
+                        in_specs=(pspec, pspec, pspec),
+                        out_specs=pspec,
                         check_vma=False,  # pallas_call outputs carry no vma
                     )
-                out = attend(q, k, v)
+                with jax.named_scope(scope):
+                    out = attend(q, k, v)
             else:
-                out = dense_attention(q, k, v, causal=True)
+                with jax.named_scope(scope):
+                    out = dense_attention(
+                        q, k, v, causal=True, window=window
+                    )
+        if blk.wg is not None:
+            # one sigmoid gate a head, on that head's output
+            gate = jax.nn.sigmoid(model_mm(self)(x, blk.wg, x.dtype))
+            out = out * gate.transpose(0, 2, 1)[..., None].astype(out.dtype)
         proj = model_mm(self)(
-            out.transpose(0, 2, 1, 3).reshape(n, s, d).astype(x.dtype),
+            out.transpose(0, 2, 1, 3).reshape(n, s, -1).astype(x.dtype),
             blk.wo,
             x.dtype,
         )
@@ -324,42 +478,47 @@ class TransformerLM:
             return proj, kv_raw
         return proj
 
-    def _moe(self, i: int):
-        return self.moe_layers[i] if self.moe_layers else None
-
     def __call__(self, tokens):
         """(B, S) int tokens → (B, S, V) float32 logits."""
         return self.forward_with_aux(tokens)[0]
 
     def backbone(self, tokens):
-        """(final hidden states (B, S, d) pre-logits, MoE aux loss) —
-        the forward minus the tied-logits projection, so losses can
-        choose how (or whether) to materialize logits."""
+        """(final hidden states (B, S, d) before the final norm and the
+        head, the expert layers' counters summed over layers) — the
+        forward minus the logits projection, so losses can choose how
+        (or whether) to materialize logits."""
         cdt = jnp.dtype(self.compute_dtype)
         x = _embed(self, tokens, cdt)
 
-        def block_fn(x, blk, moe):
-            out, _, moe_aux = _block_apply(
+        def block_fn(x, blk):
+            out, _, counters = _block_apply(
                 x, blk, cdt,
                 lambda y, b: (self._attention(y, b), None),
-                moe=moe,
                 mm_fn=model_mm(self),
+                eps=self.norm_eps,
             )
-            return out, moe_aux
+            return out, counters
 
         if self.remat:
             block_fn = remat_wrap(block_fn, self.remat_policy)
-        aux = jnp.float32(0)
-        for i, blk in enumerate(self.blocks):
-            x, moe_aux = block_fn(x, blk, self._moe(i))
-            aux = aux + moe_aux
-        return x, aux
+        total = {c: jnp.int32(0) for c in COUNTERS}
+        for blk in self.blocks:
+            x, counters = block_fn(x, blk)
+            if counters is not None:
+                total = {
+                    "routed_rows": total["routed_rows"] + counters["routed_rows"],
+                    "max_expert_rows": jnp.maximum(
+                        total["max_expert_rows"], counters["max_expert_rows"]
+                    ),
+                    "mm_rows": total["mm_rows"] + counters["mm_rows"],
+                }
+        return x, total
 
     def forward_with_aux(self, tokens):
-        """(logits (B, S, V) f32, total MoE load-balance aux loss)."""
-        x, aux = self.backbone(tokens)
+        """(logits (B, S, V) f32, the expert layers' counters)."""
+        x, counters = self.backbone(tokens)
         cdt = jnp.dtype(self.compute_dtype)
-        return _tied_logits(x, self.embed, cdt), aux
+        return output_logits(self, x, cdt), counters
 
     @staticmethod
     def create(
@@ -376,13 +535,13 @@ class TransformerLM:
         compute_dtype: str = "float32",
         moe_every: int = 0,
         num_experts: int = 8,
-        capacity_factor: float = 1.25,
         pos_encoding: str = "learned",
         num_kv_heads: int = 0,
     ) -> "TransformerLM":
-        """``moe_every=k`` replaces the dense FFN of every k-th block with
-        a top-2 routed :class:`~keystone_tpu.ops.moe.MoELayer` of
-        ``num_experts`` experts (0 = dense everywhere).
+        """The toy presets. ``moe_every=k`` replaces the dense FFN of
+        every k-th block with top-2 routed experts
+        (:class:`~keystone_tpu.ops.moe.MoELayer`, ``num_experts`` of
+        them, all held here; 0 = dense everywhere).
         ``pos_encoding="rope"`` drops the learned table (and its max_seq
         cap) for rotary q/k phases."""
         if pos_encoding not in ("learned", "rope"):
@@ -404,6 +563,9 @@ class TransformerLM:
         # normalizes to 0 (num_kv_heads=H and =0 are the same model)
         num_kv_heads = 0 if kvh == num_heads else kvh
         kv_dim = kvh * (dim // num_heads)
+        spec = LayerSpec(
+            num_heads, kvh, rope=RopeSpec() if pos_encoding == "rope" else None
+        )
         # the split count and per-block stride must not depend on
         # moe_every: dense models seeded before MoE existed must keep
         # bit-identical weights, so MoE keys are folded in separately
@@ -413,7 +575,6 @@ class TransformerLM:
             return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
 
         blocks = []
-        moes = []
         for i in range(depth):
             ks = keys[2 + 6 * i : 8 + 6 * i]
             is_moe = bool(moe_every) and (i + 1) % moe_every == 0
@@ -432,19 +593,15 @@ class TransformerLM:
                     w2=jnp.zeros((0, dim), jnp.float32)
                     if is_moe
                     else init(ks[5], (ff_mult * dim, dim), ff_mult * dim),
+                    moe=MoELayer.create(
+                        jax.random.fold_in(key, 1_000_003 + i),
+                        dim, ff_mult * dim, num_experts,
+                    )
+                    if is_moe
+                    else None,
+                    spec=spec,
                 )
             )
-            if is_moe:
-                from keystone_tpu.ops.moe import MoELayer
-
-                moes.append(
-                    MoELayer.create(
-                        jax.random.fold_in(key, 1_000_003 + i),
-                        dim, ff_mult * dim, num_experts, capacity_factor,
-                    )
-                )
-            else:
-                moes.append(None)
         return TransformerLM(
             embed=0.02 * jax.random.normal(keys[0], (vocab, dim)),
             # rope keeps a zero-width placeholder: no table params, no cap
@@ -457,9 +614,121 @@ class TransformerLM:
             mesh=mesh,
             seq_axis=seq_axis,
             compute_dtype=compute_dtype,
-            moe_layers=tuple(moes) if moe_every else (),
             pos_encoding=pos_encoding,
             num_kv_heads=num_kv_heads,
+        )
+
+    @staticmethod
+    def from_config(
+        key,
+        config: dict,
+        *,
+        mesh=None,
+        compute_dtype: str = "float32",
+        remat: bool = False,
+    ) -> "TransformerLM":
+        """A model from a ``config.json``-shaped description (the keys of
+        a public sparse decoder: ``hidden_size``, ``head_dim``,
+        ``layer_types``, ``num_attention_heads_per_layer``,
+        ``mlp_layer_types``, ``rope_parameters`` by layer type,
+        ``num_experts`` ...), at the sizes the description gives **as
+        held here**: ``num_hidden_layers`` layers from the front of the
+        per-layer lists, ``num_experts`` routed experts a layer,
+        ``vocab_size`` ids. Where that is one chip's share of a
+        deployment, ``published`` gives the model's own counts (the
+        router keeps ``published.num_experts`` outputs) and
+        ``deployment.expert_shard`` says which share of the experts this
+        is. Seeded random weights: no checkpoint is read."""
+        c = config
+        d, hd = c["hidden_size"], c["head_dim"]
+        depth, vocab = c["num_hidden_layers"], c["vocab_size"]
+        kvh = c["num_key_value_heads"]
+        heads = c.get("num_attention_heads_per_layer") or (
+            [c["num_attention_heads"]] * depth
+        )
+        kinds = c.get("layer_types") or ["full_attention"] * depth
+        mlps = c.get("mlp_layer_types") or ["dense"] * depth
+        held = c.get("num_experts", 0)
+        routed = c.get("published", {}).get("num_experts", held)
+        shard = c.get("deployment", {}).get("expert_shard", 0)
+
+        def rope_of(kind: str) -> RopeSpec:
+            r = c["rope_parameters"][kind]
+            yarn = None
+            if r.get("rope_type", "default") == "yarn":
+                yarn = (
+                    r["factor"], r["original_max_position_embeddings"],
+                    r["beta_fast"], r["beta_slow"], r["attention_factor"],
+                )
+            elif r.get("rope_type", "default") != "default":
+                raise ValueError(f"rope_type {r['rope_type']!r}")
+            return RopeSpec(
+                float(r["rope_theta"]), float(r.get("partial_rotary_factor", 1.0)),
+                yarn,
+            )
+
+        def init(k, shape, fan_in):
+            return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
+
+        k_embed, k_head, *k_layers = jax.random.split(key, 2 + depth)
+        blocks = []
+        for i in range(depth):
+            h = heads[i]
+            if h % kvh:
+                raise ValueError(f"layer {i}: {h} heads over {kvh} K/V heads")
+            sliding = kinds[i] == "sliding_attention"
+            ks = jax.random.split(k_layers[i], 9)
+            sparse = mlps[i] == "sparse"
+            ff = c["intermediate_size"]
+            blocks.append(
+                LMBlock(
+                    wq=init(ks[0], (d, h * hd), d),
+                    wk=init(ks[1], (d, kvh * hd), d),
+                    wv=init(ks[2], (d, kvh * hd), d),
+                    wo=init(ks[3], (h * hd, d), h * hd),
+                    w1=jnp.zeros((d, 0), jnp.float32)
+                    if sparse
+                    else init(ks[4], (d, ff), d),
+                    w2=jnp.zeros((0, d), jnp.float32)
+                    if sparse
+                    else init(ks[5], (ff, d), ff),
+                    w3=None if sparse else init(ks[6], (d, ff), d),
+                    wg=init(ks[7], (d, h), d) if c.get("gating") else None,
+                    norm1=jnp.ones((d,), jnp.float32),
+                    norm2=jnp.ones((d,), jnp.float32),
+                    moe=MoELayer.create(
+                        ks[8], d, c["moe_intermediate_size"], routed,
+                        held=held, first_expert=shard * held,
+                        top_k=c["num_experts_per_tok"], swiglu=True,
+                        shared_ff=c.get("shared_expert_intermediate_size", 0),
+                        scoring="sigmoid",
+                        routed_scale=c.get("moe_routed_scaling_factor", 1.0),
+                        router_std=1.0 / math.sqrt(d),
+                    )
+                    if sparse
+                    else None,
+                    spec=LayerSpec(
+                        h, kvh,
+                        window=c["sliding_window"] if sliding else 0,
+                        rope=rope_of(kinds[i]),
+                    ),
+                )
+            )
+        tied = c.get("tie_word_embeddings", False)
+        return TransformerLM(
+            embed=0.02 * jax.random.normal(k_embed, (vocab, d)),
+            pos_embed=jnp.zeros((0, d), jnp.float32),
+            blocks=tuple(blocks),
+            head=None if tied else init(k_head, (d, vocab), d),
+            final_norm=None if tied else jnp.ones((d,), jnp.float32),
+            num_heads=heads[0],
+            mesh=mesh,
+            remat=remat,
+            compute_dtype=compute_dtype,
+            pos_encoding="rope",
+            num_kv_heads=0 if kvh == heads[0] else kvh,
+            embed_scale=False,
+            norm_eps=c.get("rms_norm_eps", 1e-6),
         )
 
     def num_params(self) -> int:
@@ -493,18 +762,28 @@ def has_quantized_leaves(model) -> bool:
 
 
 def train_step_flops(model: TransformerLM, batch: int, seq: int) -> float:
-    """Analytic FLOPs of one train step: ~6·P_active·tokens for the matmul
-    work plus the attention score/value terms (12·L·d·S²·B fwd+bwd). MoE
-    expert gemms execute over ALL E·C static capacity slots (drops included
-    — that's the static-shape trade), so expert params count at C/G weight,
-    not the idealized 2/E."""
-    p = model.num_params()
+    """Model FLOPs of one train step: six times the parameters a token
+    touches (a routed layer's experts at ``top_k`` times the share held
+    here, under even routing; the embedding table is a gather unless the
+    logits are tied to it), plus the causal score and value products
+    (a window layer reckoned at its window). Recomputation not counted."""
     tokens = batch * seq
-    for m in model.moe_layers:
+    touched = 0.0
+    attn = 0.0
+    for blk in model.blocks:
+        n = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(blk))
+        m = blk.moe
         if m is not None:
-            expert_p = int(np.prod(m.w1.shape)) + int(np.prod(m.w2.shape))
-            slots = m.num_experts * m._capacity(tokens)
-            p -= expert_p * (1.0 - min(slots / (tokens * m.num_experts), 1.0))
-    d = model.embed.shape[-1]
-    attn = 12 * len(model.blocks) * d * seq * seq * batch
-    return 6.0 * p * tokens + attn
+            experts = sum(
+                int(np.prod(w.shape)) for w in (m.w1, m.w2, m.w3) if w is not None
+            )
+            n -= experts * (1.0 - m.top_k / m.num_experts)
+        touched += n
+        spec = model.layer_spec(blk)
+        keys = (seq + 1) / 2  # mean keys a causal query sees
+        if spec.window:
+            keys = min(keys, spec.window)
+        attn += 12 * blk.wq.shape[1] * keys * tokens
+    head = model.embed if model.head is None else model.head
+    touched += int(np.prod(head.shape))
+    return 6.0 * touched * tokens + attn

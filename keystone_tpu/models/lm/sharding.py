@@ -14,7 +14,7 @@ import dataclasses
 
 import jax
 
-from keystone_tpu.models.lm.model import LMBlock, TransformerLM
+from keystone_tpu.models.lm.model import TransformerLM
 
 
 def shard_params(model: TransformerLM, mesh) -> TransformerLM:
@@ -22,7 +22,8 @@ def shard_params(model: TransformerLM, mesh) -> TransformerLM:
     axis: attention q/k/v column-sharded (head-parallel) with wo
     row-sharded, MLP column- then row-sharded, embedding vocab-sharded.
     XLA then inserts exactly the two psums per block that hand-written
-    Megatron-style TP would — the layout IS the parallelism.
+    Megatron-style TP would — the layout IS the parallelism. A block's
+    routed experts are left as they are.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -43,37 +44,32 @@ def shard_params(model: TransformerLM, mesh) -> TransformerLM:
         )
         return jax.device_put(x, NamedSharding(mesh, spec))
 
+    def opt(x, spec):
+        return None if x is None else put(x, spec)
+
     blocks = tuple(
-        LMBlock(
+        dataclasses.replace(
+            b,
             wq=put(b.wq, P(None, "model")),
             wk=put(b.wk, P(None, "model")),
             wv=put(b.wv, P(None, "model")),
             wo=put(b.wo, P("model", None)),
             w1=put(b.w1, P(None, "model")),
             w2=put(b.w2, P("model", None)),
+            w3=opt(b.w3, P(None, "model")),
+            # routed experts stay whole on every device: the grouped
+            # product is a Mosaic kernel, which GSPMD cannot partition,
+            # and the exchange that expert parallelism needs is not
+            # written yet (ROADMAP C8)
         )
         for b in model.blocks
-    )
-    moes = tuple(
-        m
-        if m is None
-        else dataclasses.replace(
-            m,
-            # expert-parallel: one expert group per model-axis device;
-            # the router stays replicated (every token scores every
-            # expert) — XLA places the dispatch/combine all_to_alls
-            w_router=put(m.w_router, P()),
-            w1=put(m.w1, P("model", None, None)),
-            w2=put(m.w2, P("model", None, None)),
-        )
-        for m in model.moe_layers
     )
     return dataclasses.replace(
         model,
         embed=put(model.embed, P("model", None)),
         pos_embed=put(model.pos_embed, P()),
         blocks=blocks,
-        moe_layers=moes,
+        head=opt(model.head, P(None, "model")),
     )
 
 

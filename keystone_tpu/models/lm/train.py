@@ -11,6 +11,7 @@ sequential RNG state.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import numpy as np
@@ -18,7 +19,7 @@ import optax
 
 from keystone_tpu.core.logging import get_logger
 from keystone_tpu.models.lm.losses import (
-    next_token_loss,
+    next_token_loss_and_counters,
     token_cross_entropy,
 )
 from keystone_tpu.models.lm.model import (
@@ -27,7 +28,6 @@ from keystone_tpu.models.lm.model import (
     _embed,
     _tied_logits,
     has_quantized_leaves,
-    train_step_flops,
 )
 
 logger = get_logger("keystone_tpu.models.lm_transformer")
@@ -47,10 +47,9 @@ def pp_forward(model: TransformerLM, tokens, mesh, *, n_micro: int,
     """
     import jax.numpy as jnp
 
-    if any(m is not None for m in model.moe_layers):
+    if any(b.moe is not None for b in model.blocks):
         raise ValueError(
-            "pipeline-parallel path supports dense blocks only (route "
-            "experts over the model axis with moe_every instead)"
+            "pipeline-parallel path supports dense blocks only"
         )
     if model.seq_mode != "local":
         raise ValueError(
@@ -143,6 +142,62 @@ def make_pp_train_step(optimizer, mesh, *, n_micro: int,
     return step
 
 
+class StepStats(NamedTuple):
+    """What a step says of itself beside the loss, on the device: the
+    squared norm of every parameter's gradient (the parameters' own
+    tree) and the expert layers' counters (``ops/moe.py::COUNTERS``)."""
+
+    grad_sq: object
+    counters: dict
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("optimizer", "logit_chunk", "skip_nonfinite"),
+    donate_argnums=(0, 1),
+)
+def _train_step(model, opt_state, tokens, poison, *, optimizer, logit_chunk,
+                skip_nonfinite):
+    """The one train-step program: grads + optimizer update + loss. A
+    module-level jit, so jax's own cache answers every ``train()`` call
+    after a process's first with the same optimizer (an
+    :class:`OptimizerSpec`, hashed by its fields), shapes and model
+    description. ``poison`` is None for the plain step; the guarded step
+    (a different program) takes a scalar bool that NaNs the loss *and*
+    the grads, and with ``skip_nonfinite`` applies the update only where
+    the loss is finite."""
+    import jax.numpy as jnp
+
+    def lossfn(m, t):
+        loss, counters = next_token_loss_and_counters(
+            m, t, logit_chunk=logit_chunk
+        )
+        if poison is not None:
+            # poison scales rather than adds so the backward pass NaNs
+            # too — an injected bad batch corrupts exactly what a real
+            # one would
+            loss = loss * jnp.where(
+                poison, jnp.float32(np.nan), jnp.float32(1.0)
+            )
+        return loss, counters
+
+    (loss, counters), grads = jax.value_and_grad(lossfn, has_aux=True)(
+        model, tokens
+    )
+    updates, new_opt = optimizer.tx.update(grads, opt_state, params=model)
+    new_model = optax.apply_updates(model, updates)
+    if poison is not None and skip_nonfinite:
+        from keystone_tpu.resilience.guards import guarded_update
+
+        ok = jnp.isfinite(loss)
+        new_model = guarded_update(ok, new_model, model)
+        new_opt = guarded_update(ok, new_opt, opt_state)
+    grad_sq = jax.tree_util.tree_map(
+        lambda g: jnp.sum(jnp.square(g.astype(jnp.float32))), grads
+    )
+    return new_model, new_opt, loss, StepStats(grad_sq, counters)
+
+
 def make_train_step(
     optimizer, *, logit_chunk: int = 0, guarded: bool = False,
     skip_nonfinite: bool = True,
@@ -161,49 +216,27 @@ def make_train_step(
     leafwise ``where`` select — with buffer donation the pre-update
     state is unrecoverable on the host, so skip-batch MUST be decided
     in-program); with it False an injected NaN corrupts exactly what a
-    real bad batch would. Still one XLA launch per step."""
-    if not guarded:
+    real bad batch would. Still one XLA launch per step.
 
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def step(model, opt_state, tokens):
-            loss, grads = jax.value_and_grad(
-                functools.partial(next_token_loss, logit_chunk=logit_chunk)
-            )(model, tokens)
-            updates, opt_state = optimizer.update(
-                grads, opt_state, params=model
-            )
-            model = optax.apply_updates(model, updates)
-            return model, opt_state, loss
-
-        return step
-
-    import jax.numpy as jnp
-
-    from keystone_tpu.resilience.guards import guarded_update
-
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def guarded_step(model, opt_state, tokens, poison):
-        def lossfn(m, t):
-            loss = next_token_loss(m, t, logit_chunk=logit_chunk)
-            # poison scales rather than adds so the backward pass NaNs
-            # too — an injected bad batch corrupts exactly what a real
-            # one would
-            return loss * jnp.where(
-                poison, jnp.float32(np.nan), jnp.float32(1.0)
-            )
-
-        loss, grads = jax.value_and_grad(lossfn)(model, tokens)
-        updates, new_opt = optimizer.update(
-            grads, opt_state, params=model
-        )
-        new_model = optax.apply_updates(model, updates)
-        if skip_nonfinite:
-            ok = jnp.isfinite(loss)
-            new_model = guarded_update(ok, new_model, model)
-            new_opt = guarded_update(ok, new_opt, opt_state)
-        return new_model, new_opt, loss
-
-    return guarded_step
+    The program itself is :func:`_train_step`, made once per process:
+    an ``optimizer`` from :func:`make_optimizer` compares equal to
+    another of the same settings, so this returns a thin binding and
+    compiles nothing new on a later call."""
+    if not isinstance(optimizer, OptimizerSpec):
+        # a bare optax transformation: hashed by identity, so the
+        # program is this object's alone
+        optimizer = OptimizerSpec(optimizer)
+    bound = functools.partial(
+        _train_step, optimizer=optimizer, logit_chunk=logit_chunk,
+        skip_nonfinite=skip_nonfinite,
+    )
+    if guarded:
+        return lambda model, opt_state, tokens, poison: bound(
+            model, opt_state, tokens, poison
+        )[:3]
+    return lambda model, opt_state, tokens: bound(
+        model, opt_state, tokens, None
+    )[:3]
 
 
 def _step_batch(corpus, seed: int, i: int, batch: int, seq: int):
@@ -215,6 +248,34 @@ def _step_batch(corpus, seed: int, i: int, batch: int, seq: int):
     return np.stack([corpus[s : s + seq + 1] for s in starts])
 
 
+class OptimizerSpec:
+    """The LM optimizer: an optax transformation and, when
+    :func:`make_optimizer` made it, the settings it was made from. Two
+    specs of the same settings are equal and hash alike, so they key the
+    same compiled step; a spec around a caller's own transformation is
+    equal to itself alone. ``init`` / ``update`` are the
+    transformation's, so a spec goes wherever one went."""
+
+    def __init__(self, tx, settings: tuple | None = None):
+        self.tx = tx
+        self.settings = settings
+
+    def _key(self):
+        return ("id", id(self.tx)) if self.settings is None else self.settings
+
+    def __eq__(self, other):
+        return isinstance(other, OptimizerSpec) and self._key() == other._key()
+
+    def __hash__(self):
+        return hash(self._key())
+
+    def init(self, params):
+        return self.tx.init(params)
+
+    def update(self, grads, state, params=None):
+        return self.tx.update(grads, state, params=params)
+
+
 def make_optimizer(
     lr: float,
     *,
@@ -223,11 +284,12 @@ def make_optimizer(
     warmup_frac: float = 0.05,
     grad_clip: float = 0.0,
     weight_decay: float = 0.01,
-):
+) -> OptimizerSpec:
     """The LM training optimizer: AdamW, optionally behind global-norm
     gradient clipping, with a constant or warmup-cosine learning rate.
     ``schedule="cosine"`` warms up over ``warmup_frac`` of ``steps`` and
-    decays to lr/10 — the standard LM recipe."""
+    decays to lr/10 — the standard LM recipe. A constant schedule does
+    not depend on ``steps``, so it is left out of the spec there."""
     if schedule not in ("constant", "cosine"):
         raise ValueError(
             f"schedule={schedule!r}; expected constant|cosine"
@@ -235,17 +297,21 @@ def make_optimizer(
     if schedule == "cosine":
         if steps <= 0:
             raise ValueError("schedule='cosine' needs the total steps")
-        lr = optax.warmup_cosine_decay_schedule(
+        rate = optax.warmup_cosine_decay_schedule(
             init_value=0.0,
             peak_value=lr,
             warmup_steps=max(1, int(steps * warmup_frac)),
             decay_steps=steps,
             end_value=lr / 10.0,
         )
-    opt = optax.adamw(lr, weight_decay=weight_decay)
+    else:
+        rate, steps = lr, 0
+    opt = optax.adamw(rate, weight_decay=weight_decay)
     if grad_clip > 0.0:
         opt = optax.chain(optax.clip_by_global_norm(grad_clip), opt)
-    return opt
+    return OptimizerSpec(
+        opt, (float(lr), steps, schedule, warmup_frac, grad_clip, weight_decay)
+    )
 
 
 def train(
@@ -266,9 +332,21 @@ def train(
     logit_chunk: int = 0,
     guard=None,
     step_timeout_s: float = 0.0,
+    history: dict | None = None,
 ):
-    """Train on random windows of ``corpus`` (1-D int array). Returns
-    (model, losses). Batches are dp-sharded over the mesh ``data`` axis
+    """Train on random windows of ``corpus`` (1-D int array; one
+    synthetic or real stream, no packing of documents). Returns
+    (model, losses). A caller's ``history`` dict receives what the steps
+    said of themselves, read from the device once at the end:
+    ``grad_sq`` (step → the parameters' tree of squared gradient norms)
+    and ``counters`` (step → the expert layers' counters).
+
+    While spans are on (``observe/spans.py``: an event sink or a
+    profiler session) the call is one ``fit`` root span, or the
+    caller's if one is open, with the fit path's names under it:
+    ``fit.init`` (optimizer state), ``fit.solve`` around the step loop,
+    and under each ``train.step`` the step's ``fit.load`` (its windows)
+    and ``fit.h2d``; a recorded step waits for its loss. Batches are dp-sharded over the mesh ``data`` axis
     unless the model is sequence-parallel (then S is the sharded axis and
     the batch is replicated).
 
@@ -323,6 +401,7 @@ def train(
     import hashlib
     import os as _os
     import signal as _signal
+    import sys as _sys
     import threading as _threading
     import time as _time
 
@@ -367,12 +446,12 @@ def train(
     optimizer = make_optimizer(
         lr, steps=steps, schedule=schedule, grad_clip=grad_clip
     )
-    opt_state = optimizer.init(model)
-    step = make_train_step(
-        optimizer, logit_chunk=logit_chunk, guarded=guarded,
+    step = functools.partial(
+        _train_step, optimizer=optimizer, logit_chunk=logit_chunk,
         skip_nonfinite=skip_nonfinite,
     )
     losses = []
+    stats = []
     sharding = None
     if (
         mesh is not None
@@ -430,15 +509,8 @@ def train(
                 "pos_encoding": model.pos_encoding,
                 "remat": model.remat,
                 "remat_policy": model.remat_policy,
-                "moe_aux_weight": model.moe_aux_weight,
-                "moe_experts": [
-                    None if m is None else m.num_experts
-                    for m in model.moe_layers
-                ],
-                "moe_capacity": [
-                    None if m is None else m.capacity_factor
-                    for m in model.moe_layers
-                ],
+                # what each layer is: attention spec and expert layout
+                "layers": [_layer_identity(model, b) for b in model.blocks],
                 "corpus_len": int(len(corpus)),
                 "corpus_head_sha": hashlib.sha256(
                     corpus_head.tobytes()
@@ -474,6 +546,9 @@ def train(
                 "remat_policy": "full",
                 # pre-GQA checkpoints were all MHA
                 "num_kv_heads": model.num_heads,
+                # sidecars from before layers were described one by one
+                # held dense blocks of the model-wide head counts
+                "layers": [_layer_identity(model, b) for b in model.blocks],
             },
         )
     if step_timeout_s <= 0:
@@ -529,7 +604,6 @@ def train(
     # (KEYSTONE_PROFILE_STEPS / SIGUSR2). With no sink and no windows the
     # per-step cost is one global read (active_step_log) plus one no-op
     # tracer check.
-    step_flops = train_step_flops(model, batch, seq)
     devmon = _observe_devices.DeviceMemoryMonitor()
     # the self-tuning controller (KEYSTONE_TUNE=1): per-step host-vs-
     # compute walls + token goodput feed its rolling attribution window.
@@ -552,7 +626,25 @@ def train(
     import uuid as _uuid
 
     _train_trace = "train-" + _uuid.uuid4().hex[:8]
+    import contextlib as _contextlib
+
+    fit_root = (
+        _spans.span(
+            "fit",
+            parent=None,
+            steps=steps,
+            tokens_per_step=batch * seq,
+            chips=mesh.size if mesh is not None else 1,
+        )
+        if _spans.current() is None
+        else _contextlib.nullcontext()
+    )
+    _stack = _contextlib.ExitStack()
     try:
+        _stack.enter_context(fit_root)
+        with _spans.span("fit.init"):
+            opt_state = optimizer.init(model)
+            _spans.force(opt_state)
         if ckpt is not None:
             with _spans.span(
                 "train.restore", bucket="checkpoint", trace=_train_trace
@@ -565,21 +657,31 @@ def train(
                     "an over-trained model; point at a fresh directory"
                 )
         completed = last_saved = start
+        _stack.enter_context(_spans.span("fit.solve", bucket="compute"))
         for i in range(start, steps):
             if tracer is not None:
                 tracer.step(i)
             t_step0 = _time.perf_counter()
-            toks = jnp.asarray(_step_batch(corpus, seed, i, batch, seq))
-            if sharding is not None:
-                toks = jax.device_put(toks, sharding)
-            t_host = _time.perf_counter() - t_step0
-            if guarded:
-                poison = _faults.fire("train.nan", key=i)
-                model, opt_state, loss = step(
+            with _spans.span("train.step", step=i + 1) as s_ctx:
+                with _spans.span("fit.load", bucket="wait_host"):
+                    windows = _step_batch(corpus, seed, i, batch, seq)
+                with _spans.span(
+                    "fit.h2d", bucket="wait_host", bytes=windows.nbytes
+                ):
+                    toks = jnp.asarray(windows)
+                    if sharding is not None:
+                        toks = jax.device_put(toks, sharding)
+                    _spans.force(toks)
+                t_host = _time.perf_counter() - t_step0
+                poison = (
+                    _faults.fire("train.nan", key=i) if guarded else None
+                )
+                model, opt_state, loss, step_stats = step(
                     model, opt_state, toks, poison
                 )
-            else:
-                model, opt_state, loss = step(model, opt_state, toks)
+                # a recorded step ends when its device work has
+                _spans.force(loss)
+            stats.append(step_stats)
             # keep the loss on device: a float() here would block a host
             # round-trip into every step and serialize the dispatch queue
             # (exception: an active telemetry sink reads the scalar below
@@ -612,20 +714,13 @@ def train(
                     loss=loss_f,
                     tokens=batch * seq,
                     wall_s=wall,
-                    flops=step_flops,
                     hbm_peak_bytes=devmon.maybe_sample(),
                 )
                 # the step's causal record: host-side batch production
                 # vs dispatched device work, classified for the goodput
                 # report (structural root; children carry the buckets)
                 span_log = _spans.active_span_log()
-                if span_log is not None:
-                    s_ctx = span_log.record_span(
-                        "train.step",
-                        wall_s=wall,
-                        trace=_train_trace,
-                        step=i + 1,
-                    )
+                if span_log is not None and s_ctx is not None:
                     span_log.record_span(
                         "train.host_batch",
                         wall_s=t_host,
@@ -722,6 +817,7 @@ def train(
                 break
             _faults.maybe_preempt(key=i)
         loss_guard.flush()
+        _record_counters(model, stats)
     except _cluster.ClusterError as e:
         # a lost peer makes the coordinated rescue save impossible (its
         # barrier would wait on the dead host) — exit cleanly on the
@@ -783,13 +879,67 @@ def train(
                 tracer.close()
             for s, h in prev_handlers.items():
                 _signal.signal(s, h)
+            # the open spans (fit.solve, the fit root) end here and see
+            # the exception that is passing, if one is
+            _stack.__exit__(*_sys.exc_info())
     if loss_guard.skipped:
         logger.warning(
             "guard skipped %d non-finite step(s): %s",
             len(loss_guard.skipped),
             loss_guard.skipped,
         )
+    if history is not None:
+        got = jax.device_get(stats)
+        history["grad_sq"] = [g.grad_sq for g in got]
+        history["counters"] = [g.counters for g in got]
     return model, [float(l) for l in losses]
+
+
+def _layer_identity(model: TransformerLM, blk) -> list:
+    """What a checkpoint must agree on about one layer, as JSON."""
+    spec = model.layer_spec(blk)
+    m = blk.moe
+    return [
+        spec.num_heads,
+        spec.num_kv_heads,
+        spec.window,
+        repr(spec.rope),
+        None
+        if m is None
+        else [m.num_experts, m.held, m.first_expert, m.top_k, m.scoring,
+              m.routed_scale],
+    ]
+
+
+def _record_counters(model: TransformerLM, stats: list) -> None:
+    """The expert layers' counters of this fit as one zero-length
+    ``fit.counters`` span under the open one, read from the device once,
+    and only while spans are recorded and the model routes."""
+    from keystone_tpu.observe import spans as _spans
+
+    slots = sum(b.moe.held for b in model.blocks if b.moe is not None)
+    sl = _spans.active_span_log()
+    if sl is None or not slots or not stats:
+        return
+    got = jax.device_get([s.counters for s in stats])
+    routed = [int(c["routed_rows"]) for c in got]
+    sl.record_span(
+        "fit.counters",
+        wall_s=0.0,
+        parent=_spans.current(),
+        steps=len(got),
+        routed_rows=sum(routed),
+        mm_rows=sum(int(c["mm_rows"]) for c in got),
+        # largest load of a held expert over the mean load, a step
+        load_max_over_mean=float(
+            np.mean(
+                [
+                    int(c["max_expert_rows"]) * slots / max(r, 1)
+                    for c, r in zip(got, routed)
+                ]
+            )
+        ),
+    )
 
 
 def _emit_resilience(action: str, **fields) -> None:

@@ -103,6 +103,21 @@ class LMConfig:
         default=0,
         help="steps between checkpoints (0 = steps//10, ~10 per run)",
     )
+    config: str = arg(
+        default="",
+        help="a public architecture in place of the toy preset: the name "
+        "of a config.json-shaped file kept in the package "
+        "(models/lm/configs/<name>.json, e.g. laguna_xs2) or a path to "
+        "one; its sizes replace --dim/--depth/--num-heads/--vocab",
+    )
+    corpus_tokens: int = arg(
+        default=200_000,
+        help="length of the synthetic Markov stream (no --corpus)",
+    )
+    remat: bool = arg(
+        default=False,
+        help="rematerialize each block in the backward pass",
+    )
     logit_chunk: int = arg(
         default=0,
         help="compute the CE in this many-position chunks so the "
@@ -111,7 +126,64 @@ class LMConfig:
     )
 
 
-def run(conf: LMConfig, mesh=None) -> dict:
+def load_architecture(name_or_path: str) -> dict:
+    """The ``config.json``-shaped description ``--config`` names: a file
+    of ``models/lm/configs/`` by its stem, else a path."""
+    import json
+    import os
+
+    packaged = os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "lm", "configs",
+        name_or_path + ".json",
+    )
+    with open(packaged if os.path.isfile(packaged) else name_or_path) as f:
+        return json.load(f)
+
+
+def build_model(conf: LMConfig, mesh=None) -> TransformerLM:
+    """The model ``conf`` describes, from ``conf.seed``, laid out over
+    ``mesh``: a public architecture when ``--config`` names one, else
+    the toy preset of the size flags. One block definition serves both."""
+    key = jax.random.key(conf.seed)
+    if conf.config:
+        model = TransformerLM.from_config(
+            key,
+            load_architecture(conf.config),
+            # local attention needs the mesh too: on a TPU its Pallas
+            # flash kernel is shard_mapped over the mesh the batch is
+            # split on
+            mesh=mesh,
+            compute_dtype=conf.compute_dtype,
+            remat=conf.remat,
+        )
+    else:
+        model = TransformerLM.create(
+            key,
+            vocab=conf.vocab,
+            max_seq=conf.seq,
+            dim=conf.dim,
+            depth=conf.depth,
+            num_heads=conf.num_heads,
+            seq_mode=conf.seq_mode,
+            mesh=mesh,
+            compute_dtype=conf.compute_dtype,
+            moe_every=conf.moe_every,
+            num_experts=conf.num_experts,
+            pos_encoding=conf.pos_encoding,
+            num_kv_heads=conf.num_kv_heads,
+        )
+        if conf.remat:
+            model = dataclasses.replace(model, remat=True)
+    return shard_params(model, mesh)
+
+
+def fit(conf: LMConfig, mesh=None, history: dict | None = None):
+    """One fit, as ``run`` makes it: the model from ``conf.seed``, the
+    corpus, then ``conf.steps`` optimizer steps through :func:`train`.
+    Returns (model, losses, held-out tokens or None, seconds in
+    ``train``). While spans are on, the whole of it is one ``fit`` root
+    span (``fit.init`` covers the model and the corpus)."""
+    from keystone_tpu.observe.spans import force, span
     from keystone_tpu.parallel.mesh import create_mesh
 
     if conf.schedule not in ("constant", "cosine"):
@@ -121,51 +193,52 @@ def run(conf: LMConfig, mesh=None) -> dict:
         )
     if mesh is None and len(jax.devices()) > 1:
         mesh = create_mesh()
-    valid = None
-    if conf.corpus:
-        from keystone_tpu.loaders.text import BYTE_VOCAB, load_text_corpus
-
-        corpus, valid = load_text_corpus(conf.corpus)
-        conf = dataclasses.replace(conf, vocab=BYTE_VOCAB)
-    key = jax.random.key(conf.seed)
-    model = TransformerLM.create(
-        key,
-        vocab=conf.vocab,
-        max_seq=conf.seq,
-        dim=conf.dim,
-        depth=conf.depth,
-        num_heads=conf.num_heads,
-        seq_mode=conf.seq_mode,
-        # local attention needs the mesh too: on a TPU its Pallas flash
-        # kernel is shard_mapped over the mesh the batch is split on
-        mesh=mesh,
-        compute_dtype=conf.compute_dtype,
-        moe_every=conf.moe_every,
-        num_experts=conf.num_experts,
-        pos_encoding=conf.pos_encoding,
-        num_kv_heads=conf.num_kv_heads,
-    )
-    model = shard_params(model, mesh)
-    if not conf.corpus:
-        corpus = synthetic_corpus(200_000, conf.vocab, seed=conf.seed)
-    t0 = time.time()
-    model, losses = train(
-        model,
-        corpus,
+    with span(
+        "fit",
+        parent=None,
         steps=conf.steps,
-        batch=conf.batch,
-        seq=conf.seq,
-        lr=conf.lr,
-        mesh=mesh,
-        seed=conf.seed,
-        log_every=max(conf.steps // 5, 1),
-        checkpoint_dir=conf.checkpoint_dir,
-        checkpoint_every=conf.checkpoint_every,
-        schedule=conf.schedule,
-        grad_clip=conf.grad_clip,
-        logit_chunk=conf.logit_chunk,
-    )
-    dt = time.time() - t0
+        tokens_per_step=conf.batch * conf.seq,
+        chips=mesh.size if mesh is not None else 1,
+    ):
+        valid = None
+        with span("fit.init"):
+            if conf.corpus:
+                from keystone_tpu.loaders.text import (
+                    BYTE_VOCAB,
+                    load_text_corpus,
+                )
+
+                corpus, valid = load_text_corpus(conf.corpus)
+                conf = dataclasses.replace(conf, vocab=BYTE_VOCAB)
+            model = build_model(conf, mesh)
+            if not conf.corpus:
+                corpus = synthetic_corpus(
+                    conf.corpus_tokens, model.embed.shape[0], seed=conf.seed
+                )
+            force(model)
+        t0 = time.time()
+        model, losses = train(
+            model,
+            corpus,
+            steps=conf.steps,
+            batch=conf.batch,
+            seq=conf.seq,
+            lr=conf.lr,
+            mesh=mesh,
+            seed=conf.seed,
+            log_every=max(conf.steps // 5, 1),
+            checkpoint_dir=conf.checkpoint_dir,
+            checkpoint_every=conf.checkpoint_every,
+            schedule=conf.schedule,
+            grad_clip=conf.grad_clip,
+            logit_chunk=conf.logit_chunk,
+            history=history,
+        )
+        return model, losses, valid, time.time() - t0
+
+
+def run(conf: LMConfig, mesh=None) -> dict:
+    model, losses, valid, dt = fit(conf, mesh)
     steps_ran = len(losses)
     if not losses:
         # a resume that found the run already complete trains 0 steps

@@ -47,16 +47,26 @@ def _flash_default() -> bool:
     return on_tpu()
 
 
-def dense_attention(q, k, v, *, causal: bool = False):
-    """Reference multi-head attention. q,k,v: (B, H, S, D)."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+def dense_attention(q, k, v, *, causal: bool = False, window: int = 0):
+    """Reference multi-head attention. q: (B, H, S, D); k, v: (B, KV, S,
+    D) with H a multiple of KV: query head ``h`` reads K/V head
+    ``h // (H / KV)``, grouped in the products and never repeated.
+    ``window > 0`` (causal only) lets query ``i`` see keys
+    ``i - window + 1 .. i``."""
+    b, h, sq, d = q.shape
+    kvh, sk = k.shape[1], k.shape[2]
+    if window and not causal:
+        raise ValueError("a window is causal: pass causal=True")
+    scale = 1.0 / math.sqrt(d)
+    qg = q.reshape(b, kvh, h // kvh, sq, d)
+    s = jnp.einsum("bkgqd,bksd->bkgqs", qg, k) * scale
     if causal:
-        sq, sk = q.shape[2], k.shape[2]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    return jnp.einsum("bkgqs,bksd->bkgqd", p, v).reshape(b, h, sq, v.shape[-1])
 
 
 def _ring_fwd_state(q, k, v, *, axis_name: str, causal: bool,
@@ -227,10 +237,14 @@ def _ring_trainable_bwd(axis_name, causal, use_flash, res, g):
                 v_blk.astype(jnp.float32),
                 ((0, 0), (0, 0), (0, pad), (0, 0)),
             )
-            return _grads_rect(
-                qf, kp, vp, gf, delta, lse, q_off, k_off + s_local,
-                causal, blk, k_off=k_off,
+            # _grads_rect sweeps (B, KV, G, S, D) query groups: here
+            # every head has K and V of its own, a group of one
+            dq_h, dk_h, dv_h = _grads_rect(
+                qf[:, :, None], kp, vp, gf[:, :, None], delta[:, :, None],
+                lse[:, :, None], q_off, k_off + s_local, causal, blk,
+                k_off=k_off,
             )
+            return dq_h[:, :, 0], dk_h, dv_h
 
         if causal:
             # hops whose K/V shard is entirely in this chip's future are

@@ -98,11 +98,15 @@ def _flash_kernel_fori(
     block_k: int,
     causal: bool,
     with_lse: bool = False,
+    window: int = 0,
 ):
     """K/V-resident variant: one program per q block, fori over K blocks.
 
     Faster than grid-streaming K when K/V fit VMEM (no per-step grid
-    overhead, no scratch churn); selected automatically by size.
+    overhead, no scratch churn); selected automatically by size. With a
+    causal ``window`` the loop starts at the first K block that holds a
+    key some query of this block may see: blocks outside the window are
+    skipped, not masked.
     """
     block_q, d = q_ref.shape[1], q_ref.shape[2]
     num_k = k_ref.shape[1] // block_k
@@ -120,6 +124,11 @@ def _flash_kernel_fori(
         )
     else:
         num_k_live = num_k
+    first_k_live = 0
+    if window:
+        first_k_live = jnp.clip(
+            (q_start - (window - 1) - scalars_ref[2]) // block_k, 0, num_k
+        )
 
     q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)
 
@@ -136,6 +145,8 @@ def _flash_kernel_fori(
         valid = k_pos < s_k_valid
         if causal:
             valid = jnp.logical_and(valid, q_pos >= k_pos)
+        if window:
+            valid = jnp.logical_and(valid, q_pos - k_pos < window)
         s = jnp.where(valid, s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # explicit zero on masked lanes: when a row is fully masked m_new
@@ -151,7 +162,9 @@ def _flash_kernel_fori(
     m0 = jnp.full((block_q, 1), _NEG, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m, l, acc = lax.fori_loop(0, num_k_live, body, (m0, l0, acc0))
+    m, l, acc = lax.fori_loop(
+        first_k_live, num_k_live, body, (m0, l0, acc0)
+    )
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     if with_lse:
         # row logsumexp of the masked scaled scores — the O(S) residual a
@@ -171,6 +184,7 @@ def _flash_kernel_stream(
     scale: float,
     causal: bool,
     with_lse: bool = False,
+    window: int = 0,
 ):
     if with_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
@@ -195,6 +209,11 @@ def _flash_kernel_stream(
     # pipeline still streams them but the MXU work is skipped (dense
     # attention pays compute for the full rectangle)
     live = k_start < q_start + block_q if causal else True
+    if window:
+        # nor does a block that ends before the first query's window
+        live = jnp.logical_and(
+            live, k_start + block_k > q_start - (window - 1)
+        )
 
     @pl.when(live)
     def _step():
@@ -208,6 +227,8 @@ def _flash_kernel_stream(
         valid = k_pos < s_k_valid
         if causal:
             valid = jnp.logical_and(valid, q_pos >= k_pos)
+        if window:
+            valid = jnp.logical_and(valid, q_pos - k_pos < window)
         s = jnp.where(valid, s, _NEG)
         m = m_scr[:, :1]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -235,6 +256,7 @@ def flash_attention(
     v,
     *,
     causal: bool = False,
+    window: int = 0,
     block_q: int | None = None,
     block_k: int | None = None,
     q_offset=0,
@@ -244,7 +266,14 @@ def flash_attention(
     interpret: bool | None = None,
     return_lse: bool = False,
 ):
-    """Fused attention. q: (B, H, S_q, D); k, v: (B, H, S_k, D).
+    """Fused attention. q: (B, H, S_q, D); k, v: (B, KV, S_k, D) with
+    ``H % KV == 0``: query head ``h`` reads K/V head ``h // (H / KV)``
+    through the kernels' index maps, so grouped-query K and V are never
+    repeated up to H heads (consecutive programs of one group find their
+    K/V block already in VMEM).
+
+    ``window > 0`` (with ``causal``) lets query ``i`` see keys
+    ``i - window + 1 .. i`` only; K blocks outside are skipped.
 
     ``return_lse=True`` additionally returns the per-row logsumexp of the
     masked scaled scores, (B, H, S_q) float32 — the O(S) residual the
@@ -271,7 +300,14 @@ def flash_attention(
     if block_k is None:
         block_k = _env_int("KST_FLASH_BLOCK_K", 512)
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    kvh, s_k = k.shape[1], k.shape[2]
+    if h % kvh or v.shape[1] != kvh:
+        raise ValueError(
+            f"{h} query heads over {kvh} / {v.shape[1]} K/V heads"
+        )
+    if window and not causal:
+        raise ValueError("a window is causal: pass causal=True")
+    g = h // kvh  # query heads per K/V head
     scale = 1.0 / math.sqrt(d)
     out_dtype = q.dtype
 
@@ -285,8 +321,8 @@ def flash_attention(
         # cast on the XLA side: halves the K/V HBM→VMEM stream for bf16
         q, k, v = (x.astype(mxu_dtype) for x in (q, k, v))
     qf = _pad_to(q.reshape(b * h, s_q, d), 1, block_q)
-    kf = _pad_to(k.reshape(b * h, s_k, d), 1, block_k)
-    vf = _pad_to(v.reshape(b * h, s_k, d), 1, block_k)
+    kf = _pad_to(k.reshape(b * kvh, s_k, d), 1, block_k)
+    vf = _pad_to(v.reshape(b * kvh, s_k, d), 1, block_k)
     # zero-padding D is free: extra K columns don't change scores, extra V
     # columns produce zero output columns that are sliced away
     qf = _pad_to(qf, 2, _LANE)
@@ -316,14 +352,18 @@ def flash_attention(
             grid=(b * h, s_q_pad // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, d_pad), lambda i, j, *_: (i, j, 0)),
-                pl.BlockSpec((1, s_k_pad, d_pad), lambda i, j, *_: (i, 0, 0)),
-                pl.BlockSpec((1, s_k_pad, d_pad), lambda i, j, *_: (i, 0, 0)),
+                pl.BlockSpec(
+                    (1, s_k_pad, d_pad), lambda i, j, *_: (i // g, 0, 0)
+                ),
+                pl.BlockSpec(
+                    (1, s_k_pad, d_pad), lambda i, j, *_: (i // g, 0, 0)
+                ),
             ],
             out_specs=(out_spec, lse_spec) if return_lse else out_spec,
         )
         kernel = functools.partial(
             _flash_kernel_fori, scale=scale, block_k=block_k, causal=causal,
-            with_lse=return_lse,
+            with_lse=return_lse, window=window,
         )
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -346,10 +386,10 @@ def flash_attention(
                     (1, block_q, d_pad), lambda i, j, kk, *_: (i, j, 0)
                 ),
                 pl.BlockSpec(
-                    (1, block_k, d_pad), lambda i, j, kk, *_: (i, kk, 0)
+                    (1, block_k, d_pad), lambda i, j, kk, *_: (i // g, kk, 0)
                 ),
                 pl.BlockSpec(
-                    (1, block_k, d_pad), lambda i, j, kk, *_: (i, kk, 0)
+                    (1, block_k, d_pad), lambda i, j, kk, *_: (i // g, kk, 0)
                 ),
             ],
             out_specs=(out_spec, lse_spec) if return_lse else out_spec,
@@ -361,7 +401,7 @@ def flash_attention(
         )
         kernel = functools.partial(
             _flash_kernel_stream, scale=scale, causal=causal,
-            with_lse=return_lse,
+            with_lse=return_lse, window=window,
         )
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -605,8 +645,9 @@ def _dense_bwd_bytes(q, k) -> int:
     return 4 * 4 * b * h * s_q * k.shape[2]
 
 
-def _bwd_mask(q_pos, k_pos, s_k_valid, causal: bool):
-    """(S_q, blk) validity mask for one KV block (padding + causality).
+def _bwd_mask(q_pos, k_pos, s_k_valid, causal: bool, window: int = 0):
+    """(rows, blk) validity mask for one KV block (padding, causality
+    and the causal window).
 
     Causal positions are BEGIN-aligned (q_pos = i, k_pos = j), matching
     the flash forward's offset convention at q_offset = k_offset = 0; the
@@ -615,6 +656,8 @@ def _bwd_mask(q_pos, k_pos, s_k_valid, causal: bool):
     valid = (k_pos < s_k_valid)[None, :]
     if causal:
         valid = valid & (q_pos[:, None] >= k_pos[None, :])
+    if window:
+        valid = valid & (q_pos[:, None] - k_pos[None, :] < window)
     return valid
 
 
@@ -626,28 +669,42 @@ def _bwd_causal_chunks() -> int:
     return _env_int("KST_FLASH_BWD_CHUNKS", 8)
 
 
+# a window layer's q chunks are single K blocks, so that a chunk sweeps
+# its window's blocks and no others; past this many chunks they grow
+_BWD_WINDOW_CHUNKS_MAX = 32
+
+
 def _grads_rect(qf, kp, vp, gf, delta, lse, q_off, s_k_valid, causal, block,
-                k_off=0):
+                k_off=0, window=0):
     """Rectangle sweep of the blockwise backward over one q range: scan
     over the given (padded) K/V blocks, recomputing each score block from
-    (q, k, lse). Positions are global begin-aligned (q_off / k_off = the
-    global position of the first q / k row — nonzero k_off serves the
-    ring backward's rotating K/V shards). Returns (dq, dk, dv) for this
-    rectangle, dk/dv over kp's full padded length. Peak memory O(S·d)
-    state + O(S_q·block) transient."""
-    b, h, s_q, d = qf.shape
+    (q, k, lse). ``qf`` / ``gf`` are (B, KV, G, S_q, D) and ``kp`` /
+    ``vp`` (B, KV, S_k, D): the G query heads of a K/V head are swept
+    as G·S_q rows against that head's keys, so grouped K and V are never
+    repeated and dk / dv sum over the group in the product itself.
+    Positions are global begin-aligned (q_off / k_off = the global
+    position of the first q / k row — nonzero k_off serves the ring
+    backward's rotating K/V shards and a window's first live block).
+    Returns (dq, dk, dv) for this rectangle, dk/dv over kp's full padded
+    length. Peak memory O(S·d) state + O(G·S_q·block) transient."""
+    b, kvh, grp, s_q, d = qf.shape
     scale = 1.0 / math.sqrt(d)
     nb = kp.shape[2] // block
-    kb = jnp.moveaxis(kp.reshape(b, h, nb, block, d), 2, 0)
-    vb = jnp.moveaxis(vp.reshape(b, h, nb, block, d), 2, 0)
-    q_pos = q_off + jnp.arange(s_q)
+    kb = jnp.moveaxis(kp.reshape(b, kvh, nb, block, d), 2, 0)
+    vb = jnp.moveaxis(vp.reshape(b, kvh, nb, block, d), 2, 0)
+    rows = grp * s_q
+    qf = qf.reshape(b, kvh, rows, d)
+    gf = gf.reshape(b, kvh, rows, d)
+    delta = delta.reshape(b, kvh, rows)
+    lse = lse.reshape(b, kvh, rows)
+    q_pos = jnp.tile(q_off + jnp.arange(s_q), grp)
 
     def step(dq, inp):
         kblk, vblk, j = inp
         kf = kblk.astype(jnp.float32)
         scores = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * scale
         k_pos = k_off + j * block + jnp.arange(block)
-        mask = _bwd_mask(q_pos, k_pos, s_k_valid, causal)
+        mask = _bwd_mask(q_pos, k_pos, s_k_valid, causal, window)
         p = jnp.where(mask, jnp.exp(scores - lse[..., None]), 0.0)
         dv_j = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
         dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vblk.astype(jnp.float32))
@@ -656,76 +713,93 @@ def _grads_rect(qf, kp, vp, gf, delta, lse, q_off, s_k_valid, causal, block,
         dk_j = scale * jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
         return dq, (dk_j, dv_j)
 
-    dq0 = jnp.zeros((b, h, s_q, d), jnp.float32)
+    dq0 = jnp.zeros((b, kvh, rows, d), jnp.float32)
     dq, (dks, dvs) = jax.lax.scan(step, dq0, (kb, vb, jnp.arange(nb)))
-    dk = jnp.moveaxis(dks, 0, 2).reshape(b, h, nb * block, d)
-    dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, nb * block, d)
-    return dq, dk, dv
+    dk = jnp.moveaxis(dks, 0, 2).reshape(b, kvh, nb * block, d)
+    dv = jnp.moveaxis(dvs, 0, 2).reshape(b, kvh, nb * block, d)
+    return dq.reshape(b, kvh, grp, s_q, d), dk, dv
 
 
-def _blockwise_grads(q, k, v, g, out, lse, causal: bool, block: int):
+def _blockwise_grads(q, k, v, g, out, lse, causal: bool, block: int,
+                     window: int = 0):
     """FlashAttention-style backward. Non-causal: one rectangle sweep.
     Causal: q chunked into block-aligned prefixes, each sweeping only the
     K blocks at or below its diagonal — ~0.56·S² of score work instead of
-    the full rectangle's 1.0 (the forward kernel's num_k_live analog)."""
+    the full rectangle's 1.0 (the forward kernel's num_k_live analog).
+    Causal with a window: a chunk sweeps the K blocks from its first
+    query's window to its diagonal and skips every other block.
+    q: (B, H, S, D); k, v: (B, KV, S, D), H a multiple of KV."""
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    kvh, s_k = k.shape[1], k.shape[2]
+    grp = h // kvh
     nb = -(-s_k // block)
     pad = nb * block - s_k
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    qf = q.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
+    qf = q.astype(jnp.float32).reshape(b, kvh, grp, s_q, d)
+    gf = g.astype(jnp.float32).reshape(b, kvh, grp, s_q, d)
     # delta_i = Σ_d g·out — the softmax-jacobian diagonal term
-    delta = jnp.sum(gf * out.astype(jnp.float32), axis=-1)  # (B, H, S_q)
+    delta = jnp.sum(
+        gf * out.astype(jnp.float32).reshape(gf.shape), axis=-1
+    )  # (B, KV, G, S_q)
+    lse = lse.reshape(b, kvh, grp, s_q)
 
     if not causal:
         dq, dk, dv = _grads_rect(
             qf, kp, vp, gf, delta, lse, 0, s_k, False, block
         )
         return (
-            dq.astype(q.dtype),
+            dq.reshape(q.shape).astype(q.dtype),
             dk[:, :, :s_k].astype(k.dtype),
             dv[:, :, :s_k].astype(v.dtype),
         )
 
     # causal (s_q == s_k enforced by the trainable wrapper): chunk edges
     # in whole K blocks so each chunk's live prefix is block-aligned
-    n_chunks = min(_bwd_causal_chunks(), nb)
+    n_chunks = min(
+        _BWD_WINDOW_CHUNKS_MAX if window else _bwd_causal_chunks(), nb
+    )
     edges = sorted({round(nb * c / n_chunks) for c in range(n_chunks + 1)})
     dq_parts = []
-    dk = jnp.zeros((b, h, nb * block, d), jnp.float32)
+    dk = jnp.zeros((b, kvh, nb * block, d), jnp.float32)
     dv = jnp.zeros_like(dk)
     for lo, hi in zip(edges[:-1], edges[1:]):
         q0, q1 = lo * block, min(hi * block, s_q)
-        k_end = hi * block  # K blocks [0, hi) are the live prefix
+        # K blocks [k_lo, hi) are live: from the first query's window (or
+        # the start) to the chunk's diagonal
+        k_lo = max(0, (q0 - (window - 1)) // block) if window else 0
+        k0, k_end = k_lo * block, hi * block
         dq_c, dk_c, dv_c = _grads_rect(
-            qf[:, :, q0:q1],
-            kp[:, :, :k_end],
-            vp[:, :, :k_end],
-            gf[:, :, q0:q1],
-            delta[:, :, q0:q1],
-            lse[:, :, q0:q1],
+            qf[:, :, :, q0:q1],
+            kp[:, :, k0:k_end],
+            vp[:, :, k0:k_end],
+            gf[:, :, :, q0:q1],
+            delta[:, :, :, q0:q1],
+            lse[:, :, :, q0:q1],
             q0,
             s_k,
             True,
             block,
+            k_off=k0,
+            window=window,
         )
         dq_parts.append(dq_c)
-        dk = dk.at[:, :, :k_end].add(dk_c)
-        dv = dv.at[:, :, :k_end].add(dv_c)
-    dq = jnp.concatenate(dq_parts, axis=2)
+        dk = dk.at[:, :, k0:k_end].add(dk_c)
+        dv = dv.at[:, :, k0:k_end].add(dv_c)
+    dq = jnp.concatenate(dq_parts, axis=3)
     return (
-        dq.astype(q.dtype),
+        dq.reshape(q.shape).astype(q.dtype),
         dk[:, :, :s_k].astype(k.dtype),
         dv[:, :, :s_k].astype(v.dtype),
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def flash_attention_trainable(q, k, v, causal: bool = False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def flash_attention_trainable(q, k, v, causal: bool = False, window: int = 0):
     """Differentiable fused attention: Pallas flash forward, recompute
-    backward.
+    backward. q: (B, H, S, D); k, v: (B, KV, S, D) with H a multiple of
+    KV (grouped-query attention without repeating K and V); ``window``
+    as in :func:`flash_attention`.
 
     The flash kernels above are forward-only (inference featurizers and
     the ring/Ulysses per-hop updates). Training needs a VJP: save ONLY
@@ -744,10 +818,10 @@ def flash_attention_trainable(q, k, v, causal: bool = False):
       alone could stream 32k since round 2; the dense backward could
       not).
     """
-    return flash_attention(q, k, v, causal=causal)
+    return flash_attention(q, k, v, causal=causal, window=window)
 
 
-def _flash_trainable_fwd(q, k, v, causal: bool):
+def _flash_trainable_fwd(q, k, v, causal: bool, window: int = 0):
     if causal and q.shape[2] != k.shape[2]:
         # the flash forward masks begin-aligned (q_pos >= k_pos at offset
         # 0) while dense_attention's tril is end-aligned — the two only
@@ -759,21 +833,29 @@ def _flash_trainable_fwd(q, k, v, causal: bool):
         )
     if _dense_bwd_bytes(q, k) <= _dense_bwd_max_bytes():
         # short context: the dense backward needs only (q, k, v)
-        return flash_attention(q, k, v, causal=causal), (q, k, v, None, None)
-    out, lse = flash_attention(q, k, v, causal=causal, return_lse=True)
+        out = flash_attention(q, k, v, causal=causal, window=window)
+        return out, (q, k, v, None, None)
+    out, lse = flash_attention(
+        q, k, v, causal=causal, window=window, return_lse=True
+    )
     return out, (q, k, v, out, lse)
 
 
-def _flash_trainable_bwd(causal: bool, res, g):
+def _flash_trainable_bwd(causal: bool, window: int, res, g):
     q, k, v, out, lse = res
     if out is None:
         from keystone_tpu.ops.attention import dense_attention
 
         _, vjp = jax.vjp(
-            lambda q, k, v: dense_attention(q, k, v, causal=causal), q, k, v
+            lambda q, k, v: dense_attention(
+                q, k, v, causal=causal, window=window
+            ),
+            q, k, v,
         )
         return vjp(g)
-    return _blockwise_grads(q, k, v, g, out, lse, causal, _bwd_block())
+    return _blockwise_grads(
+        q, k, v, g, out, lse, causal, _bwd_block(), window
+    )
 
 
 flash_attention_trainable.defvjp(_flash_trainable_fwd, _flash_trainable_bwd)

@@ -1,32 +1,38 @@
-"""Mixture-of-experts FFN with expert parallelism (EP).
+"""Mixture-of-experts FFN: top-k routing without drops over the experts
+held here.
 
-The reference has no MoE (its EP-shaped pattern is the weighted solver's
-one-class-per-partition solves, ``BlockWeightedLeastSquares.scala:228-263``
-— covered by ``ops/weighted_linear.py``). This layer makes EP first-class
-for the sequence-model stack: a GShard-style top-2 routed expert FFN
-where the *sharding layout is the parallelism* —
+The reference has no MoE (its expert-shaped pattern is the weighted
+solver's one-class-per-partition solves,
+``BlockWeightedLeastSquares.scala:228-263``, covered by
+``ops/weighted_linear.py``). This layer is what a sparse LM block calls
+in place of its dense FFN:
 
-- routing, dispatch, and combine are einsums over a dense one-hot
-  dispatch tensor (no host-side scatter, no ragged shapes — the
-  capacity-factor bound makes every shape static, which is what XLA
-  needs to tile the expert gemms onto the MXU);
-- the expert axis of ``w1``/``w2`` is sharded over the mesh ``model``
-  axis (see :func:`keystone_tpu.models.lm_transformer.shard_params`), so
-  XLA inserts the dispatch/combine ``all_to_all``s over ICI exactly
-  where GShard's hand-written ones sit;
-- tokens over capacity are *dropped* (contribute zero; the residual
-  stream carries them unchanged) — the standard static-shape trade, and
-  the load-balance auxiliary loss keeps drops rare.
+- the router scores every token against **every** expert of the model
+  (``w_router`` keeps the published width) and keeps the ``top_k``
+  largest, renormalised and scaled by ``routed_scale``;
+- the layer holds a contiguous share of the experts,
+  ``first_expert .. first_expert + held``: one chip's share of an
+  expert-parallel deployment, or all of them. It computes the part of
+  the result that its own experts give. What the absent experts would
+  have added is left out; on one chip the layer runs without the
+  exchange that would bring other chips' tokens here;
+- no capacity and no dropped token: the ``tokens x top_k`` assignments
+  are sorted by expert, the token rows gathered in that order, and each
+  expert's rows multiplied by its own matrices in one grouped matrix
+  product (Pallas ``megablox.gmm``: its grid covers the row tiles of
+  the held experts alone, so the work follows the rows routed here, not
+  the static ``tokens x top_k`` bound); the results are brought back to
+  token order and summed with the routing weights;
+- an optional shared expert runs on every token beside the routed ones.
 
-Shapes follow the GShard/Switch convention: tokens route within
-fixed-size groups (``group_size``; the last group is padded with
-capacity-neutral dummies), E experts, C capacity slots per expert per
-group — bounding the (group, E, C) dispatch/combine tensors to
-O(tokens · group) total instead of O(tokens²).
+``__call__`` also returns three counters of the step program: the rows
+routed to held experts, the largest load of a held expert, and the rows
+the grouped product ran over (whole row tiles).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -34,136 +40,219 @@ import jax.numpy as jnp
 
 from keystone_tpu.core.treenode import static_field, treenode
 
+COUNTERS = ("routed_rows", "max_expert_rows", "mm_rows")
+
+
+def ffn(y, w1, w2, w3, cdt, mm_fn=None):
+    """One feed-forward: SwiGLU ``(silu(y w1) * (y w3)) w2`` when ``w3``
+    is there, else ``gelu(y w1) w2``. Shared by the block's dense FFN
+    and the shared expert."""
+    if mm_fn is None:
+        from keystone_tpu.ops.quantization import mm as mm_fn
+    h = mm_fn(y, w1, cdt)
+    h = jax.nn.gelu(h) if w3 is None else jax.nn.silu(h) * mm_fn(y, w3, cdt)
+    return mm_fn(h, w2, cdt)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(xf, order, inv, k: int):
+    """Token rows in sorted-assignment order: row ``r`` is token
+    ``order[r] // k``. ``order`` is a permutation of the ``T*k``
+    assignments and ``inv`` its inverse, so the backward is a gather
+    too (TPU scatters are slow): no scatter-add into the tokens."""
+    return xf[order // k]
+
+
+def _dispatch_fwd(xf, order, inv, k):
+    return xf[order // k], (order, inv, xf.shape[0])
+
+
+def _dispatch_bwd(k, res, g):
+    _order, inv, t = res
+    return g[inv].reshape(t, k, g.shape[-1]).sum(axis=1), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _collect(ys, order, inv):
+    """Sorted rows back in assignment order (the inverse permutation)."""
+    return ys[inv]
+
+
+def _collect_fwd(ys, order, inv):
+    return ys[inv], (order,)
+
+
+def _collect_bwd(res, g):
+    return g[res[0]], None, None
+
+
+_collect.defvjp(_collect_fwd, _collect_bwd)
+
+
+def _row_tile(m: int, most: int = 512) -> int:
+    """Largest power-of-two row tile, 8 to ``most``, that divides m (a
+    multiple of 8)."""
+    t = most
+    while m % t:
+        t //= 2
+    return t
+
+
+def grouped_mm(xs, w, group_sizes, first: int, tm: int):
+    """``xs[rows of expert e] @ w[e - first]`` for the held experts,
+    zeros elsewhere. xs: (M, K) sorted by expert; w: (held, K, N);
+    group_sizes: (E,) rows of every expert of the model."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from keystone_tpu.ops.flash_attention import interpret_default
+
+    k_dim, n_dim = w.shape[1], w.shape[2]
+    with jax.named_scope("moe_grouped_mm"):
+        return gmm(
+            xs,
+            w,
+            group_sizes,
+            xs.dtype,
+            (tm, min(k_dim, 1024), min(n_dim, 1024)),
+            jnp.asarray(first, jnp.int32),
+            None,
+            False,
+            interpret_default(),
+        )
+
 
 @treenode
 class MoELayer:
-    """Top-2 routed expert FFN: (B, S, d) → (B, S, d) plus an auxiliary
-    load-balance loss (Shazeer et al.'s importance loss, GShard eq. 4)."""
+    """Top-k routed expert FFN over the experts held here:
+    (B, S, d) → (out (B, S, d), counters)."""
 
-    w_router: jnp.ndarray  # (d, E)
-    w1: jnp.ndarray  # (E, d, ff)
-    w2: jnp.ndarray  # (E, ff, d)
-    capacity_factor: float = static_field(default=1.25)
-    # routing group size (GShard's G axis): tokens route within fixed
-    # groups so capacity — and with it the (group, E, C) dispatch/combine
-    # tensors — is bounded per group. Without it C grows with B·S and the
-    # dispatch tensors are O((B·S)²); with it they are O(B·S · group).
-    group_size: int = static_field(default=4096)
+    w_router: jnp.ndarray  # (d, E): every expert of the model
+    w1: jnp.ndarray  # (held, d, ff)
+    w2: jnp.ndarray  # (held, ff, d)
+    w3: jnp.ndarray | None = None  # (held, d, ff): SwiGLU's second input
+    # the shared expert's matrices (every token, no gate), or None
+    shared_w1: jnp.ndarray | None = None
+    shared_w2: jnp.ndarray | None = None
+    shared_w3: jnp.ndarray | None = None
+    top_k: int = static_field(default=2)
+    first_expert: int = static_field(default=0)
+    # "softmax" over all experts, or "sigmoid" of each score; either way
+    # the top_k kept are renormalised to sum to one
+    scoring: str = static_field(default="softmax")
+    routed_scale: float = static_field(default=1.0)
 
     @property
     def num_experts(self) -> int:
         return self.w_router.shape[-1]
 
+    @property
+    def held(self) -> int:
+        return self.w1.shape[0]
+
     @staticmethod
-    def create(key, dim: int, ff: int, num_experts: int,
-               capacity_factor: float = 1.25,
-               group_size: int = 4096) -> "MoELayer":
-        kr, k1, k2 = jax.random.split(key, 3)
+    def create(key, dim: int, ff: int, num_experts: int, *,
+               held: int | None = None, first_expert: int = 0,
+               top_k: int = 2, swiglu: bool = False, shared_ff: int = 0,
+               scoring: str = "softmax", routed_scale: float = 1.0,
+               router_std: float = 0.02) -> "MoELayer":
+        held = num_experts if held is None else held
+        if not 0 <= first_expert <= num_experts - held:
+            raise ValueError(
+                f"experts {first_expert}..{first_expert + held} of "
+                f"{num_experts}"
+            )
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring={scoring!r}; expected softmax|sigmoid")
+        ks = jax.random.split(key, 7)
+
+        def init(k, shape, fan_in):
+            return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
+
         return MoELayer(
-            w_router=0.02 * jax.random.normal(kr, (dim, num_experts)),
-            w1=jax.random.normal(k1, (num_experts, dim, ff))
-            / math.sqrt(dim),
-            w2=jax.random.normal(k2, (num_experts, ff, dim))
-            / math.sqrt(ff),
-            capacity_factor=capacity_factor,
-            group_size=group_size,
+            w_router=router_std * jax.random.normal(ks[0], (dim, num_experts)),
+            w1=init(ks[1], (held, dim, ff), dim),
+            w2=init(ks[2], (held, ff, dim), ff),
+            w3=init(ks[3], (held, dim, ff), dim) if swiglu else None,
+            shared_w1=init(ks[4], (dim, shared_ff), dim) if shared_ff else None,
+            shared_w2=init(ks[5], (shared_ff, dim), shared_ff)
+            if shared_ff
+            else None,
+            shared_w3=init(ks[6], (dim, shared_ff), dim)
+            if shared_ff and swiglu
+            else None,
+            top_k=top_k,
+            first_expert=first_expert,
+            scoring=scoring,
+            routed_scale=routed_scale,
         )
 
-    def _capacity(self, num_tokens: int) -> int:
-        # top-2: every token wants two slots; round up to keep tiny test
-        # shapes from degenerating to C=0
-        cap = int(
-            math.ceil(2 * num_tokens * self.capacity_factor
-                      / self.num_experts)
+    def route(self, xf):
+        """(weights (T, k) f32, expert ids (T, k)) of every token: f32
+        throughout, the sums are cheap and the ordering is sensitive."""
+        logits = xf.astype(jnp.float32) @ self.w_router.astype(jnp.float32)
+        scores = (
+            jax.nn.sigmoid(logits)
+            if self.scoring == "sigmoid"
+            else jax.nn.softmax(logits, axis=-1)
         )
-        return max(cap, 1)
+        top, idx = jax.lax.top_k(scores, self.top_k)
+        weights = top / jnp.sum(top, axis=-1, keepdims=True)
+        return weights * self.routed_scale, idx
 
     def __call__(self, x):
-        """x: (B, S, d) → (out (B, S, d), aux_loss scalar f32)."""
         b, s, d = x.shape
-        g_tot = b * s
-        xf = x.reshape(g_tot, d)
-        gs = min(self.group_size, g_tot)
-        ng = -(-g_tot // gs)
-        pad = ng * gs - g_tot
-        xp = jnp.pad(xf, ((0, pad), (0, 0)))
-        valid = (jnp.arange(ng * gs) < g_tot).reshape(ng, gs)
-        c = self._capacity(gs)
-
-        outs, auxs, counts = jax.vmap(
-            lambda xi, vi: self._route_group(xi, vi, c)
-        )(xp.reshape(ng, gs, d), valid)
-        out = outs.reshape(ng * gs, d)[:g_tot]
-        # per-group aux weighted by real token count (padding excluded)
-        aux = jnp.sum(auxs * counts) / jnp.maximum(jnp.sum(counts), 1.0)
-        return out.reshape(b, s, d), aux
-
-    def _route_group(self, xf, valid, c: int):
-        """Route one group. xf: (gs, d); valid: (gs,) bool marks real
-        tokens (padding claims no capacity and emits zero). Returns
-        (out (gs, d), aux scalar, valid count)."""
-        e = self.num_experts
-
-        # --- routing (f32: softmax + cumsum bookkeeping is cheap and
-        # precision-sensitive; the expert gemms below run in xf.dtype) ---
-        logits = (
-            xf.astype(jnp.float32) @ self.w_router.astype(jnp.float32)
-        )  # (gs, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        vmask = valid.astype(jnp.float32)[:, None]
-
-        idx1 = jnp.argmax(probs, axis=-1)  # (gs,)
-        mask1 = jax.nn.one_hot(idx1, e, dtype=jnp.float32) * vmask
-        probs2 = probs * (1.0 - mask1)
-        idx2 = jnp.argmax(probs2, axis=-1)
-        mask2 = jax.nn.one_hot(idx2, e, dtype=jnp.float32) * vmask
-
-        # load-balance aux: mean one-hot fraction × mean prob over REAL
-        # tokens, scaled E² (GShard) — minimized at uniform routing
-        # where it equals 1
-        count = jnp.maximum(jnp.sum(vmask), 1.0)
-        aux = jnp.mean(
-            (jnp.sum(mask1, axis=0) / count)
-            * (jnp.sum(probs * vmask, axis=0) / count)
-        ) * (e * e)
-
-        # capacity slots: position of each token within its expert's
-        # queue, top-1 claims first, top-2 queues behind all top-1s
-        pos1 = jnp.cumsum(mask1, axis=0) * mask1 - mask1  # (gs, E)
-        count1 = jnp.sum(mask1, axis=0, keepdims=True)  # (1, E)
-        pos2 = (jnp.cumsum(mask2, axis=0) - mask2 + count1) * mask2
-        keep1 = mask1 * (pos1 < c)
-        keep2 = mask2 * (pos2 < c)
-
-        gate1 = jnp.sum(probs * keep1, axis=-1)  # (gs,)
-        gate2 = jnp.sum(probs * keep2, axis=-1)
-        denom = jnp.maximum(gate1 + gate2, 1e-9)
-        gate1, gate2 = gate1 / denom, gate2 / denom
-
-        slot1 = jax.nn.one_hot(
-            jnp.sum(pos1, axis=-1).astype(jnp.int32), c, dtype=jnp.float32
-        )  # (gs, C)
-        slot2 = jax.nn.one_hot(
-            jnp.sum(pos2, axis=-1).astype(jnp.int32), c, dtype=jnp.float32
-        )
-        # (gs, E, C) combine weights; dispatch is its 0/1 support
-        combine = (
-            gate1[:, None, None] * keep1[:, :, None] * slot1[:, None, :]
-            + gate2[:, None, None] * keep2[:, :, None] * slot2[:, None, :]
-        )
-        dispatch = (combine > 0.0).astype(xf.dtype)
-
-        # --- dispatch → expert gemms → combine (the EP einsums; with the
-        # expert axis of w1/w2 sharded over `model`, XLA places
-        # all_to_alls here) ---
-        expert_in = jnp.einsum("gec,gd->ecd", dispatch, xf)  # (E, C, d)
-        h = jax.nn.gelu(
-            jnp.einsum("ecd,edf->ecf", expert_in, self.w1.astype(xf.dtype))
-        )
-        expert_out = jnp.einsum(
-            "ecf,efd->ecd", h, self.w2.astype(xf.dtype)
-        )
-        out = jnp.einsum(
-            "gec,ecd->gd", combine.astype(xf.dtype), expert_out
-        )
-        return out, aux, jnp.sum(vmask)
+        t, k = b * s, self.top_k
+        xf = x.reshape(t, d)
+        cdt = x.dtype
+        with jax.named_scope("moe_router"):
+            weights, idx = self.route(xf)
+            flat = idx.reshape(t * k)
+            order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+            inv = (
+                jnp.zeros(t * k, jnp.int32)
+                .at[order]
+                .set(jnp.arange(t * k, dtype=jnp.int32))
+            )
+            group_sizes = jnp.zeros(self.num_experts, jnp.int32).at[flat].add(1)
+        # a handful of decode rows is padded up to the kernel's sublane
+        # tile; the pad rows are no expert's and are cut off again
+        pad = -(t * k) % 8
+        tm = _row_tile(t * k + pad)
+        first = self.first_expert
+        with jax.named_scope("moe_experts"):
+            xs = jnp.pad(_dispatch(xf, order, inv, k), ((0, pad), (0, 0)))
+            h = grouped_mm(xs, self.w1.astype(cdt), group_sizes, first, tm)
+            if self.w3 is None:
+                h = jax.nn.gelu(h)
+            else:
+                h = jax.nn.silu(h) * grouped_mm(
+                    xs, self.w3.astype(cdt), group_sizes, first, tm
+                )
+            ys = grouped_mm(h, self.w2.astype(cdt), group_sizes, first, tm)
+            ys = ys[: t * k]
+            # rows of experts held elsewhere are zeros: they add nothing
+            y = _collect(ys, order, inv).reshape(t, k, d)
+            out = jnp.einsum(
+                "tk,tkd->td", weights.astype(cdt), y,
+                preferred_element_type=jnp.float32,
+            ).astype(cdt)
+        if self.shared_w1 is not None:
+            with jax.named_scope("moe_shared_expert"):
+                out = out + ffn(
+                    xf, self.shared_w1, self.shared_w2, self.shared_w3, cdt
+                )
+        held = jax.lax.dynamic_slice_in_dim(group_sizes, first, self.held)
+        ends = jnp.cumsum(group_sizes)
+        end = jax.lax.dynamic_slice_in_dim(ends, first, self.held)
+        start = end - held
+        # the product visits every row tile a held expert's rows touch
+        tiles = jnp.where(held > 0, -(-end // tm) - start // tm, 0)
+        counters = {
+            "routed_rows": jnp.sum(held),
+            "max_expert_rows": jnp.max(held),
+            "mm_rows": tm * jnp.sum(tiles),
+        }
+        return out.reshape(b, s, d), counters

@@ -149,6 +149,11 @@ class DecodeLoop:
     ):
         if slots < 1:
             raise ValueError(f"slots={slots}: need >= 1")
+        from keystone_tpu.models.lm.decode import refuse_unservable
+
+        # window layers, a head count per layer: refused by name here,
+        # before a pool is sized for a cache that cannot hold them
+        refuse_unservable(model)
         self.model = model
         self.slots = slots
         self.s_max = s_max

@@ -1,8 +1,8 @@
-"""Expert-parallel MoE layer: routing semantics, dense parity, capacity
-dropping, sharded parity, and end-to-end LM training (the EP member of
-the parallelism matrix — the reference's closest pattern is the weighted
-solver's one-class-per-partition solves,
-BlockWeightedLeastSquares.scala:228-263)."""
+"""Routed experts (``ops/moe.py``): top-k routing without drops over
+the experts held here, against a plain loop over experts; the share
+arithmetic; counters; and the toy LM that routes (the reference's
+closest pattern is the weighted solver's one-class-per-partition
+solves, BlockWeightedLeastSquares.scala:228-263)."""
 
 import dataclasses
 
@@ -11,104 +11,161 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from keystone_tpu.ops.moe import MoELayer
+from keystone_tpu.ops.moe import COUNTERS, MoELayer
+
+CASES = {
+    "toy_top2_softmax_gelu": dict(num_experts=4),
+    "share_top2_sigmoid_swiglu_shared": dict(
+        num_experts=8, held=2, first_expert=4, swiglu=True, shared_ff=32,
+        scoring="sigmoid", routed_scale=2.5,
+    ),
+    "top3_of_all_held": dict(num_experts=8, top_k=3, swiglu=True),
+}
 
 
-def _layer(dim=16, ff=32, experts=4, cap=2.0, seed=0):
-    return MoELayer.create(
-        jax.random.key(seed), dim, ff, experts, capacity_factor=cap
-    )
+def _layer(seed=0, dim=16, ff=32, **kw):
+    return MoELayer.create(jax.random.key(seed), dim, ff, **kw)
 
 
-def test_output_shape_and_aux_finite(rng):
-    layer = _layer()
+def loop_over_experts(m: MoELayer, x):
+    """The layer as a loop: each held expert on every token, weighted by
+    the routing weight where the token chose it."""
+    xf = x.reshape(-1, x.shape[-1])
+    w, idx = m.route(xf)
+    out = jnp.zeros_like(xf)
+
+    def expert(w1, w2, w3):
+        h = xf @ w1
+        return (jax.nn.gelu(h) if w3 is None else jax.nn.silu(h) * (xf @ w3)) @ w2
+
+    for e in range(m.held):
+        chosen = jnp.sum(jnp.where(idx == e + m.first_expert, w, 0.0), -1)
+        out = out + chosen[:, None] * expert(
+            m.w1[e], m.w2[e], None if m.w3 is None else m.w3[e]
+        )
+    if m.shared_w1 is not None:
+        out = out + expert(m.shared_w1, m.shared_w2, m.shared_w3)
+    return out.reshape(x.shape)
+
+
+def test_output_shape_and_counters(rng):
+    layer = _layer(num_experts=4)
     x = jnp.asarray(rng.normal(size=(2, 8, 16)).astype(np.float32))
-    out, aux = layer(x)
-    assert out.shape == x.shape
-    assert np.isfinite(np.asarray(out)).all()
-    # aux is the GShard importance loss: ≥ its uniform-routing minimum of
-    # ~1 and finite
-    assert 0.5 < float(aux) < 16.0
+    out, counters = layer(x)
+    assert out.shape == x.shape and np.isfinite(np.asarray(out)).all()
+    assert set(counters) == set(COUNTERS)
+    # every expert held: every one of the 16 x 2 assignments lands here
+    assert int(counters["routed_rows"]) == 32
+    assert 8 <= int(counters["max_expert_rows"]) <= 16
+    assert int(counters["mm_rows"]) >= 32
 
 
 def test_single_expert_matches_dense_ffn(rng):
-    """With E=1 and ample capacity, routing is the identity: the layer
-    must equal the plain gelu FFN with the same weights."""
-    layer = _layer(experts=1, cap=4.0)
-    x = jnp.asarray(rng.normal(size=(2, 6, 16)).astype(np.float32))
+    """One expert, one choice: routing is the identity and the weight 1."""
+    layer = _layer(num_experts=1, top_k=1)
+    x = jnp.asarray(rng.normal(size=(2, 8, 16)).astype(np.float32))
     out, _ = layer(x)
     dense = jax.nn.gelu(x @ layer.w1[0]) @ layer.w2[0]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=1e-5)
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_layer_matches_the_loop_over_experts(rng, case):
+    layer = _layer(seed=1, **CASES[case])
+    x = jnp.asarray(rng.normal(size=(2, 16, 16)).astype(np.float32))
+    out, _ = jax.jit(lambda m, t: m(t))(layer, x)
     np.testing.assert_allclose(
-        np.asarray(out), np.asarray(dense), atol=1e-5
+        np.asarray(out), np.asarray(loop_over_experts(layer, x)), atol=2e-5
     )
 
 
-def test_gates_convex_and_routed_tokens_change(rng):
-    """Kept tokens mix ≤2 experts with convex weights; with generous
-    capacity every token is kept (nonzero update for nonzero input)."""
-    layer = _layer(experts=4, cap=4.0)
-    x = jnp.asarray(rng.normal(size=(1, 32, 16)).astype(np.float32))
-    out, _ = layer(x)
-    assert float(jnp.abs(out).sum()) > 0
-    # drop all capacity: everything overflows, output must be exactly 0
-    # (the residual stream carries dropped tokens)
-    starved = dataclasses.replace(layer, capacity_factor=0.0)
-    # capacity_factor=0 clamps to 1 slot; to truly starve, send many
-    # tokens so >1 land on each expert and the tail is dropped
-    out2, _ = starved(x)
-    kept_norm = float(jnp.abs(out2).sum())
-    full_norm = float(jnp.abs(out).sum())
-    assert kept_norm < full_norm  # some tokens were dropped
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_gradients_match_the_loop_over_experts(rng, case):
+    """Through the sort, the gathers (whose backward is a gather too)
+    and the grouped products: weights, router and input."""
+    layer = _layer(seed=2, **CASES[case])
+    x = jnp.asarray(rng.normal(size=(1, 16, 16)).astype(np.float32))
+    got = jax.grad(lambda m, t: jnp.sum(jnp.sin(m(t)[0])), argnums=(0, 1))(layer, x)
+    want = jax.grad(
+        lambda m, t: jnp.sum(jnp.sin(loop_over_experts(m, t))), argnums=(0, 1)
+    )(layer, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
 
 
-def test_capacity_drop_is_positionwise(rng):
-    """Dropped tokens produce exactly zero rows while kept tokens keep
-    their full expert output (no renormalization leakage across tokens).
-
-    E=1 makes the invariant exact: every token routes to the one expert
-    with gate 1, capacity keeps the first C tokens in order, so starved
-    rows < C must equal the ample-capacity rows bit-for-tolerance and
-    rows ≥ C must be exactly zero."""
-    layer = _layer(experts=1, cap=8.0)
-    x = jnp.asarray(rng.normal(size=(1, 8, 16)).astype(np.float32))
-    out_full, _ = layer(x)
-    starved = dataclasses.replace(layer, capacity_factor=1e-9)  # C=1
-    out_st, _ = starved(x)
-    row_norm = np.abs(np.asarray(out_st)[0]).sum(axis=-1)
-    assert np.all(row_norm[1:] == 0.0)  # tokens 1..7 dropped at C=1
-    np.testing.assert_allclose(
-        np.asarray(out_st)[0, 0], np.asarray(out_full)[0, 0], atol=1e-6
+def test_no_token_is_dropped_when_every_token_picks_one_expert(rng):
+    """A router forced to send everything to expert 2 (and, second, to
+    expert 0): all 64 tokens go through it, none over any capacity."""
+    layer = _layer(num_experts=4, swiglu=True)
+    forced = dataclasses.replace(
+        layer,
+        w_router=jnp.zeros_like(layer.w_router),
     )
+    x = jnp.asarray(rng.normal(size=(4, 16, 16)).astype(np.float32))
+    # a constant feature makes the forced logits the same for every token
+    x = x.at[..., 0].set(1.0)
+    forced = dataclasses.replace(
+        forced, w_router=forced.w_router.at[0].set(jnp.array([1.0, 0.0, 9.0, -9.0]))
+    )
+    out, counters = forced(x)
+    assert int(counters["routed_rows"]) == 2 * 64
+    assert int(counters["max_expert_rows"]) == 64
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(loop_over_experts(forced, x)), atol=2e-5
+    )
+    assert float(jnp.abs(out).min(axis=-1).max()) > 0  # every row moved
 
 
-def test_sharded_parity(mesh4x2):
-    """Expert-sharded weights + data-sharded tokens produce the same
-    result as the unsharded layer (XLA inserts the all_to_alls)."""
+def test_the_shares_of_the_routed_sum_add_up(rng):
+    """Four shares of two experts each, over the same router: their
+    routed parts sum to the layer that holds all eight."""
+    whole = _layer(seed=3, num_experts=8, swiglu=True, scoring="sigmoid",
+                   routed_scale=2.5)
+    x = jnp.asarray(rng.normal(size=(2, 16, 16)).astype(np.float32))
+    want, _ = whole(x)
+    total = jnp.zeros_like(want)
+    routed = 0
+    for shard in range(4):
+        lo = 2 * shard
+        share = dataclasses.replace(
+            whole, w1=whole.w1[lo : lo + 2], w2=whole.w2[lo : lo + 2],
+            w3=whole.w3[lo : lo + 2], first_expert=lo,
+        )
+        part, counters = share(x)
+        total = total + part
+        routed += int(counters["routed_rows"])
+    assert routed == 2 * 32  # every assignment is some share's
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want), atol=2e-5)
+
+
+def test_the_product_runs_over_whole_row_tiles_of_the_held_experts(rng):
+    layer = _layer(num_experts=8, held=2, first_expert=2)
+    x = jnp.asarray(rng.normal(size=(8, 64, 16)).astype(np.float32))
+    _, counters = layer(x)
+    routed, rows = int(counters["routed_rows"]), int(counters["mm_rows"])
+    # 1024 assignments in tiles of 512: the two held experts' rows lie in
+    # at most all of them, and in no fewer rows than were routed
+    assert routed <= rows <= 2 * 1024 and rows % 512 == 0
+    assert routed < 1024  # six of eight experts are held elsewhere
+
+
+def test_data_sharded_tokens_match_the_unsharded_layer(mesh4x2):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     rng = np.random.default_rng(0)
-    layer = _layer(experts=2, cap=4.0)
+    layer = _layer(num_experts=2)
     x = jnp.asarray(rng.normal(size=(8, 4, 16)).astype(np.float32))
-    ref, ref_aux = layer(x)
-
-    sharded = dataclasses.replace(
-        layer,
-        w_router=jax.device_put(
-            layer.w_router, NamedSharding(mesh4x2, P())
-        ),
-        w1=jax.device_put(
-            layer.w1, NamedSharding(mesh4x2, P("model", None, None))
-        ),
-        w2=jax.device_put(
-            layer.w2, NamedSharding(mesh4x2, P("model", None, None))
-        ),
-    )
+    want, _ = layer(x)
     xs = jax.device_put(x, NamedSharding(mesh4x2, P("data", None, None)))
-    out, aux = jax.jit(lambda l, t: l(t))(sharded, xs)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), atol=1e-5
-    )
-    np.testing.assert_allclose(float(aux), float(ref_aux), rtol=1e-5)
+    out, _ = jax.jit(lambda l, t: l(t))(layer, xs)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+
+
+def test_create_refuses_a_share_outside_the_model():
+    with pytest.raises(ValueError, match="experts 6..10 of 8"):
+        _layer(num_experts=8, held=4, first_expert=6)
+    with pytest.raises(ValueError, match="scoring"):
+        _layer(num_experts=4, scoring="tanh")
 
 
 def test_lm_with_moe_trains_and_generates():
@@ -124,10 +181,10 @@ def test_lm_with_moe_trains_and_generates():
         moe_every=2,
         num_experts=4,
     )
-    # block 1 dense, block 2 MoE; dense FFN of the MoE block is
+    # block 1 dense, block 2 routed; the dense FFN of the routed block is
     # zero-width (no dead params)
-    assert model.moe_layers[0] is None
-    assert model.moe_layers[1] is not None
+    assert model.blocks[0].moe is None
+    assert model.blocks[1].moe is not None
     assert model.blocks[1].w1.shape[1] == 0
     corpus = lm.synthetic_corpus(20_000, 31, seed=1)
     model, losses = lm.train(
@@ -165,18 +222,3 @@ def test_moe_does_not_perturb_dense_seeding():
     np.testing.assert_array_equal(
         np.asarray(dense.blocks[0].w1), np.asarray(moe.blocks[0].w1)
     )
-
-
-def test_grouped_routing_matches_single_group(rng):
-    """With ample capacity (no drops anywhere) the grouped router must
-    equal one big group — grouping only bounds memory, not semantics."""
-    big = _layer(experts=4, cap=8.0)
-    small = dataclasses.replace(big, group_size=8)
-    # 24 tokens -> 3 groups of 8; also exercise non-divisible padding
-    for s in (24, 21):
-        x = jnp.asarray(rng.normal(size=(1, s, 16)).astype(np.float32))
-        out_big, _ = big(x)
-        out_small, _ = small(x)
-        np.testing.assert_allclose(
-            np.asarray(out_small), np.asarray(out_big), atol=1e-5
-        )

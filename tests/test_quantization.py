@@ -93,7 +93,7 @@ def test_quantize_skips_moe_and_zero_width():
     assert not isinstance(q.blocks[1].w1, QTensor)
     assert q.blocks[1].w1.shape[1] == 0
     # experts stay full precision (documented)
-    assert not isinstance(q.moe_layers[1].w1, QTensor)
+    assert not isinstance(q.blocks[1].moe.w1, QTensor)
     # ...and the quantized-MoE model still runs forward
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 31, size=(2, 8)))
     out = q(toks)
