@@ -4,10 +4,13 @@ One fit is what ``python -m keystone_tpu lm --config <file>`` does:
 ``models/lm_transformer.py::fit`` makes the model and the Markov stream
 from the seed and trains ``steps`` optimizer steps through ``train()``.
 The check makes one more such fit, asks it for what its steps said of
-themselves (``history``), and holds it to the plain reference run on
-the same weights and windows: the losses of steps 0 and 1, the gradient
-norms of step 0 by group, and the decay of the embedding rows no window
-touched, which a state kept in bfloat16 cannot represent."""
+themselves (``history``), and holds it to the plain reference, which
+draws the stream and the windows itself and holds the program's
+starting weights to the stated init: the windows, the losses of steps 0
+and 1, the gradient norms of step 0 by group, and the decay of the
+embedding rows no window touched, which a state kept in bfloat16 cannot
+represent. ``_laguna_xs2_controls.py`` plants the faults each limit is
+there to refuse."""
 
 from __future__ import annotations
 
@@ -68,7 +71,6 @@ def _conf(seed: int, sizes: dict):
         compute_dtype=sizes["compute_dtype"],
         remat=sizes["remat"],
         logit_chunk=sizes["logit_chunk"],
-        corpus_tokens=sizes["corpus_tokens"],
     )
 
 
@@ -110,61 +112,77 @@ def _norms_by_group(squared) -> dict:
     return ref.group_norms(jax.tree_util.tree_map(np.sqrt, _reference_params(squared)))
 
 
-def check_fits(seed: int, sizes: dict, fits: list[dict]):
-    """Outside the window. One more fit through the program, with its
-    history; then, its state dropped, the reference on the same initial
-    weights and the same windows (a sequence at a time, layer by layer,
-    at the timed sizes)."""
+def reference_readings(seed: int, sizes: dict) -> dict:
+    """What the plain reference says of this seed's fit: its own stream
+    and windows, the program's starting weights held to the init the
+    configuration states, then the losses of steps 0 and 1 and step 0's
+    gradient norms (a sequence at a time, layer by layer, at the timed
+    sizes). Nothing is left on the device."""
     import jax
     import jax.numpy as jnp
 
-    from keystone_tpu.models.lm_transformer import (
-        _step_batch,
-        build_model,
-        fit,
-        synthetic_corpus,
-    )
+    from keystone_tpu.models.lm_transformer import build_model
 
-    conf = _conf(seed, sizes)
     arch = architecture(sizes)
     steps, batch, seq = sizes["steps"], sizes["batch"], sizes["seq"]
-    corpus = synthetic_corpus(sizes["corpus_tokens"], arch["vocab_size"], seed=seed)
-    windows = [_step_batch(corpus, seed, i, batch, seq) for i in range(steps)]
+    stream = ref.markov_stream(arch["vocab_size"], seed)
+    windows = [ref.step_windows(stream, seed, i, batch, seq) for i in range(steps)]
     # embedding rows no input position of any step touched
     quiet = np.setdiff1d(
         np.arange(arch["vocab_size"]), np.concatenate([w[:, :-1].ravel() for w in windows])
     )
-
-    history: dict = {}
-    model, losses, _valid, _s = fit(conf, history=history)
-    got_norms = _norms_by_group(history["grad_sq"][0])
-    embed_after = np.asarray(model.embed[quiet], np.float32)
-    state_dtypes = sorted({str(l.dtype) for l in jax.tree_util.tree_leaves(model)})
-    del model, history
-
-    start = build_model(conf)
+    start = build_model(_conf(seed, sizes))
     # the reference is float32 whatever the program keeps its state in
     params = jax.tree_util.tree_map(
         lambda l: jnp.asarray(l, jnp.float32), _reference_params(start)
     )
     embed_before = np.asarray(start.embed[quiet], np.float32)
-    want_loss0, grads = ref.loss_and_grads_blocked(arch, params, jnp.asarray(windows[0]))
-    want_norms = ref.group_norms(grads)
+    del start
+    init = ref.init_deviation(params)
+    loss0, grads = ref.loss_and_grads_blocked(arch, params, jnp.asarray(windows[0]))
+    norms = ref.group_norms(grads)
     params = ref.adamw_first_step(params, grads, sizes["lr"])
-    del grads, start
-    want_loss1, _ = ref.loss_and_grads_blocked(
+    del grads
+    loss1, _ = ref.loss_and_grads_blocked(
         arch, params, jnp.asarray(windows[1]), want_grads=False
     )
     del params
-    want_loss0, want_loss1 = float(want_loss0), float(want_loss1)
+    return {
+        "windows": windows, "quiet": quiet, "embed_before": embed_before,
+        "init": init, "loss0": float(loss0), "loss1": float(loss1), "norms": norms,
+    }
 
+
+def program_readings(seed: int, sizes: dict) -> dict:
+    """One more fit through the program, with what its steps said of
+    themselves (``history``). Nothing is left on the device."""
+    import jax
+
+    from keystone_tpu.models.lm_transformer import fit
+
+    history: dict = {}
+    model, losses, _valid, _s = fit(_conf(seed, sizes), history=history)
+    return {
+        "losses": losses,
+        "windows": history["windows"],
+        "norms": _norms_by_group(history["grad_sq"][0]),
+        "embed_after": np.asarray(model.embed, np.float32),
+        "state_dtypes": sorted({str(l.dtype) for l in jax.tree_util.tree_leaves(model)}),
+    }
+
+
+def compare(got: dict, want: dict, sizes: dict, fits: list[dict]):
+    """(correct, detail): the program's readings held to the
+    reference's, each under its limit of ``tolerances``."""
+    losses, quiet = got["losses"], want["quiet"]
+    before = want["embed_before"]
     detail = {
-        "loss0": [losses[0], want_loss0],
-        "loss1": [losses[1], want_loss1],
-        "loss0_rel": abs(losses[0] - want_loss0) / want_loss0,
-        "loss1_rel": abs(losses[1] - want_loss1) / want_loss1,
+        "loss0": [losses[0], want["loss0"]],
+        "loss1": [losses[1], want["loss1"]],
+        "loss0_rel": abs(losses[0] - want["loss0"]) / want["loss0"],
+        "loss1_rel": abs(losses[1] - want["loss1"]) / want["loss1"],
         "grad_norms_rel": {
-            k: abs(got_norms[k] - want_norms[k]) / want_norms[k] for k in want_norms
+            k: abs(got["norms"][k] - v) / v for k, v in want["norms"].items()
         },
         "quiet_embedding_rows": int(quiet.size),
         # how far the quiet rows' change over the fit lies from the
@@ -172,25 +190,46 @@ def check_fits(seed: int, sizes: dict, fits: list[dict]):
         # move (weights kept in bfloat16 cannot, by 3e-6 of themselves
         # a step) reads 1
         "quiet_decay_rel": ref.distance(
-            embed_after - embed_before,
-            ref.decayed(embed_before, steps, sizes["lr"]) - embed_before,
+            got["embed_after"][quiet] - before,
+            ref.decayed(before, sizes["steps"], sizes["lr"]) - before,
         ),
-        "state_dtypes": state_dtypes,
+        "init_z_max": want["init"]["z_max"],
+        "init_worst": want["init"]["worst"],
+        # steps whose windows are not the reference's own draw
+        "windows_differ": sum(
+            not np.array_equal(g, w) for g, w in zip(got["windows"], want["windows"])
+        ) + abs(len(got["windows"]) - len(want["windows"])),
+        "state_dtypes": got["state_dtypes"],
         "losses": losses,
     }
     detail["grad_norms_rel_max"] = max(detail["grad_norms_rel"].values())
     bad = [
         (key, detail[key], TOL[key])
-        for key in ("loss0_rel", "loss1_rel", "grad_norms_rel_max", "quiet_decay_rel")
+        for key in (
+            "loss0_rel", "loss1_rel", "grad_norms_rel_max", "quiet_decay_rel",
+            "init_z_max",
+        )
         if not detail[key] <= TOL[key]
     ]
+    if detail["windows_differ"]:
+        bad.append(("windows_differ", detail["windows_differ"], 0))
+    if not want["init"]["norm_scales_are_one"]:
+        bad.append(("norm_scales_are_one", False, True))
     if not quiet.size:
         bad.append(("quiet_embedding_rows", 0, "the decay check needs some"))
-    for i, got in enumerate(fits):
-        if got["losses"] != losses:
-            bad.append((i, "differs from the checked fit", got["losses"]))
+    for i, fit in enumerate(fits):
+        if fit["losses"] != losses:
+            bad.append((i, "differs from the checked fit", fit["losses"]))
     detail["mismatches"] = bad[:5]
     return not bad, detail
+
+
+def check_fits(seed: int, sizes: dict, fits: list[dict]):
+    """Outside the window: one more fit through the program, then, its
+    state dropped, the reference on the same weights and its own
+    windows."""
+    got = program_readings(seed, sizes)
+    return compare(got, reference_readings(seed, sizes), sizes, fits)
 
 
 # ------------------------------------------------------ operations and bytes

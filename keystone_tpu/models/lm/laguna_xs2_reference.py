@@ -30,6 +30,11 @@ Equations (x is the residual stream; no projection has a bias):
 - final rms norm, the head, mean next-token cross-entropy over the
   (sliced) vocabulary.
 
+The traffic is drawn here too (``markov_stream``, ``step_windows``:
+numpy from the seed, the ids inside the vocabulary slice), and the
+starting weights, which are the program's, are held to the init the
+configuration states (``init_deviation``).
+
 ``loss_and_grads`` differentiates the whole forward at once (small
 sizes). ``loss_and_grads_blocked`` gives the same numbers a sequence at
 a time and layer by layer, one attention head at a time, so that the
@@ -278,7 +283,65 @@ def loss_and_grads_blocked(cfg, params, tokens, want_grads: bool = True):
     return total, grads
 
 
+# ------------------------------------------------------------------ traffic
+
+STREAM_TOKENS = 200_000  # the one length of the program's synthetic stream
+
+
+def markov_stream(vocab: int, seed: int, n: int = STREAM_TOKENS):
+    """The seeded order-1 Markov stream over ``vocab`` ids: every id has
+    four successors, taken with probabilities 0.7, 0.15, 0.1, 0.05."""
+    rng = np.random.default_rng(seed)
+    successors = rng.integers(0, vocab, size=(vocab, 4))
+    choices = rng.choice(4, size=n, p=np.array([0.7, 0.15, 0.1, 0.05]))
+    out = np.empty(n, np.int32)
+    out[0] = 0
+    for i in range(1, n):
+        out[i] = successors[out[i - 1], choices[i]]
+    return out
+
+
+def step_windows(stream, seed: int, step: int, batch: int, seq: int):
+    """Step ``step``'s (batch, seq + 1) windows of the stream, from
+    (seed, step) alone."""
+    rng = np.random.default_rng(np.random.SeedSequence((seed, step)))
+    starts = rng.integers(0, len(stream) - seq - 1, size=batch)
+    return np.stack([stream[s : s + seq + 1] for s in starts])
+
+
 # ------------------------------------------------------------------ checks
+
+def init_deviation(params) -> dict:
+    """How far starting weights lie from the stated init: every matrix
+    normal with mean 0 and deviation 1/sqrt(rows) (its input width), the
+    embedding 0.02, every norm scale exactly one. ``z_max`` is the
+    largest, over the matrices, of the sample mean's and the sample
+    deviation's distance from the stated one in standard errors
+    (deviation/sqrt(n) and deviation/sqrt(2n) for n entries): a sound
+    draw reads 3 to 4 at any size, a deviation wrong by a tenth at 400
+    entries reads 3 and at 100 000 entries 45."""
+    flat = {"embed": params["embed"], "head": params["head"]}
+    ones = bool(jnp.all(params["final_norm"] == 1.0))
+    for i, p in enumerate(params["layers"]):
+        for k, w in p.items():
+            if k.startswith("norm"):
+                ones = ones and bool(jnp.all(w == 1.0))
+            else:
+                flat[f"layer{i}.{k}"] = w
+    worst, z_max = "", 0.0
+    for name, w in flat.items():
+        stated = 0.02 if name == "embed" else 1.0 / np.sqrt(w.shape[-2])
+        z = jnp.asarray(w, jnp.float32) / stated
+        n = z.size
+        got = max(
+            abs(float(jnp.mean(z))) * np.sqrt(n),
+            abs(float(jnp.std(z)) - 1.0) * np.sqrt(2 * n),
+        )
+        if got > z_max:
+            worst, z_max = name, got
+    return {"z_max": z_max, "worst": worst, "norm_scales_are_one": ones}
+
+
 
 def group_norms(grads) -> dict:
     """Gradient norms by group: embedding, head, and each layer's

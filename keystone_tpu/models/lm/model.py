@@ -190,16 +190,18 @@ def _rope(x, positions, rope: RopeSpec = RopeSpec()):
     ).astype(x.dtype)
 
 
-def _block_apply(x, blk: LMBlock, cdt, attn, mm_fn=mm, eps: float = 1e-6):
+def _block_apply(x, blk: LMBlock, cdt, attn, mm_fn=mm, eps: float = 1e-6,
+                 mesh=None):
     """Pre-norm residual block shared by training forward, prefill, and
     decode: ``attn(y, blk) -> (attention output (N,S,d), aux)``. Routed
-    experts (``blk.moe``) take the dense FFN's place; returns
-    (x, attn_aux, the expert layer's counters or None)."""
+    experts (``blk.moe``) take the dense FFN's place, told the ``mesh``
+    the activations are split over; returns (x, attn_aux, the expert
+    layer's counters or None)."""
     a, aux = attn(_norm(x, blk.norm1, eps, cdt), blk)
     x = x + a
     y = _norm(x, blk.norm2, eps, cdt)
     if blk.moe is not None:
-        f, counters = blk.moe(y)
+        f, counters = blk.moe(y, mesh)
         return x + f, aux, counters
     with jax.named_scope("dense_ffn"):
         return x + ffn(y, blk.w1, blk.w2, blk.w3, cdt, mm_fn), aux, None
@@ -269,8 +271,8 @@ class TransformerLM:
     # attention strategy: "local" (dense or Pallas flash on TPU),
     # "ring" / "ulysses" (sequence-parallel over `seq_axis` of `mesh`).
     # A "local" model whose batch or heads are split over a mesh carries
-    # that mesh too: the flash kernel is shard_mapped over it (GSPMD
-    # cannot partition a Mosaic kernel)
+    # that mesh too: the flash kernel and the experts' grouped product
+    # are shard_mapped over it (GSPMD cannot partition a Mosaic kernel)
     seq_mode: str = static_field(default="local")
     mesh: object = static_field(default=None)
     seq_axis: str = static_field(default="data")
@@ -496,6 +498,7 @@ class TransformerLM:
                 lambda y, b: (self._attention(y, b), None),
                 mm_fn=model_mm(self),
                 eps=self.norm_eps,
+                mesh=self.mesh,
             )
             return out, counters
 

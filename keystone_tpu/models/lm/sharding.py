@@ -57,10 +57,9 @@ def shard_params(model: TransformerLM, mesh) -> TransformerLM:
             w1=put(b.w1, P(None, "model")),
             w2=put(b.w2, P("model", None)),
             w3=opt(b.w3, P(None, "model")),
-            # routed experts stay whole on every device: the grouped
-            # product is a Mosaic kernel, which GSPMD cannot partition,
-            # and the exchange that expert parallelism needs is not
-            # written yet (ROADMAP C8)
+            # routed experts stay whole on every device (their layer
+            # shard_maps its tokens over `data`): the exchange that
+            # expert parallelism needs is not written yet (ROADMAP C8)
         )
         for b in model.blocks
     )

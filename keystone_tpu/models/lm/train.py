@@ -222,14 +222,7 @@ def make_train_step(
     an ``optimizer`` from :func:`make_optimizer` compares equal to
     another of the same settings, so this returns a thin binding and
     compiles nothing new on a later call."""
-    if not isinstance(optimizer, OptimizerSpec):
-        # a bare optax transformation: hashed by identity, so the
-        # program is this object's alone
-        optimizer = OptimizerSpec(optimizer)
-    bound = functools.partial(
-        _train_step, optimizer=optimizer, logit_chunk=logit_chunk,
-        skip_nonfinite=skip_nonfinite,
-    )
+    bound = _bind_step(optimizer, logit_chunk, skip_nonfinite)
     if guarded:
         return lambda model, opt_state, tokens, poison: bound(
             model, opt_state, tokens, poison
@@ -237,6 +230,20 @@ def make_train_step(
     return lambda model, opt_state, tokens: bound(
         model, opt_state, tokens, None
     )[:3]
+
+
+def _bind_step(optimizer, logit_chunk: int, skip_nonfinite: bool):
+    """:func:`_train_step` with its static arguments bound:
+    ``step(model, opt_state, tokens, poison) -> (model, opt_state, loss,
+    StepStats)``."""
+    if not isinstance(optimizer, OptimizerSpec):
+        # a bare optax transformation: hashed by identity, so the
+        # program is this object's alone
+        optimizer = OptimizerSpec(optimizer)
+    return functools.partial(
+        _train_step, optimizer=optimizer, logit_chunk=logit_chunk,
+        skip_nonfinite=skip_nonfinite,
+    )
 
 
 def _step_batch(corpus, seed: int, i: int, batch: int, seq: int):
@@ -339,7 +346,8 @@ def train(
     (model, losses). A caller's ``history`` dict receives what the steps
     said of themselves, read from the device once at the end:
     ``grad_sq`` (step → the parameters' tree of squared gradient norms)
-    and ``counters`` (step → the expert layers' counters).
+    and ``counters`` (step → the expert layers' counters), and
+    ``windows`` (step → the token windows it trained on).
 
     While spans are on (``observe/spans.py``: an event sink or a
     profiler session) the call is one ``fit`` root span, or the
@@ -446,12 +454,10 @@ def train(
     optimizer = make_optimizer(
         lr, steps=steps, schedule=schedule, grad_clip=grad_clip
     )
-    step = functools.partial(
-        _train_step, optimizer=optimizer, logit_chunk=logit_chunk,
-        skip_nonfinite=skip_nonfinite,
-    )
+    step = _bind_step(optimizer, logit_chunk, skip_nonfinite)
     losses = []
     stats = []
+    seen = []
     sharding = None
     if (
         mesh is not None
@@ -665,6 +671,8 @@ def train(
             with _spans.span("train.step", step=i + 1) as s_ctx:
                 with _spans.span("fit.load", bucket="wait_host"):
                     windows = _step_batch(corpus, seed, i, batch, seq)
+                if history is not None:
+                    seen.append(windows)
                 with _spans.span(
                     "fit.h2d", bucket="wait_host", bytes=windows.nbytes
                 ):
@@ -681,7 +689,10 @@ def train(
                 )
                 # a recorded step ends when its device work has
                 _spans.force(loss)
-            stats.append(step_stats)
+            if history is not None or _spans.active_span_log() is not None:
+                # what only a caller's history and a recorded
+                # fit.counters span read: else dropped with the step
+                stats.append(step_stats)
             # keep the loss on device: a float() here would block a host
             # round-trip into every step and serialize the dispatch queue
             # (exception: an active telemetry sink reads the scalar below
@@ -892,6 +903,7 @@ def train(
         got = jax.device_get(stats)
         history["grad_sq"] = [g.grad_sq for g in got]
         history["counters"] = [g.counters for g in got]
+        history["windows"] = seen
     return model, [float(l) for l in losses]
 
 

@@ -110,10 +110,6 @@ class LMConfig:
         "(models/lm/configs/<name>.json, e.g. laguna_xs2) or a path to "
         "one; its sizes replace --dim/--depth/--num-heads/--vocab",
     )
-    corpus_tokens: int = arg(
-        default=200_000,
-        help="length of the synthetic Markov stream (no --corpus)",
-    )
     remat: bool = arg(
         default=False,
         help="rematerialize each block in the backward pass",
@@ -213,7 +209,7 @@ def fit(conf: LMConfig, mesh=None, history: dict | None = None):
             model = build_model(conf, mesh)
             if not conf.corpus:
                 corpus = synthetic_corpus(
-                    conf.corpus_tokens, model.embed.shape[0], seed=conf.seed
+                    200_000, model.embed.shape[0], seed=conf.seed
                 )
             force(model)
         t0 = time.time()

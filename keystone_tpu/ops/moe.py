@@ -32,6 +32,7 @@ the grouped product ran over (whole row tiles).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -202,7 +203,45 @@ class MoELayer:
         weights = top / jnp.sum(top, axis=-1, keepdims=True)
         return weights * self.routed_scale, idx
 
-    def __call__(self, x):
+    def __call__(self, x, mesh=None):
+        """``mesh``: the mesh the caller's arrays are split over, if
+        any. GSPMD cannot partition a Mosaic kernel, so under a mesh of
+        more than one device the routed part runs in a ``shard_map``:
+        the batch split over ``data`` (whole where ``data`` does not
+        divide it), every device routing its own tokens through the
+        experts, which it holds whole."""
+        if mesh is not None and mesh.size > 1:
+            from jax.sharding import PartitionSpec as P
+
+            n_data = mesh.shape.get("data", 1)
+            axis = "data" if n_data > 1 and x.shape[0] % n_data == 0 else None
+            routed = jax.shard_map(
+                lambda m, xs: m._routed(xs, axis),
+                mesh=mesh,
+                in_specs=(P(), P(axis)),
+                out_specs=(P(axis), P()),
+                check_vma=False,  # pallas_call outputs carry no vma
+            )
+            # the shared expert is plain XLA and stays outside
+            out, counters = routed(
+                dataclasses.replace(
+                    self, shared_w1=None, shared_w2=None, shared_w3=None
+                ),
+                x,
+            )
+        else:
+            out, counters = self._routed(x)
+        if self.shared_w1 is not None:
+            with jax.named_scope("moe_shared_expert"):
+                out = out + ffn(
+                    x, self.shared_w1, self.shared_w2, self.shared_w3, x.dtype
+                )
+        return out, counters
+
+    def _routed(self, x, axis: str | None = None):
+        """The held experts' part of the routed sum for these tokens,
+        and the counters; under a ``shard_map`` that split the tokens
+        over ``axis`` the counters are summed over it."""
         b, s, d = x.shape
         t, k = b * s, self.top_k
         xf = x.reshape(t, d)
@@ -239,20 +278,18 @@ class MoELayer:
                 "tk,tkd->td", weights.astype(cdt), y,
                 preferred_element_type=jnp.float32,
             ).astype(cdt)
-        if self.shared_w1 is not None:
-            with jax.named_scope("moe_shared_expert"):
-                out = out + ffn(
-                    xf, self.shared_w1, self.shared_w2, self.shared_w3, cdt
-                )
         held = jax.lax.dynamic_slice_in_dim(group_sizes, first, self.held)
         ends = jnp.cumsum(group_sizes)
         end = jax.lax.dynamic_slice_in_dim(ends, first, self.held)
         start = end - held
         # the product visits every row tile a held expert's rows touch
         tiles = jnp.where(held > 0, -(-end // tm) - start // tm, 0)
+        mm_rows = tm * jnp.sum(tiles)
+        if axis is not None:
+            held, mm_rows = jax.lax.psum((held, mm_rows), axis)
         counters = {
             "routed_rows": jnp.sum(held),
             "max_expert_rows": jnp.max(held),
-            "mm_rows": tm * jnp.sum(tiles),
+            "mm_rows": mm_rows,
         }
         return out.reshape(b, s, d), counters

@@ -1,0 +1,82 @@
+"""Compiles for the chip without the chip: the TPU's compiler is
+installed here and compiles for a described v5e 2x2 host what the CPU's
+interpret mode cannot refuse. Nothing runs, so these say nothing of
+results or times. All such tests live in this one file: the process that
+describes the topology keeps the TPU's library until it exits."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps the compiler away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture()
+def mosaic(monkeypatch):
+    """The kernels as the chip gets them: no interpret mode, and no
+    cache entry that only a chip could read back."""
+    import keystone_tpu.ops.flash_attention as fa
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(fa, "interpret_default", lambda: False)
+    kept = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", kept)
+    compilation_cache.reset_cache()
+
+
+def _laguna_expert_layer(mesh):
+    """Shapes of one Laguna-XS.2 expert layer (32 of 256 experts held)
+    and of 4 x 2048 tokens in bfloat16, whole on every device but for
+    the batch, which is split over ``data``."""
+    from keystone_tpu.ops.moe import MoELayer
+
+    layer = jax.eval_shape(
+        lambda: MoELayer.create(
+            jax.random.key(0), 2048, 512, 256, held=32, top_k=8, swiglu=True,
+            shared_ff=512, scoring="sigmoid", routed_scale=2.5,
+        )
+    )
+    whole = NamedSharding(mesh, P())
+    layer = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=whole), layer
+    )
+    x = jax.ShapeDtypeStruct(
+        (4, 2048, 2048), jnp.bfloat16, sharding=NamedSharding(mesh, P("data"))
+    )
+    return layer, x
+
+
+def _loss_and_grads(mesh):
+    def loss(m, t):
+        out, counters = m(t, mesh)
+        return jnp.sum(jnp.square(out.astype(jnp.float32))), counters
+
+    return jax.jit(jax.value_and_grad(loss, has_aux=True))
+
+
+def test_routed_experts_compile_for_four_chips_only_under_their_mesh(topo, mosaic):
+    """The grouped product is a Mosaic kernel, which GSPMD refuses to
+    partition: told its mesh, the layer shard_maps it and the backward's
+    weight gradients are all-reduced over the mesh; not told, the
+    compiler refuses the program (what a routed model on the four-chip
+    host would have met)."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    layer, x = _laguna_expert_layer(mesh)
+    text = _loss_and_grads(mesh).lower(layer, x).compile().as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    with pytest.raises(Exception, match="cannot be automatically partitioned"):
+        _loss_and_grads(None).lower(layer, x).compile()

@@ -296,7 +296,7 @@ def _fit_conf(tmp_path, toy, **kw):
     path = tmp_path / "toy.json"
     path.write_text(json.dumps(toy))
     return lm.LMConfig(config=str(path), steps=2, batch=2, seq=64, seed=5,
-                       logit_chunk=16, corpus_tokens=4096, remat=True, **kw)
+                       logit_chunk=16, remat=True, **kw)
 
 
 def test_a_second_fit_records_no_jit_span(tmp_path, toy):
@@ -371,30 +371,49 @@ def test_operations_against_hand_worked_numbers(adapter):
     assert work["attn_window_kernel_flops_per_step"] == pytest.approx(2 * window)
 
 
-def test_the_check_passes_the_program_and_refuses_bfloat16_weights(adapter, monkeypatch):
+def test_the_check_passes_the_program(adapter):
     """The gate itself, at toy size: the program agrees with the
-    reference; the same program with its weights (and so its moments)
-    kept in bfloat16 loses the decay of the quiet embedding rows, reads
-    1 there and is refused; a fit of the window that returned other
-    losses is refused too."""
+    reference, which drew the same windows itself and finds the stated
+    init; a fit of the window that returned other losses is refused."""
     mod, sizes_of = adapter
     toy = sizes_of(True)
-    ok, detail = mod.check_fits(7, toy, [])
+    got, want = mod.program_readings(7, toy), mod.reference_readings(7, toy)
+    ok, detail = mod.compare(got, want, toy, [])
     assert ok, detail["mismatches"]
     assert detail["loss0_rel"] < 1e-5 and detail["grad_norms_rel_max"] < 1e-4
     assert detail["quiet_embedding_rows"] > 20 and detail["quiet_decay_rel"] < 0.05
-    ok, again = mod.check_fits(7, toy, [{"losses": [detail["losses"][0], 0.0]}])
+    assert detail["windows_differ"] == 0 and detail["init_z_max"] < 5.0
+    assert all(w.shape == (2, 65) and 0 <= w.min() and w.max() < 256
+               for w in want["windows"])
+    ok, again = mod.compare(got, want, toy, [{"losses": [detail["losses"][0], 0.0]}])
     assert not ok and "differs" in again["mismatches"][0][1]
 
-    import keystone_tpu.models.lm_transformer as entry
 
-    build = entry.build_model
+# plant -> the limits that refuse it at toy size (float32 compute, so
+# the rounding-sized limits read far under their chip readings)
+PLANTS = {
+    "bfloat16_state": {"quiet_decay_rel", "loss1_rel"},
+    "half_batch": {"windows_differ", "grad_norms_rel_max"},
+    "no_update": {"quiet_decay_rel", "loss1_rel"},
+    "no_window": {"grad_norms_rel_max"},
+    "init_scale": {"init_z_max"},
+    "ids_outside_slice": {"windows_differ"},
+}
 
-    def in_bfloat16(conf, mesh=None):
-        return jax.tree.map(lambda l: l.astype(jnp.bfloat16), build(conf, mesh))
 
-    monkeypatch.setattr(entry, "build_model", in_bfloat16)
-    ok, low = mod.check_fits(7, toy, [])
-    assert not ok and low["state_dtypes"] == ["bfloat16"]
-    assert low["quiet_decay_rel"] == pytest.approx(1.0, abs=0.05)
-    assert "quiet_decay_rel" in [m[0] for m in low["mismatches"]]
+@pytest.mark.parametrize("plant", sorted(PLANTS))
+def test_the_check_refuses_a_planted_fault(adapter, plant):
+    """The controls the builder runs on the chip
+    (``benchmarks/configs/_laguna_xs2_controls.py``), at toy size: each
+    fault comes out not correct, by the limits that are there for it."""
+    from harness import find
+
+    mod, sizes_of = adapter
+    controls = find.load_module("configs", "_laguna_xs2_controls.py")
+    assert set(controls.plants(mod)) == set(PLANTS) | {"sound"}
+    line = controls.run_plant(mod, plant, 7, sizes_of(True))
+    assert not line["correct"]
+    assert PLANTS[plant] <= set(line["refused_by"]), line
+    if plant in ("bfloat16_state", "no_update"):
+        # a state that did not move
+        assert line["quiet_decay_rel"] == pytest.approx(1.0, abs=0.05)

@@ -149,16 +149,38 @@ def test_the_product_runs_over_whole_row_tiles_of_the_held_experts(rng):
     assert routed < 1024  # six of eight experts are held elsewhere
 
 
-def test_data_sharded_tokens_match_the_unsharded_layer(mesh4x2):
+@pytest.mark.parametrize("batch", [8, 6], ids=["split_over_data", "whole"])
+def test_on_a_mesh_the_layer_is_the_unsharded_one(mesh4x2, batch):
+    """Told its mesh, the layer shard_maps the routed part (GSPMD
+    cannot partition the grouped kernel): the batch over ``data`` where
+    it divides, whole where not. Output, every gradient and the counters
+    are the one-device layer's."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     rng = np.random.default_rng(0)
-    layer = _layer(num_experts=2)
-    x = jnp.asarray(rng.normal(size=(8, 4, 16)).astype(np.float32))
-    want, _ = layer(x)
-    xs = jax.device_put(x, NamedSharding(mesh4x2, P("data", None, None)))
-    out, _ = jax.jit(lambda l, t: l(t))(layer, xs)
+    layer = _layer(**CASES["share_top2_sigmoid_swiglu_shared"])
+    x = jnp.asarray(rng.normal(size=(batch, 4, 16)).astype(np.float32))
+
+    def loss(m, t, mesh=None):
+        out, counters = m(t, mesh)
+        return jnp.sum(out * out), (out, counters)
+
+    (_, (want, want_c)), want_g = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+        layer, x
+    )
+    split = P("data" if batch % 4 == 0 else None, None, None)
+    xs = jax.device_put(x, NamedSharding(mesh4x2, split))
+    (_, (out, got_c)), got_g = jax.jit(
+        jax.value_and_grad(lambda m, t: loss(m, t, mesh4x2), (0, 1), has_aux=True)
+    )(layer, xs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+    assert {k: int(v) for k, v in got_c.items() if k != "mm_rows"} == {
+        k: int(v) for k, v in want_c.items() if k != "mm_rows"
+    }
+    # each device's product runs over its own whole row tiles
+    assert int(got_c["mm_rows"]) >= int(got_c["routed_rows"])
+    for got, ref in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-4)
 
 
 def test_create_refuses_a_share_outside_the_model():
