@@ -891,8 +891,9 @@ LM_LONG_SEQ, LM_LONG_DIM, LM_LONG_DEPTH = 16_384, 512, 4
 def _flash_tuned_env(path: str | None = None) -> dict:
     """Winning block sizes from the on-chip flash sweep
     (FLASH_SWEEP.json, tools/flash_sweep.py), as KST_FLASH_* env knobs
-    for the long-context bench. The sweep tags configs
-    ``q{bq}_k{bk}_bwd{bwd}_c{chunks}``; a malformed or missing artifact
+    for the long-context bench. The sweep tags configs ``q{bq}_k{bk}``
+    (an artifact from before the backward became a kernel carries two
+    more parts, which nothing reads); a malformed or missing artifact
     means no override (kernel defaults)."""
     if path is None:
         path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -900,14 +901,10 @@ def _flash_tuned_env(path: str | None = None) -> dict:
     try:
         with open(path) as f:
             best = json.load(f)["best"]["config"]
-        bq, bk, bwd, chunks = (
-            part.lstrip("qkbwdc") for part in best.split("_")
-        )
+        bq, bk = (part.lstrip("qk") for part in best.split("_")[:2])
         return {
             "KST_FLASH_BLOCK_Q": str(int(bq)),
             "KST_FLASH_BLOCK_K": str(int(bk)),
-            "KST_FLASH_BWD_BLOCK": str(int(bwd)),
-            "KST_FLASH_BWD_CHUNKS": str(int(chunks)),
         }
     except (OSError, ValueError, KeyError, TypeError):
         return {}

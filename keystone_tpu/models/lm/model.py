@@ -294,7 +294,7 @@ class TransformerLM:
     compute_dtype: str = static_field(default="float32")
     # "learned" = trained absolute table (pos_embed, capped at max_seq);
     # "rope" = rotary q/k phases — no table, no length cap beyond memory,
-    # the right pairing for the blockwise long-context backward
+    # the right pairing for the long-context kernel backward
     pos_encoding: str = static_field(default="learned")
     # grouped-query attention: K/V carry this many heads (0 = num_heads,
     # plain MHA; 1 = MQA). The decode cache shrinks by num_heads/kv_heads

@@ -383,7 +383,7 @@ def ulysses_attention(
 
     Requires H divisible by the axis size. Prefers ICI bandwidth over ring
     latency — the usual pick when heads are plentiful. ``trainable``
-    makes the flash path differentiable (blockwise recompute backward).
+    makes the flash path differentiable (recompute backward).
     """
     if use_flash is None:
         use_flash = _flash_default()

@@ -13,8 +13,11 @@ VMEM-resident pass (flash-attention schedule):
   online-softmax state (m, l, acc) carried in and out. This is the fused
   inner step of ring attention: the ring loop keeps K/V rotating via
   ``ppermute`` (XLA collectives over ICI) and calls this kernel per hop.
+- :func:`flash_attention_bwd` — the training backward of long sequences:
+  one kernel that holds a K/V head's keys in VMEM and recomputes each
+  live score block there, for dq, dk and dv at once.
 
-Both run compiled on TPU and in Pallas interpret mode on the CPU (the
+All run compiled on TPU and in Pallas interpret mode on the CPU (the
 8-device test mesh), selected automatically. Numerics: scores and the
 online-softmax state are always float32; masked positions use a large
 negative finite constant so no ±inf arithmetic appears in the kernel.
@@ -167,8 +170,8 @@ def _flash_kernel_fori(
     )
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     if with_lse:
-        # row logsumexp of the masked scaled scores — the O(S) residual a
-        # blockwise backward needs (fully masked rows stay at _NEG)
+        # row logsumexp of the masked scaled scores — the O(S) residual
+        # the backward kernel needs (fully masked rows stay at _NEG)
         lse = m + jnp.log(jnp.maximum(l, 1e-30))
         maybe_lse[0][0] = jnp.broadcast_to(lse, maybe_lse[0].shape[1:])
 
@@ -277,7 +280,7 @@ def flash_attention(
 
     ``return_lse=True`` additionally returns the per-row logsumexp of the
     masked scaled scores, (B, H, S_q) float32 — the O(S) residual the
-    blockwise training backward consumes (computed in-kernel from the
+    kernel training backward consumes (computed in-kernel from the
     online-softmax state; costs one extra lane-tile write, not a sweep).
 
     ``kv_resident`` forces the K/V-in-VMEM variant (True) or the
@@ -621,22 +624,23 @@ def _env_int(name: str, default: int) -> int:
 
 
 # bytes budget for the dense-recompute backward's transient (S_q, S_k)
-# tensors (~4 of them, f32, per (b, h)): above this the blockwise
-# O(S·block) backward takes over
+# tensors (~4 of them, f32, per (b, h)): above this the backward kernel
+# takes over
 _DENSE_BWD_MAX_BYTES = 4 << 30
 
 
 def _dense_bwd_max_bytes() -> int:
     # tunable per call like the other KST_FLASH_* knobs (0 forces the
-    # blockwise backward everywhere — the dense-vs-blockwise A/B axis of
+    # kernel backward everywhere — the dense-vs-kernel A/B axis of
     # tools/lm_mfu_push.py); unset/malformed keeps the module default,
     # which tests monkeypatch directly (read at call time)
     return _env_int("KST_FLASH_DENSE_BWD_MAX", _DENSE_BWD_MAX_BYTES)
 
 
 def _bwd_block() -> int:
-    # read per call, like the forward block_q/block_k pair — setting
-    # KST_FLASH_BWD_BLOCK after import must take effect (a tuner knob)
+    # the ring backward's K block (ops/attention.py), read per call like
+    # the forward block_q/block_k pair — setting KST_FLASH_BWD_BLOCK
+    # after import must take effect (a tuner knob)
     return _env_int("KST_FLASH_BWD_BLOCK", 512)
 
 
@@ -645,9 +649,9 @@ def _dense_bwd_bytes(q, k) -> int:
     return 4 * 4 * b * h * s_q * k.shape[2]
 
 
-def _bwd_mask(q_pos, k_pos, s_k_valid, causal: bool, window: int = 0):
-    """(rows, blk) validity mask for one KV block (padding, causality
-    and the causal window).
+def _bwd_mask(q_pos, k_pos, s_k_valid, causal: bool):
+    """(rows, blk) validity mask for one KV block (padding and
+    causality).
 
     Causal positions are BEGIN-aligned (q_pos = i, k_pos = j), matching
     the flash forward's offset convention at q_offset = k_offset = 0; the
@@ -656,27 +660,13 @@ def _bwd_mask(q_pos, k_pos, s_k_valid, causal: bool, window: int = 0):
     valid = (k_pos < s_k_valid)[None, :]
     if causal:
         valid = valid & (q_pos[:, None] >= k_pos[None, :])
-    if window:
-        valid = valid & (q_pos[:, None] - k_pos[None, :] < window)
     return valid
 
 
-# causal backward q-chunking: each chunk sweeps only its live K prefix.
-# More chunks → closer to the ideal 0.5·S² triangle (n chunks execute
-# (n+1)/2n of the rectangle) at the cost of shorter scans; 8 is a good
-# regular-pipelining compromise (0.5625·S²)
-def _bwd_causal_chunks() -> int:
-    return _env_int("KST_FLASH_BWD_CHUNKS", 8)
-
-
-# a window layer's q chunks are single K blocks, so that a chunk sweeps
-# its window's blocks and no others; past this many chunks they grow
-_BWD_WINDOW_CHUNKS_MAX = 32
-
-
 def _grads_rect(qf, kp, vp, gf, delta, lse, q_off, s_k_valid, causal, block,
-                k_off=0, window=0):
-    """Rectangle sweep of the blockwise backward over one q range: scan
+                k_off=0):
+    """Rectangle sweep of the ring backward (``ops/attention.py``, whose
+    offsets are traced) over one q range: a ``jnp`` scan
     over the given (padded) K/V blocks, recomputing each score block from
     (q, k, lse). ``qf`` / ``gf`` are (B, KV, G, S_q, D) and ``kp`` /
     ``vp`` (B, KV, S_k, D): the G query heads of a K/V head are swept
@@ -684,7 +674,7 @@ def _grads_rect(qf, kp, vp, gf, delta, lse, q_off, s_k_valid, causal, block,
     repeated and dk / dv sum over the group in the product itself.
     Positions are global begin-aligned (q_off / k_off = the global
     position of the first q / k row — nonzero k_off serves the ring
-    backward's rotating K/V shards and a window's first live block).
+    backward's rotating K/V shards).
     Returns (dq, dk, dv) for this rectangle, dk/dv over kp's full padded
     length. Peak memory O(S·d) state + O(G·S_q·block) transient."""
     b, kvh, grp, s_q, d = qf.shape
@@ -704,7 +694,7 @@ def _grads_rect(qf, kp, vp, gf, delta, lse, q_off, s_k_valid, causal, block,
         kf = kblk.astype(jnp.float32)
         scores = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * scale
         k_pos = k_off + j * block + jnp.arange(block)
-        mask = _bwd_mask(q_pos, k_pos, s_k_valid, causal, window)
+        mask = _bwd_mask(q_pos, k_pos, s_k_valid, causal)
         p = jnp.where(mask, jnp.exp(scores - lse[..., None]), 0.0)
         dv_j = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
         dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vblk.astype(jnp.float32))
@@ -720,77 +710,202 @@ def _grads_rect(qf, kp, vp, gf, delta, lse, q_off, s_k_valid, causal, block,
     return dq.reshape(b, kvh, grp, s_q, d), dk, dv
 
 
-def _blockwise_grads(q, k, v, g, out, lse, causal: bool, block: int,
-                     window: int = 0):
-    """FlashAttention-style backward. Non-causal: one rectangle sweep.
-    Causal: q chunked into block-aligned prefixes, each sweeping only the
-    K blocks at or below its diagonal — ~0.56·S² of score work instead of
-    the full rectangle's 1.0 (the forward kernel's num_k_live analog).
-    Causal with a window: a chunk sweeps the K blocks from its first
-    query's window to its diagonal and skips every other block.
-    q: (B, H, S, D); k, v: (B, KV, S, D), H a multiple of KV."""
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _flash_bwd_kernel(
+    q_ref,  # (1, block_q, d)
+    k_ref,  # (1, seg, d): this K/V head's segment, resident in VMEM
+    v_ref,
+    g_ref,  # (1, block_q, d): the output's cotangent
+    lse_ref,  # (1, 1, block_q) float32 rows
+    delta_ref,
+    dq_ref,  # (1, 1, block_q, d)
+    dk_ref,  # (1, seg, d) float32, resident: summed over the group's
+    dv_ref,  # heads and the q blocks
+    *,
+    scale: float,
+    block_k: int,
+    causal: bool,
+    window: int,
+    s_k: int,
+):
+    """One program per (K/V head, K segment, query head of the group, q
+    block): sweeps the K blocks of the segment that this q block sees and
+    no others. A score block is made, used and dropped in VMEM, keys on
+    sublanes and queries on lanes, so that ``lse`` and ``delta`` come in
+    as rows and only ``ds`` is transposed (for ``dq``)."""
+    block_q = q_ref.shape[1]
+    seg = k_ref.shape[1]
+    num_k = seg // block_k
+    q_start = pl.program_id(3) * block_q
+    k0 = pl.program_id(1) * seg
+
+    @pl.when(jnp.logical_and(pl.program_id(2) == 0, pl.program_id(3) == 0))
+    def _init():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    # live blocks [lo, hi) of this segment; among them [full_lo, full_hi)
+    # hold no masked pair and skip the mask's arithmetic
+    lo, hi = 0, num_k
+    full_hi = (s_k - k0) // block_k
+    if causal:
+        hi = jnp.clip((q_start + block_q - k0 + block_k - 1) // block_k, 0, num_k)
+        full_hi = jnp.minimum(full_hi, (q_start - k0 + 1) // block_k)
+    full_lo = lo
+    if window:
+        lo = jnp.clip((q_start - (window - 1) - k0) // block_k, 0, num_k)
+        full_lo = (q_start + block_q - 1 - window - k0) // block_k + 1
+    full_lo = jnp.clip(full_lo, lo, hi)
+    full_hi = jnp.clip(full_hi, full_lo, hi)
+
+    q, g = q_ref[0], g_ref[0]
+    # the forward's scores, rounding and all: lse is theirs
+    qs = q * jnp.asarray(scale, q.dtype)
+    lse, delta = lse_ref[0], delta_ref[0]
+    q_pos = q_start + lax.broadcasted_iota(jnp.int32, (1, block_q), 1)
+
+    def step(j, dq, masked: bool):
+        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k_blk, v_blk = k_ref[0, rows, :], v_ref[0, rows, :]
+        x = lax.dot_general(
+            k_blk, qs, _NT, preferred_element_type=jnp.float32
+        ) - lse  # (block_k, block_q)
+        if masked:
+            k_pos = (
+                k0 + j * block_k
+                + lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+            )
+            valid = k_pos < s_k
+            if causal:
+                valid = jnp.logical_and(valid, q_pos >= k_pos)
+            if window:
+                valid = jnp.logical_and(valid, q_pos - k_pos < window)
+            x = jnp.where(valid, x, _NEG)
+        p = jnp.exp(x)
+        dv_ref[0, rows, :] += jnp.dot(
+            p.astype(g.dtype), g, preferred_element_type=jnp.float32
+        )
+        dp = lax.dot_general(
+            v_blk, g, _NT, preferred_element_type=jnp.float32
+        )
+        ds = (p * (dp - delta)).astype(q.dtype)
+        dk_ref[0, rows, :] += jnp.dot(
+            ds, qs, preferred_element_type=jnp.float32
+        )
+        return dq + lax.dot_general(
+            ds, k_blk, _TN, preferred_element_type=jnp.float32
+        )
+
+    edge = functools.partial(step, masked=True)
+    dq = jnp.zeros(q.shape, jnp.float32)
+    dq = lax.fori_loop(lo, full_lo, edge, dq)
+    dq = lax.fori_loop(full_lo, full_hi, functools.partial(step, masked=False), dq)
+    dq = lax.fori_loop(full_hi, hi, edge, dq)
+    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
+
+
+# queries by keys of one score block of the backward kernel. On the chip
+# (v5e, head 128, bfloat16, S = 8192, PR 29) 512 x 512 ran a causal
+# layer's backward in 26.7 ms and a window-512 layer's in 11.0 ms;
+# 256 x 256 took 47.0 and 13.8 ms, 1024 x 1024 26.9 ms (causal)
+_BWD_BLOCK = 512
+
+
+def _bwd_blocks(s_q: int, s_k: int, d_pad: int, itemsize: int):
+    """(block_q, block_k, K blocks a segment) of the backward kernel,
+    from the shape. A segment of K and V stays in VMEM with its float32
+    dk and dv, each double-buffered, inside half the scoped limit."""
+    block_q = min(_BWD_BLOCK, -(-s_q // 8) * 8)
+    block_k = min(_BWD_BLOCK, -(-s_k // 8) * 8)
+    limit = None if interpret_default() else _vmem_limit_bytes()
+    held = 2 * 2 * d_pad * (itemsize + 4)  # bytes a key of K, V, dk, dv
+    fit = max(1, ((limit or 16 << 20) // 2) // (held * block_k))
+    num_k = -(-s_k // block_k)
+    segments = -(-num_k // fit)  # of equal length, so the last pads least
+    return block_q, block_k, -(-num_k // segments)
+
+
+def flash_attention_bwd(q, k, v, g, out, lse, *, causal: bool, window: int = 0):
+    """(dq, dk, dv) of :func:`flash_attention` from its residuals, as one
+    Pallas kernel: FlashAttention's backward with a K/V head's keys held
+    in VMEM. Score blocks the mask kills are skipped; a K/V head's dk
+    and dv sum over its query heads inside the kernel; the products take
+    operands in the inputs' dtype and accumulate in float32. Keys past
+    what VMEM holds are swept a segment at a time, each segment's dq
+    summed outside. Positions are begin-aligned at offset 0."""
+    interpret = interpret_default()
     b, h, s_q, d = q.shape
     kvh, s_k = k.shape[1], k.shape[2]
     grp = h // kvh
-    nb = -(-s_k // block)
-    pad = nb * block - s_k
-    kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    qf = q.astype(jnp.float32).reshape(b, kvh, grp, s_q, d)
-    gf = g.astype(jnp.float32).reshape(b, kvh, grp, s_q, d)
-    # delta_i = Σ_d g·out — the softmax-jacobian diagonal term
-    delta = jnp.sum(
-        gf * out.astype(jnp.float32).reshape(gf.shape), axis=-1
-    )  # (B, KV, G, S_q)
-    lse = lse.reshape(b, kvh, grp, s_q)
-
-    if not causal:
-        dq, dk, dv = _grads_rect(
-            qf, kp, vp, gf, delta, lse, 0, s_k, False, block
-        )
-        return (
-            dq.reshape(q.shape).astype(q.dtype),
-            dk[:, :, :s_k].astype(k.dtype),
-            dv[:, :, :s_k].astype(v.dtype),
-        )
-
-    # causal (s_q == s_k enforced by the trainable wrapper): chunk edges
-    # in whole K blocks so each chunk's live prefix is block-aligned
-    n_chunks = min(
-        _BWD_WINDOW_CHUNKS_MAX if window else _bwd_causal_chunks(), nb
+    scale = 1.0 / math.sqrt(d)
+    d_pad = -(-d // _LANE) * _LANE
+    block_q, block_k, seg_blocks = _bwd_blocks(
+        s_q, s_k, d_pad, q.dtype.itemsize
     )
-    edges = sorted({round(nb * c / n_chunks) for c in range(n_chunks + 1)})
-    dq_parts = []
-    dk = jnp.zeros((b, kvh, nb * block, d), jnp.float32)
-    dv = jnp.zeros_like(dk)
-    for lo, hi in zip(edges[:-1], edges[1:]):
-        q0, q1 = lo * block, min(hi * block, s_q)
-        # K blocks [k_lo, hi) are live: from the first query's window (or
-        # the start) to the chunk's diagonal
-        k_lo = max(0, (q0 - (window - 1)) // block) if window else 0
-        k0, k_end = k_lo * block, hi * block
-        dq_c, dk_c, dv_c = _grads_rect(
-            qf[:, :, :, q0:q1],
-            kp[:, :, k0:k_end],
-            vp[:, :, k0:k_end],
-            gf[:, :, :, q0:q1],
-            delta[:, :, :, q0:q1],
-            lse[:, :, :, q0:q1],
-            q0,
-            s_k,
-            True,
-            block,
-            k_off=k0,
-            window=window,
-        )
-        dq_parts.append(dq_c)
-        dk = dk.at[:, :, k0:k_end].add(dk_c)
-        dv = dv.at[:, :, k0:k_end].add(dv_c)
-    dq = jnp.concatenate(dq_parts, axis=3)
+    seg = seg_blocks * block_k
+    n_seg = -(-s_k // seg)
+
+    def rows(x, heads, block):
+        x = _pad_to(x.reshape(b * heads, x.shape[2], d), 1, block)
+        return _pad_to(x, 2, _LANE)
+
+    qf, gf = rows(q, h, block_q), rows(g, h, block_q)
+    kf, vf = rows(k, kvh, seg), rows(v, kvh, seg)
+    s_q_pad = qf.shape[1]
+    # delta_i = sum_d g * out: the softmax jacobian's diagonal term
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+
+    def row_vectors(x):
+        return _pad_to(x.reshape(b * h, 1, s_q), 2, block_q)
+
+    q_spec = pl.BlockSpec(
+        (1, block_q, d_pad), lambda i, sg, gg, j: (i * grp + gg, j, 0)
+    )
+    kv_spec = pl.BlockSpec((1, seg, d_pad), lambda i, sg, gg, j: (i, sg, 0))
+    row_spec = pl.BlockSpec(
+        (1, 1, block_q), lambda i, sg, gg, j: (i * grp + gg, 0, j)
+    )
+    kv_shape = jax.ShapeDtypeStruct((b * kvh, n_seg * seg, d_pad), jnp.float32)
+    with jax.named_scope("attn_bwd"):
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(
+                _flash_bwd_kernel, scale=scale, block_k=block_k,
+                causal=causal, window=window, s_k=s_k,
+            ),
+            grid=(b * kvh, n_seg, grp, s_q_pad // block_q),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=(
+                pl.BlockSpec(
+                    (1, 1, block_q, d_pad),
+                    lambda i, sg, gg, j: (sg, i * grp + gg, j, 0),
+                ),
+                kv_spec,
+                kv_spec,
+            ),
+            out_shape=(
+                jax.ShapeDtypeStruct(
+                    (n_seg, b * h, s_q_pad, d_pad),
+                    q.dtype if n_seg == 1 else jnp.float32,
+                ),
+                kv_shape,
+                kv_shape,
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=(
+                    "parallel", "arbitrary", "arbitrary", "arbitrary"
+                ),
+                vmem_limit_bytes=None if interpret else _vmem_limit_bytes(),
+            ),
+            interpret=interpret,
+        )(qf, kf, vf, gf, row_vectors(lse), row_vectors(delta))
+    dq = dq[0] if n_seg == 1 else jnp.sum(dq, axis=0)
     return (
-        dq.reshape(q.shape).astype(q.dtype),
-        dk[:, :, :s_k].astype(k.dtype),
-        dv[:, :, :s_k].astype(v.dtype),
+        dq[:, :s_q, :d].reshape(q.shape).astype(q.dtype),
+        dk[:, :s_k, :d].reshape(k.shape).astype(k.dtype),
+        dv[:, :s_k, :d].reshape(v.shape).astype(v.dtype),
     )
 
 
@@ -801,22 +916,19 @@ def flash_attention_trainable(q, k, v, causal: bool = False, window: int = 0):
     KV (grouped-query attention without repeating K and V); ``window``
     as in :func:`flash_attention`.
 
-    The flash kernels above are forward-only (inference featurizers and
-    the ring/Ulysses per-hop updates). Training needs a VJP: save ONLY
-    (q, k, v) from the forward — nothing S²-sized persists between the
-    forward and backward (with per-layer remat that's what bounds memory
-    ACROSS the step). The backward recomputes attention two ways:
+    Nothing S²-sized persists between the forward and the backward (with
+    per-layer remat that's what bounds memory ACROSS the step). The
+    backward recomputes attention one of two ways, by the shape:
 
     - short context (transient bytes ≤ ``_DENSE_BWD_MAX_BYTES``, counting
-      the B·H multiplier): differentiate the dense formulation — a few
-      transient (S_q, S_k) tensors, fastest at sizes where they fit;
-    - long context: FlashAttention-style blockwise backward — the
-      forward kernel emits the row logsumexp (O(S), in-kernel, no extra
-      sweep), and the backward accumulates dq/dk/dv block by block from
-      (q, k, v, out, lse). Peak memory O(S·d + S_q·block), which is what
-      makes 32k+ causal *training* fit a single chip (the forward kernel
-      alone could stream 32k since round 2; the dense backward could
-      not).
+      the B·H multiplier): save only (q, k, v) and differentiate the
+      dense formulation — a few transient (S_q, S_k) tensors, fastest at
+      sizes where they fit;
+    - long context: the forward kernel also emits the row logsumexp
+      (O(S), in-kernel, no extra sweep), and :func:`flash_attention_bwd`
+      makes dq/dk/dv from (q, k, v, out, lse) in one Pallas kernel whose
+      score blocks never leave VMEM. Peak memory O(S·d), which is what
+      makes 32k+ causal *training* fit a single chip.
     """
     return flash_attention(q, k, v, causal=causal, window=window)
 
@@ -825,7 +937,7 @@ def _flash_trainable_fwd(q, k, v, causal: bool, window: int = 0):
     if causal and q.shape[2] != k.shape[2]:
         # the flash forward masks begin-aligned (q_pos >= k_pos at offset
         # 0) while dense_attention's tril is end-aligned — the two only
-        # agree at s_q == s_k, and the blockwise backward assumes the
+        # agree at s_q == s_k, and the backward kernel assumes the
         # forward's convention. Reject rather than return wrong grads.
         raise ValueError(
             f"flash_attention_trainable: causal cross-attention with "
@@ -853,8 +965,8 @@ def _flash_trainable_bwd(causal: bool, window: int, res, g):
             q, k, v,
         )
         return vjp(g)
-    return _blockwise_grads(
-        q, k, v, g, out, lse, causal, _bwd_block(), window
+    return flash_attention_bwd(
+        q, k, v, g, out, lse, causal=causal, window=window
     )
 
 

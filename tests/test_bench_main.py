@@ -202,8 +202,6 @@ def test_flash_tuned_env_parses_sweep_winner(tmp_path):
     assert bench._flash_tuned_env(str(art)) == {
         "KST_FLASH_BLOCK_Q": "256",
         "KST_FLASH_BLOCK_K": "512",
-        "KST_FLASH_BWD_BLOCK": "1024",
-        "KST_FLASH_BWD_CHUNKS": "16",
     }
     art.write_text(json.dumps({"best": None}))  # all-configs-failed sweep
     assert bench._flash_tuned_env(str(art)) == {}

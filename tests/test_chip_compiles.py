@@ -80,3 +80,42 @@ def test_routed_experts_compile_for_four_chips_only_under_their_mesh(topo, mosai
     assert "tpu_custom_call" in text and "all-reduce" in text
     with pytest.raises(Exception, match="cannot be automatically partitioned"):
         _loss_and_grads(None).lower(layer, x).compile()
+
+
+@pytest.mark.parametrize("heads,window", [(48, 0), (64, 512)])
+def test_attention_backward_kernels_compile_at_the_cells_shapes(
+    topo, mosaic, monkeypatch, heads, window
+):
+    """``jax.grad`` of the trainable attention at Laguna-XS.2's two layer
+    shapes (2 x 8192 tokens, head 128, 8 K/V heads, bfloat16): the
+    backward is the ``attn_bwd`` Mosaic call, one a layer and no loop of
+    XLA's around it, and with a K/V head's 8192 keys held as one segment
+    it fits the scoped VMEM limit of the described chip (Mosaic refuses
+    a kernel that does not)."""
+    import re
+
+    import keystone_tpu.ops.flash_attention as fa
+    from jax.sharding import SingleDeviceSharding
+    from keystone_tpu.plan.costs import device_peaks
+
+    limit = device_peaks(topo.devices[0].device_kind).vmem_limit
+    monkeypatch.setattr(fa, "_vmem_limit_bytes", lambda: limit)
+    _block_q, block_k, seg_blocks = fa._bwd_blocks(8192, 8192, 128, 2)
+    assert block_k * seg_blocks == 8192
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((2, heads, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    assert fa._dense_bwd_bytes(q, kv) > fa._DENSE_BWD_MAX_BYTES
+
+    def loss(q, k, v):
+        with jax.named_scope("attn_window" if window else "attn_full"):
+            out = fa.flash_attention_trainable(q, k, v, True, window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    calls = re.findall(r"^\s*%(\S+) = .*custom-call\(.*tpu_custom_call", text, re.M)
+    backward = [c for c in calls if "attn_bwd" in c]
+    assert len(backward) == 1 and len(calls) == 2, calls
+    # the forward's readers match attn_full / attn_window in an op's name
+    assert not any("attn_full" in c or "attn_window" in c for c in backward)
+    assert " while(" not in text

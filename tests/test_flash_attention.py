@@ -162,9 +162,9 @@ def test_ring_flash_under_jit_long_sequence(mesh8, rng):
 
 
 def test_dense_bwd_env_knob_selects_path(rng, monkeypatch):
-    """KST_FLASH_DENSE_BWD_MAX=0 must force the blockwise backward (the
+    """KST_FLASH_DENSE_BWD_MAX=0 must force the kernel backward (the
     lm_mfu_push A/B axis): the fwd saves (out, lse) residuals only on
-    the blockwise path, so their presence IS the path taken."""
+    the kernel's path, so their presence IS the path taken."""
     import keystone_tpu.ops.flash_attention as fa
 
     q = jnp.asarray(rng.normal(size=(1, 2, 128, 32)).astype(np.float32))
@@ -173,7 +173,7 @@ def test_dense_bwd_env_knob_selects_path(rng, monkeypatch):
     assert res[3] is None, "small shape should default to the dense bwd"
     monkeypatch.setenv("KST_FLASH_DENSE_BWD_MAX", "0")
     _, res = fa._flash_trainable_fwd(q, q, q, False)
-    assert res[3] is not None, "env 0 must force the blockwise bwd"
+    assert res[3] is not None, "env 0 must force the kernel bwd"
     # malformed value falls back to the default, like the sibling knobs
     monkeypatch.setenv("KST_FLASH_DENSE_BWD_MAX", "not-an-int")
     _, res = fa._flash_trainable_fwd(q, q, q, False)
@@ -182,15 +182,15 @@ def test_dense_bwd_env_knob_selects_path(rng, monkeypatch):
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("s", [196, 1024])
-def test_blockwise_backward_matches_dense_grads(rng, causal, s, monkeypatch):
-    """The long-context blockwise backward (lse recompute + per-block
-    dq/dk/dv scans) must produce the same gradients as differentiating
-    dense attention — forced on at small S by dropping the dense-path
-    threshold."""
+def test_kernel_backward_matches_dense_grads(rng, causal, s, monkeypatch):
+    """The long-context backward (the Pallas kernel over the forward's
+    lse) must produce the same gradients as differentiating dense
+    attention: forced on at small S by dropping the dense-path
+    threshold, in blocks of 256 and, at 1024, two K segments."""
     import keystone_tpu.ops.flash_attention as fa
 
     monkeypatch.setattr(fa, "_DENSE_BWD_MAX_BYTES", 0)
-    monkeypatch.setenv("KST_FLASH_BWD_BLOCK", "256")
+    monkeypatch.setattr(fa, "_bwd_blocks", lambda *a: (256, 256, 2))
     q, k, v = (
         jnp.asarray(rng.normal(size=(2, 3, s, 32)).astype(np.float32))
         for _ in range(3)
@@ -211,6 +211,70 @@ def test_blockwise_backward_matches_dense_grads(rng, causal, s, monkeypatch):
             np.asarray(gf), np.asarray(gd), atol=2e-3,
             err_msg=f"d{name} mismatch (causal={causal}, s={s})",
         )
+
+
+# the kernel's blocks in these cases: 16 queries by 16 keys
+_BWD_CASES = {
+    # name: (heads, K/V heads, S, D, causal, window, K blocks a segment)
+    "causal": (2, 2, 96, 8, True, 0, 6),
+    "window_under_block": (2, 1, 96, 8, True, 5, 6),
+    "window_is_block": (2, 1, 96, 8, True, 16, 6),
+    "window_three_blocks": (2, 1, 96, 8, True, 48, 6),
+    "group_of_1": (3, 3, 64, 8, True, 24, 4),
+    "group_of_6": (6, 1, 64, 8, True, 0, 4),
+    "group_of_8": (8, 1, 64, 8, True, 24, 4),
+    "ragged_sequence": (4, 2, 70, 8, True, 0, 5),
+    "ragged_window": (4, 2, 70, 8, True, 20, 5),
+    "head_dim_64": (2, 1, 48, 64, True, 0, 3),
+    # 5 blocks of keys in segments of 2: the sixth block is all padding
+    "masked_padded_tail": (2, 1, 70, 8, False, 0, 2),
+    "causal_segments": (4, 2, 96, 8, True, 0, 2),
+    "window_segments": (4, 2, 96, 8, True, 24, 2),
+    "not_causal": (4, 2, 64, 8, False, 0, 4),
+}
+# bfloat16 inputs against the float32 oracle on the same (rounded)
+# inputs: the kernel rounds p and ds to bfloat16 for the MXU and its
+# results to bfloat16, 2**-9 a rounding. The largest distance over
+# these cases and three seeds read 3.9e-3; float32 inputs read 4.3e-7.
+_BWD_LIMIT = {"float32": 1e-5, "bfloat16": 1e-2}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", _BWD_CASES)
+def test_backward_kernel_matches_the_dense_vjp(rng, monkeypatch, case, dtype):
+    """The backward kernel (interpret mode) against ``jax.vjp`` of
+    ``dense_attention`` at full precision: live blocks only, grouped K/V
+    summed in the kernel, padding masked."""
+    import keystone_tpu.ops.flash_attention as fa
+
+    h, kvh, s, d, causal, window, seg_blocks = _BWD_CASES[case]
+    monkeypatch.setattr(fa, "_bwd_blocks", lambda *a: (16, 16, seg_blocks))
+    q, k, v, ct = (
+        jnp.asarray(rng.normal(size=(2, heads, s, d)), dtype)
+        for heads in (h, kvh, kvh, h)
+    )
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, ct)]
+    with jax.default_matmul_precision("highest"):
+        _, vjp = jax.vjp(
+            lambda q, k, v: dense_attention(
+                q, k, v, causal=causal, window=window
+            ),
+            *f32[:3],
+        )
+        want = vjp(f32[3])
+    out, lse = flash_attention(
+        q, k, v, causal=causal, window=window, block_q=16, block_k=16,
+        return_lse=True,
+    )
+    got = fa.flash_attention_bwd(
+        q, k, v, ct, out, lse, causal=causal, window=window
+    )
+    for a, b, name in zip(got, want, "qkv"):
+        assert a.shape == b.shape and a.dtype == q.dtype
+        err = float(
+            jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b)
+        )
+        assert err <= _BWD_LIMIT[dtype], (name, err)
 
 
 @pytest.mark.parametrize("kv_resident", [True, False])

@@ -137,10 +137,10 @@ def masked_dense(q, k, v, window):
     )
 
 
-@pytest.mark.parametrize("blockwise_backward", [False, True])
+@pytest.mark.parametrize("kernel_backward", [False, True])
 @pytest.mark.parametrize("heads,window", [(48, 0), (64, 24), (64, 512)])
 def test_window_kernel_matches_masked_dense_attention(
-    rng, monkeypatch, heads, window, blockwise_backward
+    rng, monkeypatch, heads, window, kernel_backward
 ):
     """The Pallas forward (interpret mode) and both backwards, for the
     48-over-8 and 64-over-8 groupings, K and V never repeated; a window
@@ -150,9 +150,9 @@ def test_window_kernel_matches_masked_dense_attention(
 
     monkeypatch.setenv("KST_FLASH_BLOCK_Q", "16")
     monkeypatch.setenv("KST_FLASH_BLOCK_K", "16")
-    monkeypatch.setenv("KST_FLASH_BWD_BLOCK", "16")
-    if blockwise_backward:
+    if kernel_backward:
         monkeypatch.setattr(fa, "_DENSE_BWD_MAX_BYTES", 0)
+        monkeypatch.setattr(fa, "_bwd_blocks", lambda *a: (16, 16, 6))
     q = jnp.asarray(rng.normal(size=(1, heads, 96, 8)).astype(np.float32))
     k, v, ct = (
         jnp.asarray(rng.normal(size=(1, h, 96, 8)).astype(np.float32))

@@ -1,6 +1,6 @@
 """Flash-attention block-size sweep for the long-context train step.
 
-VERDICT r3 #2 names attention-backward block sizes as an MFU lever; the
+VERDICT r3 #2 names attention block sizes as an MFU lever; the forward
 kernels' tunables are env knobs (`KST_FLASH_*`, ops/flash_attention.py,
 all read per call) — each configuration still runs in a FRESH
 subprocess so the shape-keyed jit cache can't serve config A's
@@ -8,10 +8,11 @@ compiled program to config B. This
 harness times one 16k-token causal train step per
 configuration (the workload whose S² term the blocks govern —
 bench.bench_lm_longctx's shape) and writes FLASH_SWEEP.json with
-tokens/s per config and the winner.
+tokens/s per config and the winner. The backward kernel chooses its
+blocks from the shape (``_bwd_blocks``) and has no knob to sweep.
 
 Run ON CHIP, in one call of the chip tool (the parent stays off jax so
-each child owns the chip; unset JAX_PLATFORMS means TPU). ~1-2 min/config, default grid 6.
+each child owns the chip; unset JAX_PLATFORMS means TPU). ~1-2 min/config, default grid 4.
 """
 
 from __future__ import annotations
@@ -24,17 +25,14 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# (block_q, block_k, bwd_block, bwd_chunks): the defaults first, three
-# single-knob moves, then two combined candidates — enough to read which
-# direction helps without paying the full grid (each extra point is a
-# subprocess-minute or two)
+# (block_q, block_k): the defaults first, then three moves — enough to
+# read which direction helps without paying the full grid (each extra
+# point is a subprocess-minute or two)
 CONFIGS = [
-    (512, 512, 512, 8),
-    (256, 512, 512, 8),
-    (1024, 1024, 512, 8),
-    (512, 512, 1024, 8),
-    (512, 512, 512, 16),
-    (512, 1024, 1024, 16),
+    (512, 512),
+    (256, 512),
+    (1024, 1024),
+    (512, 1024),
 ]
 
 _CHILD = r"""
@@ -75,15 +73,13 @@ def _write(results) -> dict:
 
 def main() -> None:
     results = []
-    for bq, bk, bwd, chunks in CONFIGS:
+    for bq, bk in CONFIGS:
         env = dict(
             os.environ,
             KST_FLASH_BLOCK_Q=str(bq),
             KST_FLASH_BLOCK_K=str(bk),
-            KST_FLASH_BWD_BLOCK=str(bwd),
-            KST_FLASH_BWD_CHUNKS=str(chunks),
         )
-        tag = f"q{bq}_k{bk}_bwd{bwd}_c{chunks}"
+        tag = f"q{bq}_k{bk}"
         try:
             out = subprocess.run(
                 [sys.executable, "-c", _CHILD.format(repo=REPO)],

@@ -99,11 +99,8 @@ def _env_for(cfg: dict) -> dict:
     env = dict(os.environ)
     # scrub every knob this sweep owns, then set the config's —
     # inherited exports must not contaminate a config's measurement
-    # (incl. the flash-sweep's backward-pass knobs: an ambient
-    # KST_FLASH_BWD_* export would skew every stage-2 config)
     for k in ("KST_LOCAL_ATTN", "KST_FLASH_BLOCK_Q",
-              "KST_FLASH_BLOCK_K", "KST_FLASH_DENSE_BWD_MAX",
-              "KST_FLASH_BWD_BLOCK", "KST_FLASH_BWD_CHUNKS"):
+              "KST_FLASH_BLOCK_K", "KST_FLASH_DENSE_BWD_MAX"):
         env.pop(k, None)
     if not cfg["dense_bwd"]:
         env["KST_FLASH_DENSE_BWD_MAX"] = "0"
