@@ -5,8 +5,7 @@ gzipped).
 The reference's MNIST workload reads a CSV conversion
 (MnistRandomFFT.scala expects label-first CSV rows); this loader accepts
 the UPSTREAM format directly so a staged real corpus works without a
-conversion step (VERDICT r2 missing #4: no real-corpus parity point —
-if the driver stages MNIST in either format, the pipeline runs on it).
+conversion step: staged in either format, the pipeline runs on it.
 
 Format (http-era de facto standard): big-endian header
 ``[0, 0, dtype_code, ndim] + ndim * int32 dims``, then row-major data.

@@ -284,8 +284,8 @@ class TransformerLM:
     # "full" recomputes everything inside the block (max memory saving,
     # ~1/3 extra forward FLOPs in the backward); "dots" saves the matmul
     # outputs and recomputes only the cheap elementwise/LN work — the
-    # memory/MFU middle ground (ROOFLINE.md §6): the MXU never re-runs,
-    # so measured step FLOPs stay at the analytic 6·P·tokens
+    # middle ground between memory and speed: the MXU never re-runs, so
+    # the step's FLOPs stay at the analytic 6·P·tokens
     remat_policy: str = static_field(default="full")
     # mixed precision: params/optimizer state stay float32; activations
     # and the matmul operands run in this dtype ("bfloat16" halves HBM
@@ -302,8 +302,8 @@ class TransformerLM:
     num_kv_heads: int = static_field(default=0)
     # how int8 QTensor weights multiply: "xla" trusts the convert-into-
     # dot fusion (ops/quantization.mm); "pallas" streams the codes as
-    # int8 via the fused kernel (ops/int8_matmul.mm_fused) — the A/B the
-    # bench measures e2e (ROOFLINE.md §6 decode note)
+    # int8 via the fused kernel (ops/int8_matmul.mm_fused), which does
+    # not depend on XLA keeping that fusion
     int8_kernel: str = static_field(default="xla")
     # the toy presets scale embeddings by sqrt(d); public configs do not
     embed_scale: bool = static_field(default=True)
@@ -402,19 +402,7 @@ class TransformerLM:
         else:
             from keystone_tpu.ops.flash_attention import on_tpu
 
-            # KST_LOCAL_ATTN overrides the auto-select (read per call,
-            # like the KST_FLASH_* knobs): the S=2048 flagship shape sits
-            # in the regime where dense XLA attention can rival the
-            # Pallas kernel (TPU_VALIDATION 0.98-1.27x at <=8k), so the
-            # MFU push sweeps this axis too (tools/lm_mfu_push2.py)
-            import os as _os
-
-            mode = _os.environ.get("KST_LOCAL_ATTN", "auto")
-            if mode not in ("auto", "flash", "dense"):
-                raise ValueError(
-                    f"KST_LOCAL_ATTN={mode!r}; expected auto|flash|dense"
-                )
-            use_flash = on_tpu() if mode == "auto" else mode == "flash"
+            use_flash = on_tpu()
             # the scope names the layer's kind in the trace's op names
             scope = "attn_window" if window else "attn_full"
             if use_flash:

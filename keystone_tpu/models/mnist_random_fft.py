@@ -145,8 +145,7 @@ def _featurize_fused(signs_mat, data, n: int, alpha: float, max_val: float):
     w = (signs_mat.T[:, :, None] * cos[:, None, :]).reshape(d, -1)
     # materialize w BEFORE the gemm: without the barrier XLA may fuse the
     # signs x cos construction into the dot's RHS loads, recomputing it
-    # per k-tile — measured slower than the unfused chain path despite
-    # equal nominal FLOPs (MFU_SWEEP round 3, VERDICT r3 weak #3)
+    # per k-tile, at equal nominal FLOPs
     w = jax.lax.optimization_barrier(w)
     return jnp.maximum(max_val, data @ w - alpha)
 

@@ -10,8 +10,8 @@ Activation is env-gated and near-zero cost when off:
 
 - ``KEYSTONE_OBSERVE_DIR=/path`` — every process that touches the
   pipeline DSL appends events under a fresh run directory there.
-- :func:`run` — explicit, scoped activation (the CLI launcher, bench,
-  and tests use this); restores the previous sink on exit.
+- :func:`run` — explicit, scoped activation (the CLI launcher and
+  tests use this); restores the previous sink on exit.
 - disabled — :func:`active` is one module-global read returning None,
   and the pipeline hooks take their plain fast path.
 
@@ -21,7 +21,7 @@ Event schema (one JSON object per line; fields beyond these are free-form):
 ``ts``          unix time (float, seconds)
 ``run``         run id (shared by all events of one run)
 ``event``       ``run_start`` | ``run_end`` | ``node`` | ``span`` |
-                ``phase`` | ``optimize`` | ``bench``
+                ``phase`` | ``optimize``
 ``node``        node label (``node`` events), e.g. ``01:BlockLinearMapper``
 ``phase``       ``fit`` | ``apply`` | ``compile`` (first traced call)
 ``wall_s``      wall-clock duration of the bracket
@@ -199,8 +199,7 @@ class JsonlSink:
 class EventLog:
     """A single run's event sink: JSONL file plus an in-memory mirror.
 
-    ``base_dir=None`` gives a memory-only log (bench uses this to build
-    per-node breakdowns without touching disk). All methods are
+    ``base_dir=None`` gives a memory-only log. All methods are
     thread-safe; a failing disk write disables the file sink with one
     warning rather than taking down the run.
     """
@@ -356,7 +355,7 @@ def _close_at_exit(log: EventLog) -> None:
 
 
 def reset() -> None:
-    """Drop the active sink and re-arm env detection (tests, bench)."""
+    """Drop the active sink and re-arm env detection (tests)."""
     global _active
     with _state_lock:
         if isinstance(_active, EventLog):
@@ -396,8 +395,8 @@ def run(
         _active = log
     # a new scoped run means new baselines: without this, the anomaly
     # monitor would carry a previous run's frozen step-wall p95 / loss
-    # EMA into an unrelated workload and mis-alert (bench runs several
-    # training loops of different sizes in one process)
+    # EMA into an unrelated workload and mis-alert (a process may run
+    # several training loops of different sizes)
     from keystone_tpu.observe.health import reset_monitor
 
     reset_monitor()

@@ -9,7 +9,7 @@ in :mod:`keystone_tpu.core.pipeline` whenever an event sink is active.
 - ``sync=True`` blocks on each node's output before stopping the clock,
   so per-node wall time attributes device work to the node that launched
   it instead of to whichever later node forces the value (JAX dispatch
-  is async; see ROOFLINE.md §0),
+  is async),
 - outputs are bit-exact: the wrapper calls the node and returns its
   result untouched (``block_until_ready`` does not change values).
 

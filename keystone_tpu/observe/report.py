@@ -4,8 +4,8 @@ Joins a run's wall-time events (:mod:`.events`) with its per-node cost
 profiles (:mod:`.cost`) into the KeystoneML-style operator summary: per
 node — calls, total/mean wall time, share of run, modeled GFLOPs and
 bytes from ``cost_analysis()``, achieved FLOP/s, and the fraction of the
-chip's bf16 peak (roofline basis: ROOFLINE.md — one v5e chip ≈ 197 TF/s
-bf16, HBM ≈ 819 GB/s; CPU runs have no peak entry and show ``-``).
+chip's bf16 peak (:data:`keystone_tpu.plan.costs.DEVICE_PEAKS`: one v5e
+chip is 197 TF/s bf16; CPU runs have no peak entry and show ``-``).
 """
 
 from __future__ import annotations
@@ -18,23 +18,9 @@ from typing import Any
 from keystone_tpu.observe import cost as _cost
 from keystone_tpu.observe import events as _events
 
-# The roofline basis lives in ONE place now:
-# :data:`keystone_tpu.plan.costs.DEVICE_PEAKS` (bf16 MXU peak, HBM B/s,
-# PCIe B/s, ICI B/s per device kind — ROOFLINE.md). Re-exported here so
-# bench.py / tools/mfu_sweep.py keep their historical import site and
-# the report's vs_peak column can never drift from the planner's
-# transfer/recompute estimates.
-from keystone_tpu.plan.costs import (  # noqa: F401 — re-exports
-    DEVICE_PEAKS,
-    peak_flops_for,
-)
-
-#: legacy aliases (pre-single-sourcing callers): bf16 peaks per chip and
-#: the v5e HBM stream rate, both views of DEVICE_PEAKS
-PEAK_FLOPS = {
-    kind: peaks.flops for kind, peaks in DEVICE_PEAKS.items() if kind != "cpu"
-}
-HBM_BYTES_PER_S = DEVICE_PEAKS["v5 lite"].hbm_bw
+# the vs_peak column reads the planner's table, so it cannot drift from
+# the planner's transfer/recompute estimates
+from keystone_tpu.plan.costs import peak_flops_for
 
 
 def summarize(events: list[dict]) -> dict[str, Any]:
@@ -310,7 +296,7 @@ def render(run_dir: str) -> str:
     if peak is None and profiles:
         lines.append(
             "(no bf16 peak known for this device kind — vs_peak omitted; "
-            "roofline basis: ROOFLINE.md)"
+            "peaks: plan/costs.py DEVICE_PEAKS)"
         )
     return "\n".join(lines)
 
@@ -646,39 +632,6 @@ def _telemetry_sections(run_dir: str, summary: dict) -> list[str]:
                 lines.append(f"  ... {len(series) - 40} more")
             lines.append("")
     return lines
-
-
-def per_node_breakdown(
-    log: "_events.EventLog",
-    profiles: dict[str, dict] | None = None,
-    since: int = 0,
-) -> dict[str, dict]:
-    """Compact per-node dict for embedding in machine artifacts (bench):
-    node label → calls/wall plus flops/bytes when profiled. ``since``
-    restricts to records appended after that index — pass the record
-    count captured before your instrumented apply when reusing an
-    ambient log, so unrelated earlier events don't leak in."""
-    summary = summarize(log.records[since:])
-    out: dict[str, dict] = {}
-    for label, stat in summary["nodes"].items():
-        entry = {
-            "calls": stat["calls"],
-            "wall_s": round(stat["total_s"], 6),
-        }
-        prof = (profiles or {}).get(label, {})
-        if "flops" in prof:
-            entry["flops"] = prof["flops"]
-        if "bytes_accessed" in prof:
-            entry["bytes_accessed"] = prof["bytes_accessed"]
-        out[label] = entry
-    if not out and getattr(log, "dropped", 0):
-        # the in-memory mirror hit its cap before these events: say so
-        # rather than returning {} that reads as "no nodes ran" (the
-        # file sink, when present, still has the full record)
-        return {
-            "error": f"{log.dropped} event records dropped (in-memory cap)"
-        }
-    return out
 
 
 # ------------------------------------------------------------- run diff

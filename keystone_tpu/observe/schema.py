@@ -26,7 +26,6 @@ EVENT_KINDS: dict[str, str] = {
     "phase": "coarse run phase wall (model mains)",
     "optimize": "a planner / fusion / staging decision (plan/passes.py, "
     "core/fusion.py, core/staging.py)",
-    "bench": "the bench.py result record routed through the run log",
     "resilience": "a survived resilience decision: fault, retry, guard, "
     "preemption (resilience/emit.py); fleet routing/failover/breaker/"
     "restart decisions ride the same kind with action=fleet_* "

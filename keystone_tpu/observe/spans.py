@@ -111,8 +111,8 @@ BUCKETS = (
     "checkpoint",
 )
 
-# in-memory mirror cap — enough for the bench's goodput summaries and
-# the trace renderer without growing with run length
+# in-memory mirror cap — enough for the goodput summaries and the
+# trace renderer without growing with run length
 _MAX_MEMORY_SPANS = 8192
 
 _bind_lock = threading.Lock()

@@ -39,8 +39,6 @@ from typing import Any
 
 from keystone_tpu.observe import events as _events
 from keystone_tpu.observe import metrics as _metrics
-from keystone_tpu.observe.metrics import percentiles  # noqa: F401 — the
-# one home of the nearest-rank estimator; bench and tests reach it here
 
 STEPS_FILE = "steps.jsonl"
 
@@ -73,7 +71,7 @@ def _peak_flops_total() -> float | None:
 
 class StepLog:
     """One run's per-step telemetry sink: ``steps.jsonl`` plus a bounded
-    in-memory mirror (bench and the ``--once`` dashboard read it).
+    in-memory mirror (the ``--once`` dashboard reads it).
 
     ``run_dir=None`` gives a memory-only stream. Thread-safe; a failing
     disk write disables the file sink with one warning, same degrade

@@ -202,8 +202,12 @@ def _ring_trainable_fwd(q, k, v, axis_name, causal, use_flash):
     return out, (q, k, v, out, lse)
 
 
+# keys a block of the ring backward's sweep over one hop's K/V shard
+_RING_BWD_BLOCK = 512
+
+
 def _ring_trainable_bwd(axis_name, causal, use_flash, res, g):
-    from keystone_tpu.ops.flash_attention import _bwd_block, _grads_rect
+    from keystone_tpu.ops.flash_attention import _grads_rect
 
     q, k, v, out, lse = res
     n = lax.axis_size(axis_name)
@@ -215,8 +219,7 @@ def _ring_trainable_bwd(axis_name, causal, use_flash, res, g):
     gf = g.astype(jnp.float32)
     delta = jnp.sum(gf * out.astype(jnp.float32), axis=-1)
 
-    bwd_block = _bwd_block()
-    blk = bwd_block if s_local > bwd_block else -(-s_local // 8) * 8
+    blk = min(_RING_BWD_BLOCK, -(-s_local // 8) * 8)
     pad = -(-s_local // blk) * blk - s_local
 
     dq = jnp.zeros((b, h, s_local, d), jnp.float32)

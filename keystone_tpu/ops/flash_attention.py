@@ -39,6 +39,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30  # masked-score value: exp(_NEG - m) underflows to exactly 0
 _LANE = 128
+# queries by keys of one score block of the forward, where the caller
+# passes none (read at call time)
+_BLOCK_Q = _BLOCK_K = 512
 
 
 def on_tpu() -> bool:
@@ -299,9 +302,9 @@ def flash_attention(
     if interpret is None:
         interpret = interpret_default()
     if block_q is None:
-        block_q = _env_int("KST_FLASH_BLOCK_Q", 512)
+        block_q = _BLOCK_Q
     if block_k is None:
-        block_k = _env_int("KST_FLASH_BLOCK_K", 512)
+        block_k = _BLOCK_K
     b, h, s_q, d = q.shape
     kvh, s_k = k.shape[1], k.shape[2]
     if h % kvh or v.shape[1] != kvh:
@@ -611,37 +614,10 @@ def flash_attention_step(
     )
 
 
-def _env_int(name: str, default: int) -> int:
-    """Tuning knob from the environment (the flash_sweep harness sets
-    these per subprocess to map the block-size space on chip; normal use
-    never sets them)."""
-    import os
-
-    try:
-        return int(os.environ.get(name, default))
-    except ValueError:
-        return default
-
-
 # bytes budget for the dense-recompute backward's transient (S_q, S_k)
 # tensors (~4 of them, f32, per (b, h)): above this the backward kernel
 # takes over
 _DENSE_BWD_MAX_BYTES = 4 << 30
-
-
-def _dense_bwd_max_bytes() -> int:
-    # tunable per call like the other KST_FLASH_* knobs (0 forces the
-    # kernel backward everywhere — the dense-vs-kernel A/B axis of
-    # tools/lm_mfu_push.py); unset/malformed keeps the module default,
-    # which tests monkeypatch directly (read at call time)
-    return _env_int("KST_FLASH_DENSE_BWD_MAX", _DENSE_BWD_MAX_BYTES)
-
-
-def _bwd_block() -> int:
-    # the ring backward's K block (ops/attention.py), read per call like
-    # the forward block_q/block_k pair — setting KST_FLASH_BWD_BLOCK
-    # after import must take effect (a tuner knob)
-    return _env_int("KST_FLASH_BWD_BLOCK", 512)
 
 
 def _dense_bwd_bytes(q, k) -> int:
@@ -943,7 +919,7 @@ def _flash_trainable_fwd(q, k, v, causal: bool, window: int = 0):
             f"flash_attention_trainable: causal cross-attention with "
             f"s_q={q.shape[2]} != s_k={k.shape[2]} is ambiguous"
         )
-    if _dense_bwd_bytes(q, k) <= _dense_bwd_max_bytes():
+    if _dense_bwd_bytes(q, k) <= _DENSE_BWD_MAX_BYTES:
         # short context: the dense backward needs only (q, k, v)
         out = flash_attention(q, k, v, causal=causal, window=window)
         return out, (q, k, v, None, None)

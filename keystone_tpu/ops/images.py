@@ -121,8 +121,7 @@ def conv_convolver(
     (``p.F_f``) plus per-patch scalars from two box-filter reductions —
     no (N, oh, ow, k^2 C) patch tensor ever exists. HBM traffic drops from
     ~k^2 x image bytes to image-in/featuremap-out; this is the TPU-first
-    design the fused Pallas kernel approximated, measured faster than
-    both it and the XLA im2col path on a real v5e (TPU_VALIDATION.json).
+    design the retired fused Pallas kernel approximated.
 
     The box sums run through ``lax.reduce_window`` (exact f32 VPU adds),
     not the MXU, so mu/sigma carry no bf16-pass rounding.
@@ -184,10 +183,9 @@ class Convolver(Transformer):
 
     A Pallas im2col kernel (``impl="fused"``) existed through round 2 and
     was retired: per-image im2col with C=3 writes 3-of-128 lanes per
-    store — structurally lane-hostile — and it measured 0.28× the im2col
-    path on v5e while the conv-algebra path won (ROOFLINE.md §5). Folding
-    the normalization *algebraically* around XLA's native conv lowering
-    is the TPU-first answer here, not a hand-written kernel.
+    store, structurally lane-hostile. Folding the normalization
+    *algebraically* around XLA's native conv lowering is the TPU-first
+    answer here, not a hand-written kernel.
 
     ``filters``: (num_filters, patch_size²·C), rows in (dy, dx, c) layout —
     exactly what :class:`Windower`+:class:`ImageVectorizer` sampling or
@@ -320,13 +318,13 @@ class FusedConvRectifyPool(Transformer):
       ``concatenate`` forces XLA to materialize the (N, oh, ow, 2F) map
       in HBM between the rectifier and the pooler; pooling each half
       first keeps the rectifier fused into ``reduce_window``'s operand
-      and the concat runs on the tiny pooled map (measured ~12% e2e on
-      v5e at the CIFAR random-patch shape, and the 2F map never exists).
+      and the concat runs on the tiny pooled map (the 2F map never
+      exists).
     - ``unfused``: the literal three-node chain (parity baseline).
 
     A single fused VMEM Pallas kernel (``impl="pallas"``) existed through
-    round 2 and was retired with the Convolver's kernel — the per-image
-    im2col made it slower than ``auto`` on v5e (ROOFLINE.md §5).
+    round 2 and was retired with the Convolver's kernel, for the same
+    per-image im2col.
 
     Output is identical in shape/layout to the chain: (N, ph, pw, 2F),
     channels ``[pos | neg]``.

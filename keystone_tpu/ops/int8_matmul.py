@@ -2,7 +2,7 @@
 
 Weight-only int8 serving (``ops/quantization.py``) leans on XLA fusing
 the ``q.astype(bf16)`` convert into the dot's operand load — a compiler
-property, not a guarantee (ROOFLINE.md §6 decode note). This kernel
+property, not a guarantee. This kernel
 removes the bet: the int8 codes stream from HBM *as int8* (half the
 bytes of bf16 — decode's entire economics) and are widened in VMEM right
 before the MXU pass, with the per-output-channel f32 scale applied to
@@ -14,12 +14,9 @@ tile and the f32 accumulator carried in VMEM scratch. Runs compiled on
 TPU and in Pallas interpret mode on the CPU (tests).
 
 The serving entry point stays :func:`keystone_tpu.ops.quantization.mm`;
-``mm_fused`` here is the measured alternative — ``tools/mfu_sweep.py``
-A/Bs bf16 vs XLA-int8 vs this kernel at the decode shapes
-(``decode_mm_*`` in MFU_SWEEP.json, weight-stream GB/s), and
-``bench.py`` separately records the e2e float-vs-int8 generate rates —
-so the fusion question is settled by numbers, not assumption
-(VERDICT r3 #4, ROOFLINE.md §6 decode note).
+``mm_fused`` here is the alternative (the LM's ``int8_kernel="pallas"``).
+Which of the two streams fewer bytes a token on the chip is not
+measured: no benchmark cell decodes yet.
 """
 
 from __future__ import annotations

@@ -1,17 +1,17 @@
 """Weight-only int8 quantization for inference.
 
-Decode is the HBM-bound regime (ROOFLINE.md §6, decode note: every step
-re-reads all params), so the serving lever on TPU is weight bytes, not
-FLOPs: int8 weights halve the bf16 stream. Symmetric per-output-channel
+Decode is the HBM-bound regime (every step re-reads all params), so the
+serving lever on TPU is weight bytes, not FLOPs: int8 weights halve the
+bf16 stream. Symmetric per-output-channel
 scales keep the matmul exact up to rounding, applied to the
 activation-sized result (``(y @ q) * scale``).
 
 The int8→compute-dtype convert is written as ``q.astype`` feeding the
 dot; whether the weight stream actually halves rests on XLA fusing that
 convert into the dot's operand load (the usual TPU lowering). That is a
-compiler property, not a code guarantee — which is why the bench records
-the measured int8-vs-float decode rates side by side
-(``lm_decode[_int8]_tokens_per_s``) rather than asserting the ratio.
+compiler property, not a code guarantee, so nothing here asserts the
+ratio: :mod:`keystone_tpu.ops.int8_matmul` is the path whose stream is
+int8 by construction.
 
 The reference has no quantization (it serves f64 BLAS models); this is a
 beyond-reference serving capability in the spirit of the KV-cache

@@ -122,9 +122,7 @@ class PaddedFFT(Transformer):
     - ``matmul``: the same values as one cosine-matrix gemm,
       ``x @ cos(2π k n / N)`` — only the needed half-spectrum's real part
       is ever computed, the zero padding never materializes, and the work
-      lands on the MXU where it fuses with neighboring ops. On v5e this
-      is ~5x faster than XLA's FFT lowering at MNIST shapes (the
-      featurize stage dominated the round-2 bench before this).
+      lands on the MXU where it fuses with neighboring ops.
     - ``auto`` (default): matmul on TPU, fft elsewhere.
     """
 

@@ -150,7 +150,7 @@ def data_sharding_fn(mesh: Mesh | None):
 
 def data_axis_size(mesh: Mesh | None) -> int:
     """Size of the "data" axis; 1 for no mesh or a mesh without one —
-    the ONE home of the shard-count read (planner, staging, bench)."""
+    the ONE home of the shard-count read (planner, staging)."""
     if mesh is None:
         return 1
     try:

@@ -70,7 +70,7 @@ STALL_ACTIONS: dict[str, tuple[tuple[str, int], ...]] = {
     "queue": (("serve_bucket", +1),),
 }
 
-# window summaries kept for bench / the e2e tests — bounded so a
+# window summaries kept for the e2e tests — bounded so a
 # day-long run can't grow the host heap
 _MAX_HISTORY = 256
 
@@ -686,7 +686,7 @@ def active() -> Autotuner | None:
 
 
 def configure(tuner: Autotuner | None) -> None:
-    """Install a tuner programmatically (tests, bench); None disables."""
+    """Install a tuner programmatically (tests); None disables."""
     global _active
     with _state_lock:
         _active = tuner

@@ -12,7 +12,7 @@ of ingest-level skip/retry as a framework concern:
   fault injection; every CI failure replays exactly.
 - :mod:`.retry` — :class:`~keystone_tpu.resilience.retry.RetryPolicy`
   (exponential backoff + jitter + deadline + transient classifier),
-  applied to tar/idx ingestion, checkpoint IO, and the bench probe.
+  applied to tar/idx ingestion and checkpoint IO.
 - :mod:`.guards` — non-finite/spike loss guards for the LM train loop
   (donation-safe in-program skip, one host sync per interval) and the
   opt-in pipeline output guard (``KEYSTONE_GUARD_OUTPUTS``).

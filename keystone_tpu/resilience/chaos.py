@@ -38,9 +38,8 @@ The runner emits one ``chaos`` verdict event, writes a human-readable
 PASS/FAIL report plus a JSON verdict into the report directory, and
 exits nonzero when any invariant fails — the game day is a gate, not a
 demo. Three canned campaigns ship under ``resilience/campaigns/``
-(fleet / train / refit game days); ``bench.py``'s ``chaos_drill``
-record runs the fleet one on CPU-pinned stub replicas so composed-fault
-recovery regressions fail the bench gate like a perf number.
+(fleet / train / refit game days); the fleet one runs on CPU-pinned
+stub replicas.
 """
 
 from __future__ import annotations

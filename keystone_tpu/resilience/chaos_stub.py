@@ -1,4 +1,4 @@
-"""Stdlib-only stub replica for chaos game days and the bench gate.
+"""Stdlib-only stub replica for chaos game days.
 
 The fleet game-day campaign drills the ROUTER's composed-failure
 behavior — failover, breakers, relaunch, the burst's client-visible
@@ -6,8 +6,7 @@ outcome — none of which depends on what the replica computes. This
 worker implements exactly the slice of the ``serve`` HTTP contract the
 router consumes (``POST /predict`` echoing rows doubled, ``GET
 /healthz`` with the ``draining`` flag, SIGTERM drain-then-exit-0) with
-zero jax/model boot cost, so a full campaign runs in seconds and the
-bench ``chaos_drill`` record stays CPU-pinned and cheap. The canned
+zero jax/model boot cost, so a full campaign runs in seconds. The canned
 campaign can swap in real ``serve mnist`` replicas with
 ``"replica": "mnist"`` when the game day should cover the model path
 too (``tests/test_fleet.py`` already drills that stack).

@@ -124,7 +124,7 @@ class DecodeLoop:
     generated ``(n,) int32`` tokens (EOS included when hit); ``step``
     admits queued prompts into free slots, advances every active slot
     one token, and retires finished sequences. ``run`` drives steps
-    until a set of futures resolves (bench/tests); a server runs
+    until a set of futures resolves (tests); a server runs
     :meth:`worker` in a thread instead.
 
     Sampling config is fixed per loop (it is baked into the two
@@ -184,8 +184,8 @@ class DecodeLoop:
         self._slots: list[_Sequence | None] = [None] * slots
         self._tok = np.zeros(slots, np.int32)
         self.cache = self._empty_cache()
-        # occupancy accounting for the batch-fill telemetry the bench
-        # and the serving panel report
+        # occupancy accounting for the batch-fill telemetry the
+        # serving panel reports
         self.tokens_out = 0
         self.occupancy_steps = 0  # sum of active slots over steps
 

@@ -420,9 +420,9 @@ class Fleet:
         ]
         self._next_rid = 0
         self._lock = threading.Lock()
-        # (next_rid below is the public view — request-keyed drills and
-        # the bench key their fault specs off it instead of reaching
-        # into the private counter)
+        # (next_rid below is the public view — request-keyed drills
+        # key their fault specs off it instead of reaching into the
+        # private counter)
         self._inflight = 0
         self._stop = threading.Event()
         self._restart_lock = threading.Lock()

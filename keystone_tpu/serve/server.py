@@ -713,7 +713,7 @@ options:
   --deadline-ms F   micro-batch SLO deadline (default KEYSTONE_SERVE_DEADLINE_MS)
   --synthetic N     mnist demo fit size (default 2048)
   --num-ffts N      mnist demo featurizer count (default 16; small = a
-                    seconds-fast replica boot for fleet drills/bench)
+                    seconds-fast replica boot for fleet drills)
   --slots N         lm decode slots (default 8)
   --max-new N       lm default tokens per request (default 64)
   --s-max N         lm pool sequence capacity (default 256)

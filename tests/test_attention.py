@@ -84,14 +84,7 @@ def test_vit_ridge_synthetic_end_to_end():
     assert res["test_error"] < 0.4
 
 
-@pytest.mark.parametrize("use_flash", [False, True])
-@pytest.mark.parametrize("causal", [False, True])
-def test_ring_trainable_grads_match_dense(mesh8, rng, causal, use_flash):
-    """The custom-VJP ring backward (traveling dk/dv accumulators +
-    per-hop blockwise recompute) must produce dense-attention gradients —
-    for both the jnp and the flash-forward per-hop paths."""
-    q, k, v = _qkv(rng, s=128, d=16)
-
+def _assert_ring_grads_match_dense(mesh8, q, k, v, causal, use_flash):
     def loss_ring(q, k, v):
         out = ring_attention(
             q, k, v, mesh8, seq_axis="data", causal=causal,
@@ -103,13 +96,37 @@ def test_ring_trainable_grads_match_dense(mesh8, rng, causal, use_flash):
         out = dense_attention(q, k, v, causal=causal)
         return jnp.sum(jnp.sin(out) * out)
 
-    g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
     g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for gr, gd, name in zip(g_ring, g_dense, "qkv"):
         np.testing.assert_allclose(
             np.asarray(gr), np.asarray(gd), atol=2e-3,
             err_msg=f"d{name} (causal={causal}, flash={use_flash})",
         )
+
+
+@pytest.mark.parametrize("use_flash", [False, True])
+@pytest.mark.parametrize("causal", [False, True])
+def test_ring_trainable_grads_match_dense(mesh8, rng, causal, use_flash):
+    """The custom-VJP ring backward (traveling dk/dv accumulators +
+    per-hop blockwise recompute) must produce dense-attention gradients —
+    for both the jnp and the flash-forward per-hop paths."""
+    q, k, v = _qkv(rng, s=128, d=16)
+    _assert_ring_grads_match_dense(mesh8, q, k, v, causal, use_flash)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_ring_backward_sweeps_a_shard_in_many_blocks(
+    mesh8, rng, monkeypatch, causal
+):
+    """A shard longer than the ring backward's block: 40 positions a
+    device in blocks of 16, the third half padding. At the constant's
+    512 a CPU-sized shard is one block."""
+    import keystone_tpu.ops.attention as attention
+
+    monkeypatch.setattr(attention, "_RING_BWD_BLOCK", 16)
+    q, k, v = _qkv(rng, b=1, h=2, s=320, d=16)
+    _assert_ring_grads_match_dense(mesh8, q, k, v, causal, False)
 
 
 @pytest.mark.parametrize("use_flash", [False, True])

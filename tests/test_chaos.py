@@ -848,15 +848,3 @@ def test_chaos_run_cli_smoke_subprocess(tmp_path):
     assert r.returncode == 0, r.stdout + r.stderr
     assert "PASS" in r.stdout
     assert (tmp_path / "chaos_verdict.json").exists()
-
-
-def test_bench_chaos_drill_record():
-    sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
-    import bench
-
-    rec = bench.bench_chaos_drill()
-    assert rec["passed"] is True
-    assert rec["client_failures"] == 0
-    assert rec["client_ok"] == 24
-    assert rec["failover"] >= 1
-    assert rec["campaign_wall_s"] > 0

@@ -3,7 +3,7 @@
 normalize + whitener modes that make Convolver a non-plain convolution).
 
 The Pallas im2col kernel that used to live in ``ops/conv_kernel.py`` was
-retired in round 3 (0.28× the XLA im2col path on v5e — ROOFLINE.md §5);
+retired in round 3 (per-image im2col with C=3 fills 3 of 128 lanes);
 the conv-algebra impl these tests gate is the production path.
 """
 

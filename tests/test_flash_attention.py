@@ -161,21 +161,83 @@ def test_ring_flash_under_jit_long_sequence(mesh8, rng):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-def test_dense_bwd_env_knob_selects_path(rng, monkeypatch):
-    """KST_FLASH_DENSE_BWD_MAX=0 must force the kernel backward (the
-    lm_mfu_push A/B axis): the fwd saves (out, lse) residuals only on
-    the kernel's path, so their presence IS the path taken."""
+def _probe_forward(mesh8, q):
+    return lambda q: flash_attention(q, q, q, causal=True), q
+
+
+def _probe_backward(mesh8, q):
+    from keystone_tpu.ops.flash_attention import flash_attention_trainable
+
+    return jax.grad(lambda q: jnp.sum(flash_attention_trainable(q, q, q, True))), q
+
+
+def _probe_ring_backward(mesh8, q):
+    def loss(q):
+        out = ring_attention(
+            q, q, q, mesh8, seq_axis="data", causal=True, use_flash=False,
+            trainable=True,
+        )
+        return jnp.sum(out)
+
+    return jax.grad(loss), jnp.tile(q, (1, 1, 5, 1))  # 40 positions a device
+
+
+def _probe_local_lm(mesh8, q):
+    from keystone_tpu.models.lm.model import TransformerLM
+
+    model = TransformerLM.create(
+        jax.random.key(0), vocab=31, max_seq=16, dim=16, depth=1, num_heads=2
+    )
+    return model, jnp.arange(16, dtype=jnp.int32)[None] % 31
+
+
+# the names generation one's sweeps set, each with a value that moved
+# the blocks or the path while the program read it
+_RETIRED_NAMES = {
+    "KST_FLASH_BLOCK_Q": ("16", _probe_forward),
+    "KST_FLASH_BLOCK_K": ("16", _probe_forward),
+    "KST_FLASH_DENSE_BWD_MAX": ("0", _probe_backward),
+    "KST_FLASH_BWD_BLOCK": ("16", _probe_ring_backward),
+    "KST_LOCAL_ATTN": ("flash", _probe_local_lm),
+}
+
+
+@pytest.mark.parametrize("name", _RETIRED_NAMES)
+def test_no_environment_name_tunes_attention(mesh8, rng, monkeypatch, name):
+    """Blocks and paths follow the shapes and the platform alone: with
+    a retired name set, the traced program and its output are those of
+    the unset run."""
+    value, probe = _RETIRED_NAMES[name]
+    fn, x = probe(mesh8, _qkv(rng, b=1, h=2, s=64, d=16)[0])
+
+    def traced_and_run():
+        # a function of its own, so that no cache answers for the trace
+        def fresh(x):
+            return fn(x)
+
+        return str(jax.make_jaxpr(fresh)(x)), jax.jit(fresh)(x)
+
+    monkeypatch.delenv(name, raising=False)
+    want_program, want = traced_and_run()
+    monkeypatch.setenv(name, value)
+    program, got = traced_and_run()
+    assert program == want_program
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_dense_bwd_limit_selects_path(rng, monkeypatch):
+    """Transients over ``_DENSE_BWD_MAX_BYTES`` (read at call time) take
+    the kernel backward: the fwd saves (out, lse) residuals only on the
+    kernel's path, so their presence IS the path taken."""
     import keystone_tpu.ops.flash_attention as fa
 
     q = jnp.asarray(rng.normal(size=(1, 2, 128, 32)).astype(np.float32))
-    monkeypatch.delenv("KST_FLASH_DENSE_BWD_MAX", raising=False)
     _, res = fa._flash_trainable_fwd(q, q, q, False)
     assert res[3] is None, "small shape should default to the dense bwd"
-    monkeypatch.setenv("KST_FLASH_DENSE_BWD_MAX", "0")
+    monkeypatch.setattr(fa, "_DENSE_BWD_MAX_BYTES", fa._dense_bwd_bytes(q, q) - 1)
     _, res = fa._flash_trainable_fwd(q, q, q, False)
-    assert res[3] is not None, "env 0 must force the kernel bwd"
-    # malformed value falls back to the default, like the sibling knobs
-    monkeypatch.setenv("KST_FLASH_DENSE_BWD_MAX", "not-an-int")
+    assert res[3] is not None, "over the limit must take the kernel bwd"
+    monkeypatch.setattr(fa, "_DENSE_BWD_MAX_BYTES", fa._dense_bwd_bytes(q, q))
     _, res = fa._flash_trainable_fwd(q, q, q, False)
     assert res[3] is None
 
@@ -231,6 +293,17 @@ _BWD_CASES = {
     "causal_segments": (4, 2, 96, 8, True, 0, 2),
     "window_segments": (4, 2, 96, 8, True, 24, 2),
     "not_causal": (4, 2, 64, 8, False, 0, 4),
+    "blocks_16x32": (4, 2, 96, 8, True, 0, 3),
+    "blocks_16x32_window": (4, 2, 96, 8, True, 24, 3),
+    "blocks_32x16": (4, 2, 96, 8, True, 0, 6),
+    "blocks_32x16_window": (4, 2, 96, 8, True, 24, 6),
+}
+# queries by keys of a block where a case's are not 16 x 16
+_BWD_CASE_BLOCKS = {
+    "blocks_16x32": (16, 32),
+    "blocks_16x32_window": (16, 32),
+    "blocks_32x16": (32, 16),
+    "blocks_32x16_window": (32, 16),
 }
 # bfloat16 inputs against the float32 oracle on the same (rounded)
 # inputs: the kernel rounds p and ds to bfloat16 for the MXU and its
@@ -242,20 +315,26 @@ _BWD_LIMIT = {"float32": 1e-5, "bfloat16": 1e-2}
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("case", _BWD_CASES)
 def test_backward_kernel_matches_the_dense_vjp(rng, monkeypatch, case, dtype):
-    """The backward kernel (interpret mode) against ``jax.vjp`` of
-    ``dense_attention`` at full precision: live blocks only, grouped K/V
-    summed in the kernel, padding masked."""
+    """The forward at its block constants and the backward kernel
+    (interpret mode) against ``dense_attention`` and its ``jax.vjp`` at
+    full precision: live blocks only, grouped K/V summed in the kernel,
+    padding masked."""
     import keystone_tpu.ops.flash_attention as fa
 
     h, kvh, s, d, causal, window, seg_blocks = _BWD_CASES[case]
-    monkeypatch.setattr(fa, "_bwd_blocks", lambda *a: (16, 16, seg_blocks))
+    block_q, block_k = _BWD_CASE_BLOCKS.get(case, (16, 16))
+    monkeypatch.setattr(fa, "_BLOCK_Q", block_q)
+    monkeypatch.setattr(fa, "_BLOCK_K", block_k)
+    monkeypatch.setattr(
+        fa, "_bwd_blocks", lambda *a: (block_q, block_k, seg_blocks)
+    )
     q, k, v, ct = (
         jnp.asarray(rng.normal(size=(2, heads, s, d)), dtype)
         for heads in (h, kvh, kvh, h)
     )
     f32 = [x.astype(jnp.float32) for x in (q, k, v, ct)]
     with jax.default_matmul_precision("highest"):
-        _, vjp = jax.vjp(
+        want_out, vjp = jax.vjp(
             lambda q, k, v: dense_attention(
                 q, k, v, causal=causal, window=window
             ),
@@ -263,9 +342,13 @@ def test_backward_kernel_matches_the_dense_vjp(rng, monkeypatch, case, dtype):
         )
         want = vjp(f32[3])
     out, lse = flash_attention(
-        q, k, v, causal=causal, window=window, block_q=16, block_k=16,
-        return_lse=True,
+        q, k, v, causal=causal, window=window, return_lse=True
     )
+    err = float(
+        jnp.linalg.norm(out.astype(jnp.float32) - want_out)
+        / jnp.linalg.norm(want_out)
+    )
+    assert err <= _BWD_LIMIT[dtype], ("out", err)
     got = fa.flash_attention_bwd(
         q, k, v, ct, out, lse, causal=causal, window=window
     )

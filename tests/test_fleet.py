@@ -928,30 +928,3 @@ def test_mnist_fleet_rolling_restart_under_load(mnist_fleet):
     )
     assert all(r.state == "up" for r in fleet.replicas)
     assert _counter("fleet_rolling_restarts") >= 1
-
-
-# ---------------------------------------------------------------------------
-# bench record: fleet_latency (scaled down for tier-1)
-
-
-def test_bench_fleet_latency_record_cpu():
-    import importlib.util
-
-    path = pathlib.Path(__file__).parent.parent / "bench.py"
-    spec = importlib.util.spec_from_file_location("bench_under_fleet", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    rec = bench.bench_fleet_latency(
-        n_requests=10, replicas=2, fit_n=96, num_ffts=2,
-        compare_single=False,
-    )
-    for key in (
-        "replicas", "request_p50_ms", "request_p95_ms",
-        "requests_per_s", "kill_drill",
-    ):
-        assert key in rec, rec
-    assert rec["replicas"] == 2
-    drill = rec["kill_drill"]
-    assert drill["errors"] == 0
-    assert drill["failover"] >= 1
-    assert drill["request_p95_ms"] > 0

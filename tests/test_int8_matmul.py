@@ -1,7 +1,7 @@
 """Fused int8-dequant Pallas matmul vs the XLA mm() path.
 
-Runs in Pallas interpret mode on the CPU test mesh (the compiled path is
-exercised by the on-chip bench A/B — ROOFLINE.md §6 decode note)."""
+Runs in Pallas interpret mode on the CPU test mesh (``chip_smoke.py``
+compiles and runs the kernel on the chip)."""
 
 import jax.numpy as jnp
 import numpy as np

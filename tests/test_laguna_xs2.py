@@ -148,8 +148,8 @@ def test_window_kernel_matches_masked_dense_attention(
     positions masks nothing."""
     import keystone_tpu.ops.flash_attention as fa
 
-    monkeypatch.setenv("KST_FLASH_BLOCK_Q", "16")
-    monkeypatch.setenv("KST_FLASH_BLOCK_K", "16")
+    monkeypatch.setattr(fa, "_BLOCK_Q", 16)
+    monkeypatch.setattr(fa, "_BLOCK_K", 16)
     if kernel_backward:
         monkeypatch.setattr(fa, "_DENSE_BWD_MAX_BYTES", 0)
         monkeypatch.setattr(fa, "_bwd_blocks", lambda *a: (16, 16, 6))

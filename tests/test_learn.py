@@ -788,29 +788,6 @@ def test_top_and_report_render_model_swaps(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bench record
-
-
-def test_bench_refit_latency_record_cpu():
-    import importlib.util
-    import pathlib
-
-    path = pathlib.Path(__file__).parent.parent / "bench.py"
-    spec = importlib.util.spec_from_file_location("bench_under_learn", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    rec = bench.bench_refit_latency(n_base=4096, chunk_rows=512, d_feats=64)
-    for key in (
-        "fold_finalize_s", "full_retrain_s", "incremental_vs_full",
-        "swap_s", "e2e_refresh_s",
-    ):
-        assert key in rec, rec
-    # the economics the subsystem exists for: folding one chunk beats
-    # retraining from scratch even at a tiny 8:1 corpus:chunk ratio
-    assert rec["incremental_vs_full"] > 1.0, rec
-
-
-# ---------------------------------------------------------------------------
 # CLI smokes: refit --once over a real watch dir; HTTP /admin/reload
 
 

@@ -147,8 +147,7 @@ def test_generate_sampled_and_bounds():
 def test_remat_gradients_match():
     """jax.checkpoint per block changes memory, not math: grads with
     remat off / full remat / dots-saveable policy all agree (the dots
-    policy keeps matmul outputs so the MXU never re-runs — the bench's
-    memory-bound option)."""
+    policy keeps matmul outputs so the MXU never re-runs)."""
     base = _tiny()
     toks = jnp.asarray(np.random.default_rng(11).integers(0, 31, size=(4, 32)))
     for cdt in ("float32", "bfloat16"):
@@ -706,11 +705,9 @@ def test_gqa_composes_with_ring_sp_training(mesh8):
     assert losses[-1] < losses[0], losses
 
 
-def test_local_attn_env_knob_selects_path(monkeypatch):
-    """KST_LOCAL_ATTN must override the local-mode auto-select (the
-    stage-2 MFU push A/B axis, tools/lm_mfu_push2.py): 'flash' forces
-    the Pallas trainable wrapper even off-TPU, 'dense' forces the XLA
-    path, and an unknown value fails loudly like the sibling knobs."""
+def test_local_attention_path_follows_the_platform(monkeypatch):
+    """Local mode takes the Pallas trainable wrapper on a TPU and the
+    XLA path off it, and both compute the same attention."""
     import keystone_tpu.ops.flash_attention as fa
 
     model = _tiny()
@@ -726,26 +723,15 @@ def test_local_attn_env_knob_selects_path(monkeypatch):
 
     monkeypatch.setattr(fa, "flash_attention_trainable", spy)
 
-    monkeypatch.delenv("KST_LOCAL_ATTN", raising=False)
-    model(toks)
-    assert not calls, "auto off-TPU must take the dense path"
+    out_dense = model(toks)
+    assert not calls, "off the TPU local mode must take the dense path"
 
-    monkeypatch.setenv("KST_LOCAL_ATTN", "flash")
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)  # interpret mode here
     out_flash = model(toks)
     assert calls == ["flash"] * len(model.blocks)
-
-    calls.clear()
-    monkeypatch.setenv("KST_LOCAL_ATTN", "dense")
-    out_dense = model(toks)
-    assert not calls
-    # both paths compute the same attention
     np.testing.assert_allclose(
         np.asarray(out_flash), np.asarray(out_dense), atol=2e-4
     )
-
-    monkeypatch.setenv("KST_LOCAL_ATTN", "fused")
-    with pytest.raises(ValueError, match="KST_LOCAL_ATTN"):
-        model(toks)
 
 
 def test_local_flash_is_shard_mapped_under_a_mesh(monkeypatch, mesh4x2):
@@ -757,7 +743,9 @@ def test_local_flash_is_shard_mapped_under_a_mesh(monkeypatch, mesh4x2):
     divide still runs (whole on every device)."""
     from keystone_tpu.parallel.mesh import data_sharding
 
-    monkeypatch.setenv("KST_LOCAL_ATTN", "flash")  # interpret mode here
+    import keystone_tpu.ops.flash_attention as fa
+
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)  # interpret mode here
     kw = dict(vocab=31, max_seq=16, dim=16, depth=1, num_heads=2)
     plain = lm.TransformerLM.create(jax.random.key(0), **kw)
     meshed = lm.shard_params(

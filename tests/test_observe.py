@@ -298,15 +298,15 @@ def test_observe_cli_usage_and_missing_dir(tmp_path):
         cli_main(["observe", str(tmp_path / "nowhere")])
 
 
-def test_per_node_breakdown_compact_dict(tmp_path):
+def test_summarize_counts_each_node_of_a_memory_only_log():
     pipe = three_node_pipe()
-    from keystone_tpu.observe.report import per_node_breakdown
+    from keystone_tpu.observe.report import summarize
 
     with events.run() as log:  # memory-only: no dir
         instrument(pipe, sync=True)(jnp.ones((16, 4)))
-        breakdown = per_node_breakdown(log)
-    assert set(breakdown) == {"00:add1", "01:mul2", "02:sub"}
-    assert all(v["calls"] == 1 and v["wall_s"] >= 0 for v in breakdown.values())
+        nodes = summarize(log.records)["nodes"]
+    assert set(nodes) == {"00:add1", "01:mul2", "02:sub"}
+    assert all(v["calls"] == 1 and v["total_s"] >= 0 for v in nodes.values())
 
 
 # ------------------------------------------- logging/profiling satellites

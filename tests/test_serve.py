@@ -122,6 +122,8 @@ def test_batcher_bucket_padding_trimmed_from_responses():
     mb = MicroBatcher(
         disp, buckets=(2, 8), deadline_ms=5.0, clock=clock, start=False
     )
+    names = ("serve_batches", "serve_rows", "serve_pad_rows")
+    before = {name: _counter(name) for name in names}
     f1 = mb.submit(_rows(3, fill=1.0))
     f2 = mb.submit(_rows(2, fill=5.0))
     clock.t = 0.005
@@ -131,7 +133,9 @@ def test_batcher_bucket_padding_trimmed_from_responses():
     assert disp.shapes == [(8, 3)]
     np.testing.assert_array_equal(f1.result(0), _rows(3, fill=1.0) * 2)
     np.testing.assert_array_equal(f2.result(0), _rows(2, fill=5.0) * 2)
-    assert _counter("serve_pad_rows") >= 3
+    # the counters a batch fill is read from: one batch, 5 rows of 8
+    batches, rows, pad = (_counter(name) - before[name] for name in names)
+    assert (batches, rows, pad) == (1, 5, 3)
 
 
 def test_batcher_burst_coalesces_to_ceil_n_over_bucket():
@@ -620,34 +624,6 @@ def test_report_renders_serving_sections(tmp_path):
     # wall must never inflate the per-dispatch percentiles
     assert "dispatch wall p50 10.0 ms  p95 10.0 ms" in text
     assert "generation wall p50 100.0 ms" in text
-
-
-# ---------------------------------------------------------------------------
-# bench record: aggregate decode ≥ 1.5x single-stream on CPU
-
-
-def test_bench_serve_latency_record_cpu():
-    import importlib.util
-    import pathlib
-
-    path = pathlib.Path(__file__).parent.parent / "bench.py"
-    spec = importlib.util.spec_from_file_location("bench_under_serve", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    rec = bench.bench_serve_latency(
-        n_requests=12, fit_n=96, max_new=16, streams=8
-    )
-    for key in (
-        "cold_start_s", "request_p50_ms", "request_p95_ms", "batches",
-        "batch_fill", "decode_single_stream_tokens_per_s",
-        "decode_concurrent_tokens_per_s", "aggregate_vs_single",
-    ):
-        assert key in rec, rec
-    assert rec["batches"] >= 1
-    assert 0.0 < rec["batch_fill"] <= 1.0
-    # the acceptance floor: continuous batching multiplies aggregate
-    # tokens/s ≥ 1.5x on the CPU fallback (≥ 3x expected on a TPU)
-    assert rec["aggregate_vs_single"] >= 1.5, rec
 
 
 # ---------------------------------------------------------------------------

@@ -339,7 +339,7 @@ def test_fused_fit_matches_naive_mnist(rng):
     after = _counters("plan_fused_fits", "plan_fit_materialized")
     assert after["plan_fused_fits"] - before["plan_fused_fits"] == 1
     assert after["plan_fit_materialized"] == before["plan_fit_materialized"]
-    assert plan.fit.fused
+    assert plan.fit.fused and plan.fit.gram in ("fp32", "int8")
     fuse = [d for d in plan.decisions if d["action"] == "fuse_fit"]
     assert fuse and fuse[0]["materialize_features"] is False
     x1 = np.concatenate([np.asarray(a) for a in naive[-1].xs])
@@ -665,7 +665,7 @@ def test_cifar_model_planned_fit_matches(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# CLI + bench record
+# CLI
 
 
 def test_plan_cli_fit_smoke():
@@ -681,16 +681,3 @@ def test_plan_cli_fit_smoke():
     assert "fit: fused streaming" in out.stdout
     assert "fuse_streaming_fit" in out.stdout
     assert "fit_operator" in out.stdout
-
-
-def test_bench_solver_mfu_record():
-    sys.path.insert(0, "/root/repo")
-    import bench
-
-    rec = bench.bench_solver_mfu(n=4096, d_feats=128)
-    assert rec["chosen_operator"] in ("fp32", "int8")
-    assert rec["streamed_fit_s"] > 0 and rec["materialized_fit_s"] > 0
-    assert rec["rows_per_s"] > 0
-    assert any(
-        d_["action"] == "fuse_fit" for d_ in rec["decisions"]
-    )

@@ -1,7 +1,6 @@
 """Self-tuning runtime tests: the autotuner controller (zero-sleep,
 injected clock), the persisted plan store, the async ingest frontier,
-the end-to-end host-bound pin, the observe diff / --learned CLIs, and
-the bench perf-regression gate."""
+the end-to-end host-bound pin, and the observe diff / --learned CLIs."""
 
 import json
 import os
@@ -669,79 +668,3 @@ def test_plan_cli_learned_requires_store(monkeypatch):
     monkeypatch.delenv(plan_store.ENV_STORE, raising=False)
     with pytest.raises(SystemExit, match="KEYSTONE_PLAN_STORE"):
         plan_cli.main(["cifar-random-patch", "--learned"])
-
-
-# ---------------------------------------------------------------------------
-# bench: the perf-regression gate + the autotune record
-
-
-def _load_bench():
-    import importlib.util
-    import pathlib
-
-    path = pathlib.Path(__file__).parent.parent / "bench.py"
-    spec = importlib.util.spec_from_file_location("bench_under_tune_test", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_check_passes_and_fails(tmp_path):
-    bench = _load_bench()
-
-    baseline = {
-        "value": 100.0,
-        "lm_train_tokens_per_s": 1000.0,
-        "serve_latency": {"request_p95_ms": 20.0},
-        "notes": "ignored",
-    }
-    ok = {
-        "value": 99.0,  # -1% within 5%
-        "lm_train_tokens_per_s": 1100.0,
-        "serve_latency": {"request_p95_ms": 20.5},
-    }
-    bad = {
-        "value": 80.0,  # -20% regression
-        "lm_train_tokens_per_s": 1000.0,
-        "serve_latency": {"request_p95_ms": 30.0},  # +50% latency
-    }
-    bpath = tmp_path / "base.json"
-    bpath.write_text(json.dumps(baseline))
-    okpath = tmp_path / "ok.json"
-    okpath.write_text(json.dumps({"result": ok}))  # wrapper accepted
-    badpath = tmp_path / "bad.json"
-    badpath.write_text(json.dumps(bad))
-    assert (
-        bench.main(
-            ["--check", str(bpath), "--against", str(okpath), "--tolerance", "5"]
-        )
-        == 0
-    )
-    assert (
-        bench.main(
-            ["--check", str(bpath), "--against", str(badpath), "--tolerance", "5"]
-        )
-        == 1
-    )
-    regs, checked = bench.compare_records(baseline, bad, 5.0)
-    assert checked == 3
-    assert any("value" in r for r in regs)
-    assert any("request_p95_ms" in r for r in regs)
-    assert len(regs) == 2  # tokens/s held steady
-
-
-def test_bench_check_missing_file_exits_2(tmp_path):
-    bench = _load_bench()
-
-    nope = str(tmp_path / "nope.json")
-    assert bench.main(["--check", nope, "--against", nope]) == 2
-
-
-@pytest.mark.slow
-def test_bench_autotune_record():
-    bench = _load_bench()
-
-    rec = bench.bench_autotune(n_items=32, decode_s=0.003, compute_s=0.0005)
-    assert rec["tuned_items_per_s"] >= rec["static_items_per_s"]
-    assert rec["final_ingest_workers"] > 1
-    assert rec["wait_host_share_last"] < rec["wait_host_share_first"]
