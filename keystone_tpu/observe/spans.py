@@ -381,7 +381,9 @@ def span(
     """Bracket a block as one span: measures wall, parents on the
     ambient context (or an explicit ``parent``), installs itself as the
     ambient context for the duration, and emits on exit (``status:
-    failed`` rides a raised exception out).
+    failed`` rides a raised exception out). An attribute given as a
+    callable is called on exit: the way to say what only the block finds
+    out (a cache's hits).
 
     While a profiler session is on the block is also bracketed by a
     ``TraceAnnotation`` of the same name (module docstring). With no
@@ -424,7 +426,7 @@ def span(
             parent=pctx,
             ctx=ctx,
             status=status,
-            **attrs,
+            **{k: v() if callable(v) else v for k, v in attrs.items()},
         )
 
 
@@ -547,7 +549,9 @@ def _render_node(node: dict, depth: int, lines: list[str]) -> None:
         extras.append(rec["bucket"])
     if rec.get("status") == "failed":
         extras.append("FAILED")
-    for key in ("rid", "step", "rows", "requests", "bucket_size", "tokens"):
+    for key in (
+        "rid", "step", "rows", "requests", "bucket_size", "tokens", "cached"
+    ):
         if key in rec:
             extras.append(f"{key}={rec[key]}")
     tag = f"  [{', '.join(extras)}]" if extras else ""

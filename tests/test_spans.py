@@ -1005,6 +1005,31 @@ def test_a_new_profiler_session_starts_a_new_list(tmp_path, profiled_fit):
     assert spans_mod._session_log.records.maxlen == spans_mod._MAX_MEMORY_SPANS == 8192
 
 
+def test_fit_load_says_how_many_corpora_the_memo_answered(tmp_path, profiled_fit):
+    """``cached`` on ``fit.load``: 0 in a process's first fit at a size,
+    2 in the next; the copy to the device stays in every fit."""
+    import jax
+
+    from keystone_tpu.models.timit_pipeline import TimitConfig, run
+
+    _out, recs, _dir = profiled_fit
+    (load,) = [r for r in recs if r["name"] == "fit.load"]
+    assert load["cached"] == 2  # the fixture's fit came second
+    conf = TimitConfig(synthetic=260, num_cosines=2, cosine_features=32, num_epochs=2)
+    _start_profile(tmp_path)
+    try:
+        run(conf)
+        run(conf)
+    finally:
+        jax.profiler.stop_trace()
+    mine = spans_mod.profiled_spans()
+    assert [r["cached"] for r in mine if r["name"] == "fit.load"] == [0, 2]
+    h2d = [r for r in mine if r["name"] == "fit.h2d"]
+    assert [r["bytes"] for r in h2d] == [(260 + 52) * 440 * 4] * 2
+    assert all(r["t1_ns"] > r["t0_ns"] for r in h2d)
+    assert "cached=2" in spans_mod.render_traces(mine)
+
+
 def _xplane(name, lines):
     return NS(name=name, lines=[
         NS(name=ln, events=[
