@@ -39,6 +39,7 @@ from keystone_tpu.ops.attention import (
 )
 from keystone_tpu.ops.moe import COUNTERS, MoELayer, ffn
 from keystone_tpu.ops.quantization import QTensor, mm
+from keystone_tpu.ops.ssm import COUNTERS as SSM_COUNTERS, Mamba2Mixer
 from keystone_tpu.ops.vit import _layer_norm
 
 
@@ -84,23 +85,25 @@ class RopeSpec:
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """What one layer's attention is, static: its head counts, its
-    causal window (0 = every earlier key) and its rotary scheme (None =
-    no rotation: learned positions). Layers of one model may differ."""
+    causal window (0 = every earlier key), its rotary scheme (None = no
+    rotation: learned positions, or none at all) and its softmax scale
+    (None = 1/sqrt(head_dim)). Layers of one model may differ."""
 
     num_heads: int
     num_kv_heads: int
     window: int = 0
     rope: RopeSpec | None = None
+    scale: float | None = None
 
 
 @treenode
 class LMBlock:
-    """One decoder block: attention, then a dense FFN or routed experts.
-    The one block definition: the toy presets of :meth:`TransformerLM.
-    create` and a public ``config.json`` (:meth:`TransformerLM.
-    from_config`) fill the same fields."""
+    """One decoder block: attention or a state-space mixer, then a dense
+    FFN or routed experts. The one block definition: the toy presets of
+    :meth:`TransformerLM.create` and a public ``config.json``
+    (:meth:`TransformerLM.from_config`) fill the same fields."""
 
-    wq: jnp.ndarray  # (d, H·hd)
+    wq: jnp.ndarray  # (d, H·hd); zero-width under a state-space mixer
     wk: jnp.ndarray  # (d, KV·hd)
     wv: jnp.ndarray
     wo: jnp.ndarray  # (H·hd, d)
@@ -111,6 +114,7 @@ class LMBlock:
     norm1: jnp.ndarray | None = None  # learned RMSNorm scales, or None
     norm2: jnp.ndarray | None = None  # for the parameter-free LayerNorm
     moe: object | None = None  # ops.moe.MoELayer in place of the FFN
+    ssm: object | None = None  # ops.ssm.Mamba2Mixer in place of attention
     spec: LayerSpec | None = static_field(default=None)
 
 
@@ -191,20 +195,25 @@ def _rope(x, positions, rope: RopeSpec = RopeSpec()):
 
 
 def _block_apply(x, blk: LMBlock, cdt, attn, mm_fn=mm, eps: float = 1e-6,
-                 mesh=None):
+                 mesh=None, residual: float = 1.0):
     """Pre-norm residual block shared by training forward, prefill, and
-    decode: ``attn(y, blk) -> (attention output (N,S,d), aux)``. Routed
+    decode: ``attn(y, blk) -> (mixer output (N,S,d), aux)``. Routed
     experts (``blk.moe``) take the dense FFN's place, told the ``mesh``
-    the activations are split over; returns (x, attn_aux, the expert
-    layer's counters or None)."""
+    the activations are split over; each branch joins the stream times
+    ``residual``; returns (x, attn_aux, the expert layer's counters or
+    None)."""
+
+    def join(x, branch):
+        return x + (branch if residual == 1.0 else branch * residual)
+
     a, aux = attn(_norm(x, blk.norm1, eps, cdt), blk)
-    x = x + a
+    x = join(x, a)
     y = _norm(x, blk.norm2, eps, cdt)
     if blk.moe is not None:
         f, counters = blk.moe(y, mesh)
-        return x + f, aux, counters
+        return join(x, f), aux, counters
     with jax.named_scope("dense_ffn"):
-        return x + ffn(y, blk.w1, blk.w2, blk.w3, cdt, mm_fn), aux, None
+        return join(x, ffn(y, blk.w1, blk.w2, blk.w3, cdt, mm_fn)), aux, None
 
 
 def _gather_embed(embed, tokens):
@@ -222,6 +231,8 @@ def _embed(model, tokens, cdt):
     x = _gather_embed(model.embed, tokens)
     if model.embed_scale:
         x = x * math.sqrt(model.embed.shape[-1])
+    if model.embed_multiplier != 1.0:
+        x = x * model.embed_multiplier
     if model.pos_encoding == "learned":
         x = x + model.pos_embed[: tokens.shape[1]]
     return x.astype(cdt)
@@ -243,13 +254,15 @@ def _tied_logits(x, embed, cdt):
 
 def output_logits(model, x, cdt):
     """Final norm, then the output head: the model's own ``head`` (d, V)
-    when it has one, else the embedding transposed (tied). f32 out."""
-    if model.head is None:
+    when it has one, else the embedding transposed (tied: behind the
+    learned final norm where the model has one, else behind the
+    parameter-free LayerNorm), times ``logits_scale``. f32 out."""
+    if model.head is None and model.final_norm is None:
         return _tied_logits(x, model.embed, cdt)
     xn = _norm(x, model.final_norm, model.norm_eps, cdt)
-    return jnp.matmul(
-        xn, model.head.astype(cdt), preferred_element_type=jnp.float32
-    )
+    head = model.embed.T if model.head is None else model.head
+    logits = jnp.matmul(xn, head.astype(cdt), preferred_element_type=jnp.float32)
+    return logits if model.logits_scale == 1.0 else logits * model.logits_scale
 
 
 @treenode
@@ -263,8 +276,8 @@ class TransformerLM:
     embed: jnp.ndarray  # (V, d)
     pos_embed: jnp.ndarray  # (S_max, d)
     blocks: tuple  # of LMBlock
-    # the output head (d, V) and the final RMSNorm's scale; None = logits
-    # tied to the embedding behind the parameter-free LayerNorm
+    # the output head (d, V); None = logits tied to the embedding. The
+    # final RMSNorm's scale; None = the parameter-free LayerNorm
     head: jnp.ndarray | None = None
     final_norm: jnp.ndarray | None = None
     num_heads: int = static_field(default=8)
@@ -294,7 +307,8 @@ class TransformerLM:
     compute_dtype: str = static_field(default="float32")
     # "learned" = trained absolute table (pos_embed, capped at max_seq);
     # "rope" = rotary q/k phases — no table, no length cap beyond memory,
-    # the right pairing for the long-context kernel backward
+    # the right pairing for the long-context kernel backward; "nope" =
+    # no positional encoding at all (each layer's spec says: rope=None)
     pos_encoding: str = static_field(default="learned")
     # grouped-query attention: K/V carry this many heads (0 = num_heads,
     # plain MHA; 1 = MQA). The decode cache shrinks by num_heads/kv_heads
@@ -308,6 +322,11 @@ class TransformerLM:
     # the toy presets scale embeddings by sqrt(d); public configs do not
     embed_scale: bool = static_field(default=True)
     norm_eps: float = static_field(default=1e-6)
+    # a public config's own multipliers: on the embedding, on each
+    # branch as it joins the residual stream, on the logits
+    embed_multiplier: float = static_field(default=1.0)
+    residual_multiplier: float = static_field(default=1.0)
+    logits_scale: float = static_field(default=1.0)
 
     @property
     def kv_heads(self) -> int:
@@ -326,12 +345,23 @@ class TransformerLM:
 
     def uniform_decode_reason(self) -> str | None:
         """None when every layer is the kind the KV-cache path computes
-        (one head count, no window, no head gate, plain rotary or learned
-        positions, tied logits); else what it cannot serve, by name: the
-        first layer of each kind it has no cache or step for."""
+        (attention with one head count, no window, no head gate, plain
+        rotary or learned positions, the default softmax scale; tied
+        logits behind the parameter-free norm, no multipliers); else what
+        it cannot serve, by name: the first layer of each kind it has no
+        cache or step for."""
         why: dict[str, str] = {}
         for i, blk in enumerate(self.blocks):
+            if blk.ssm is not None:
+                why.setdefault(
+                    "ssm",
+                    f"layer {i} is a state-space layer: a slot holds no "
+                    "recurrent state",
+                )
+                continue
             spec = self.layer_spec(blk)
+            if spec.scale is not None:
+                why.setdefault("scale", f"layer {i} scales its scores by {spec.scale}")
             if spec.window:
                 why.setdefault(
                     "window", f"layer {i} attends through a window of {spec.window}"
@@ -350,6 +380,12 @@ class TransformerLM:
                 why.setdefault("rope", f"layer {i} rotates by {spec.rope}")
         if self.head is not None:
             why["head"] = "the output head is untied"
+        elif self.final_norm is not None:
+            why["head"] = "the tied head lies behind a learned final norm"
+        if (self.embed_multiplier, self.residual_multiplier, self.logits_scale) != (
+            1.0, 1.0, 1.0
+        ):
+            why["multipliers"] = "the embedding, residual or logits are scaled"
         return "; ".join(why.values()) or None
 
     def _qkv_heads(self, x, blk: LMBlock, positions=None):
@@ -359,6 +395,10 @@ class TransformerLM:
         mm_fn = model_mm(self)
         spec = self.layer_spec(blk)
         q = _split_heads(x, blk.wq, spec.num_heads, mm_fn)
+        if spec.scale is not None:
+            # every attention path scales by 1/sqrt(head_dim): the rest
+            # of the layer's own scale goes into q
+            q = q * jnp.asarray(spec.scale * math.sqrt(q.shape[-1]), q.dtype)
         k = _split_heads(x, blk.wk, spec.num_kv_heads, mm_fn)
         v = _split_heads(x, blk.wv, spec.num_kv_heads, mm_fn)
         if spec.rope is not None:
@@ -472,37 +512,49 @@ class TransformerLM:
         """(B, S) int tokens → (B, S, V) float32 logits."""
         return self.forward_with_aux(tokens)[0]
 
+    def _mixer(self, y, blk: LMBlock):
+        """(the block's mixer on ``y``, its counters or None): attention,
+        or the state-space mixer that stands in for it."""
+        if blk.ssm is None:
+            return self._attention(y, blk), None
+        return blk.ssm(y, self.mesh, model_mm(self))
+
     def backbone(self, tokens):
         """(final hidden states (B, S, d) before the final norm and the
-        head, the expert layers' counters summed over layers) — the
-        forward minus the logits projection, so losses can choose how
-        (or whether) to materialize logits."""
+        head, the expert layers' counters summed over layers and, where
+        the model has state-space layers, theirs) — the forward minus
+        the logits projection, so losses can choose how (or whether) to
+        materialize logits."""
         cdt = jnp.dtype(self.compute_dtype)
         x = _embed(self, tokens, cdt)
 
         def block_fn(x, blk):
-            out, _, counters = _block_apply(
-                x, blk, cdt,
-                lambda y, b: (self._attention(y, b), None),
+            out, scanned, counters = _block_apply(
+                x, blk, cdt, self._mixer,
                 mm_fn=model_mm(self),
                 eps=self.norm_eps,
                 mesh=self.mesh,
+                residual=self.residual_multiplier,
             )
-            return out, counters
+            return out, counters, scanned
 
         if self.remat:
             block_fn = remat_wrap(block_fn, self.remat_policy)
         total = {c: jnp.int32(0) for c in COUNTERS}
+        if any(blk.ssm is not None for blk in self.blocks):
+            total.update({c: jnp.int32(0) for c in SSM_COUNTERS})
         for blk in self.blocks:
-            x, counters = block_fn(x, blk)
+            x, counters, scanned = block_fn(x, blk)
             if counters is not None:
-                total = {
-                    "routed_rows": total["routed_rows"] + counters["routed_rows"],
-                    "max_expert_rows": jnp.maximum(
+                total.update(
+                    routed_rows=total["routed_rows"] + counters["routed_rows"],
+                    max_expert_rows=jnp.maximum(
                         total["max_expert_rows"], counters["max_expert_rows"]
                     ),
-                    "mm_rows": total["mm_rows"] + counters["mm_rows"],
-                }
+                    mm_rows=total["mm_rows"] + counters["mm_rows"],
+                )
+            if scanned is not None:
+                total.update({c: total[c] + scanned[c] for c in SSM_COUNTERS})
         return x, total
 
     def forward_with_aux(self, tokens):
@@ -619,11 +671,13 @@ class TransformerLM:
         remat: bool = False,
     ) -> "TransformerLM":
         """A model from a ``config.json``-shaped description (the keys of
-        a public sparse decoder: ``hidden_size``, ``head_dim``,
-        ``layer_types``, ``num_attention_heads_per_layer``,
-        ``mlp_layer_types``, ``rope_parameters`` by layer type,
-        ``num_experts`` ...), at the sizes the description gives **as
-        held here**: ``num_hidden_layers`` layers from the front of the
+        a public decoder: ``hidden_size``, ``head_dim``, ``layer_types``,
+        ``num_attention_heads_per_layer``, ``mlp_layer_types``,
+        ``rope_parameters`` by layer type, ``num_experts``, or a hybrid's
+        ``layer_types`` of "mamba" / "attention" with its ``mamba_*``
+        keys, ``position_embedding_type`` "nope" and its four
+        multipliers ...), at the sizes the description gives **as held
+        here**: ``num_hidden_layers`` layers from the front of the
         per-layer lists, ``num_experts`` routed experts a layer,
         ``vocab_size`` ids. Where that is one chip's share of a
         deployment, ``published`` gives the model's own counts (the
@@ -631,9 +685,12 @@ class TransformerLM:
         ``deployment.expert_shard`` says which share of the experts this
         is. Seeded random weights: no checkpoint is read."""
         c = config
-        d, hd = c["hidden_size"], c["head_dim"]
+        d = c["hidden_size"]
+        hd = c.get("head_dim") or d // c["num_attention_heads"]
         depth, vocab = c["num_hidden_layers"], c["vocab_size"]
         kvh = c["num_key_value_heads"]
+        nope = c.get("position_embedding_type") == "nope"
+        scale = c.get("attention_multiplier")
         heads = c.get("num_attention_heads_per_layer") or (
             [c["num_attention_heads"]] * depth
         )
@@ -670,39 +727,66 @@ class TransformerLM:
             sliding = kinds[i] == "sliding_attention"
             ks = jax.random.split(k_layers[i], 9)
             sparse = mlps[i] == "sparse"
-            ff = c["intermediate_size"]
+            ff = c.get("shared_intermediate_size", c["intermediate_size"])
+            after_mixer = dict(
+                w1=jnp.zeros((d, 0), jnp.float32)
+                if sparse
+                else init(ks[4], (d, ff), d),
+                w2=jnp.zeros((0, d), jnp.float32)
+                if sparse
+                else init(ks[5], (ff, d), ff),
+                w3=None if sparse else init(ks[6], (d, ff), d),
+                norm1=jnp.ones((d,), jnp.float32),
+                norm2=jnp.ones((d,), jnp.float32),
+                moe=MoELayer.create(
+                    ks[8], d, c["moe_intermediate_size"], routed,
+                    held=held, first_expert=shard * held,
+                    top_k=c["num_experts_per_tok"], swiglu=True,
+                    shared_ff=c.get("shared_expert_intermediate_size", 0),
+                    scoring="sigmoid",
+                    routed_scale=c.get("moe_routed_scaling_factor", 1.0),
+                    router_std=1.0 / math.sqrt(d),
+                )
+                if sparse
+                else None,
+            )
+            if kinds[i] == "mamba":
+                # the mixer stands in for the attention weights, which
+                # stay as zero-width placeholders (as w1 / w2 do under
+                # routed experts)
+                blocks.append(
+                    LMBlock(
+                        wq=jnp.zeros((d, 0), jnp.float32),
+                        wk=jnp.zeros((d, 0), jnp.float32),
+                        wv=jnp.zeros((d, 0), jnp.float32),
+                        wo=jnp.zeros((0, d), jnp.float32),
+                        ssm=Mamba2Mixer.create(
+                            ks[0], d,
+                            heads=c["mamba_n_heads"], head_dim=c["mamba_d_head"],
+                            state=c["mamba_d_state"], groups=c["mamba_n_groups"],
+                            conv=c["mamba_d_conv"], conv_bias=c["mamba_conv_bias"],
+                            chunk=c["mamba_chunk_size"],
+                            eps=c.get("rms_norm_eps", 1e-6),
+                        ),
+                        spec=LayerSpec(0, 0),
+                        **after_mixer,
+                    )
+                )
+                continue
             blocks.append(
                 LMBlock(
                     wq=init(ks[0], (d, h * hd), d),
                     wk=init(ks[1], (d, kvh * hd), d),
                     wv=init(ks[2], (d, kvh * hd), d),
                     wo=init(ks[3], (h * hd, d), h * hd),
-                    w1=jnp.zeros((d, 0), jnp.float32)
-                    if sparse
-                    else init(ks[4], (d, ff), d),
-                    w2=jnp.zeros((0, d), jnp.float32)
-                    if sparse
-                    else init(ks[5], (ff, d), ff),
-                    w3=None if sparse else init(ks[6], (d, ff), d),
                     wg=init(ks[7], (d, h), d) if c.get("gating") else None,
-                    norm1=jnp.ones((d,), jnp.float32),
-                    norm2=jnp.ones((d,), jnp.float32),
-                    moe=MoELayer.create(
-                        ks[8], d, c["moe_intermediate_size"], routed,
-                        held=held, first_expert=shard * held,
-                        top_k=c["num_experts_per_tok"], swiglu=True,
-                        shared_ff=c.get("shared_expert_intermediate_size", 0),
-                        scoring="sigmoid",
-                        routed_scale=c.get("moe_routed_scaling_factor", 1.0),
-                        router_std=1.0 / math.sqrt(d),
-                    )
-                    if sparse
-                    else None,
                     spec=LayerSpec(
                         h, kvh,
                         window=c["sliding_window"] if sliding else 0,
-                        rope=rope_of(kinds[i]),
+                        rope=None if nope else rope_of(kinds[i]),
+                        scale=scale,
                     ),
+                    **after_mixer,
                 )
             )
         tied = c.get("tie_word_embeddings", False)
@@ -711,15 +795,18 @@ class TransformerLM:
             pos_embed=jnp.zeros((0, d), jnp.float32),
             blocks=tuple(blocks),
             head=None if tied else init(k_head, (d, vocab), d),
-            final_norm=None if tied else jnp.ones((d,), jnp.float32),
+            final_norm=jnp.ones((d,), jnp.float32),
             num_heads=heads[0],
             mesh=mesh,
             remat=remat,
             compute_dtype=compute_dtype,
-            pos_encoding="rope",
+            pos_encoding="nope" if nope else "rope",
             num_kv_heads=0 if kvh == heads[0] else kvh,
             embed_scale=False,
             norm_eps=c.get("rms_norm_eps", 1e-6),
+            embed_multiplier=float(c.get("embedding_multiplier", 1.0)),
+            residual_multiplier=float(c.get("residual_multiplier", 1.0)),
+            logits_scale=1.0 / float(c.get("logits_scaling", 1.0)),
         )
 
     def num_params(self) -> int:
@@ -757,7 +844,9 @@ def train_step_flops(model: TransformerLM, batch: int, seq: int) -> float:
     touches (a routed layer's experts at ``top_k`` times the share held
     here, under even routing; the embedding table is a gather unless the
     logits are tied to it), plus the causal score and value products
-    (a window layer reckoned at its window). Recomputation not counted."""
+    (a window layer reckoned at its window; a state-space block, whose
+    ``wq`` is zero-width, has none, and its scan's own FLOPs, under 2 % of
+    such a step, are left out). Recomputation not counted."""
     tokens = batch * seq
     touched = 0.0
     attn = 0.0

@@ -23,7 +23,10 @@ def shard_params(model: TransformerLM, mesh) -> TransformerLM:
     row-sharded, MLP column- then row-sharded, embedding vocab-sharded.
     XLA then inserts exactly the two psums per block that hand-written
     Megatron-style TP would — the layout IS the parallelism. A block's
-    routed experts are left as they are.
+    routed experts are left as they are, and so is a state-space mixer:
+    its leaves stay whole on every device under ``model`` (its scan is
+    split over ``data`` alone; no head-parallel layout of the mixer is
+    written).
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -59,7 +62,9 @@ def shard_params(model: TransformerLM, mesh) -> TransformerLM:
             w3=opt(b.w3, P(None, "model")),
             # routed experts stay whole on every device (their layer
             # shard_maps its tokens over `data`): the exchange that
-            # expert parallelism needs is not written yet (ROADMAP C8)
+            # expert parallelism needs is not written yet (ROADMAP C8);
+            # b.ssm stays whole too: the zero-width wq..wo above are its
+            # placeholders and nothing of the mixer is split over `model`
         )
         for b in model.blocks
     )

@@ -641,6 +641,7 @@ def train(
             steps=steps,
             tokens_per_step=batch * seq,
             chips=mesh.size if mesh is not None else 1,
+            ssm_layers=ssm_layers(model),
         )
         if _spans.current() is None
         else _contextlib.nullcontext()
@@ -911,6 +912,9 @@ def _layer_identity(model: TransformerLM, blk) -> list:
     """What a checkpoint must agree on about one layer, as JSON."""
     spec = model.layer_spec(blk)
     m = blk.moe
+    if blk.ssm is not None:
+        x = blk.ssm
+        return ["ssm", x.heads, x.head_dim, x.state, x.groups, x.chunk]
     return [
         spec.num_heads,
         spec.num_kv_heads,
@@ -920,18 +924,26 @@ def _layer_identity(model: TransformerLM, blk) -> list:
         if m is None
         else [m.num_experts, m.held, m.first_expert, m.top_k, m.scoring,
               m.routed_scale],
+        *([] if spec.scale is None else [spec.scale]),
     ]
 
 
+def ssm_layers(model: TransformerLM) -> int:
+    """How many of the model's layers are state-space layers."""
+    return sum(b.ssm is not None for b in model.blocks)
+
+
 def _record_counters(model: TransformerLM, stats: list) -> None:
-    """The expert layers' counters of this fit as one zero-length
-    ``fit.counters`` span under the open one, read from the device once,
-    and only while spans are recorded and the model routes."""
+    """The expert and state-space layers' counters of this fit as one
+    zero-length ``fit.counters`` span under the open one, read from the
+    device once, and only while spans are recorded and the model routes
+    or scans. A model without experts leaves their counters at 0, and
+    one without state-space layers theirs."""
     from keystone_tpu.observe import spans as _spans
 
     slots = sum(b.moe.held for b in model.blocks if b.moe is not None)
     sl = _spans.active_span_log()
-    if sl is None or not slots or not stats:
+    if sl is None or not (slots or ssm_layers(model)) or not stats:
         return
     got = jax.device_get([s.counters for s in stats])
     routed = [int(c["routed_rows"]) for c in got]
@@ -942,6 +954,9 @@ def _record_counters(model: TransformerLM, stats: list) -> None:
         steps=len(got),
         routed_rows=sum(routed),
         mm_rows=sum(int(c["mm_rows"]) for c in got),
+        # positions scanned and chunks run, summed over state-space layers
+        ssm_rows=sum(int(c.get("ssm_rows", 0)) for c in got),
+        ssm_chunks=sum(int(c.get("ssm_chunks", 0)) for c in got),
         # largest load of a held expert over the mean load, a step
         load_max_over_mean=float(
             np.mean(

@@ -47,7 +47,7 @@ from keystone_tpu.models.lm.decode import _filter_logits  # noqa: F401
 from keystone_tpu.models.lm.model import (  # noqa: F401
     has_quantized_leaves as _has_quantized_leaves,
 )
-from keystone_tpu.models.lm.train import _step_batch  # noqa: F401
+from keystone_tpu.models.lm.train import _step_batch, ssm_layers  # noqa: F401
 
 logger = get_logger("keystone_tpu.models.lm_transformer")
 
@@ -189,12 +189,15 @@ def fit(conf: LMConfig, mesh=None, history: dict | None = None):
         )
     if mesh is None and len(jax.devices()) > 1:
         mesh = create_mesh()
+    found: dict = {}  # what fit.init learns of the model it makes
     with span(
         "fit",
         parent=None,
         steps=conf.steps,
         tokens_per_step=conf.batch * conf.seq,
         chips=mesh.size if mesh is not None else 1,
+        # known when the span closes: the model is made inside it
+        ssm_layers=lambda: found.get("ssm_layers", 0),
     ):
         valid = None
         with span("fit.init"):
@@ -207,6 +210,7 @@ def fit(conf: LMConfig, mesh=None, history: dict | None = None):
                 corpus, valid = load_text_corpus(conf.corpus)
                 conf = dataclasses.replace(conf, vocab=BYTE_VOCAB)
             model = build_model(conf, mesh)
+            found["ssm_layers"] = ssm_layers(model)
             if not conf.corpus:
                 corpus = synthetic_corpus(
                     200_000, model.embed.shape[0], seed=conf.seed

@@ -1,0 +1,443 @@
+"""State-space mixer (Mamba-2): what a hybrid LM block calls in place of
+attention.
+
+The reference has no sequence model at all (SURVEY section 5). A layer
+whose state is a recurrence has three parts, each here once:
+
+- :func:`causal_conv`: a depthwise causal convolution over the last few
+  positions (zeros before position 0);
+- :func:`ssd_scan`: the selective scan ``S_t = exp(dt_t A) S_{t-1} +
+  dt_t x_t B_t^T``, ``y_t = S_t C_t`` in its chunked form: inside a chunk
+  of ``chunk`` positions the decay-masked ``chunk x chunk`` block of
+  ``C_t . B_s`` times ``dt x``, across chunks the ``P x N`` state a head
+  carries. On a TPU the forward is one Pallas kernel, ``ssd_chunk``
+  (chunks in sequence on the grid's last axis, the state in VMEM scratch,
+  the ``chunk x chunk`` blocks made and dropped in VMEM); off it the same
+  sums run as ``jax.numpy``, a chunk at a time. Both return the state
+  entering every chunk, and the backward recomputes each chunk from it: a
+  short scan carries the state's cotangent backwards over the chunks, then
+  every chunk's gradients come from ``jax.vjp`` of the chunk's own sums, a
+  group of heads at a time so that the ``chunk x chunk`` blocks alive at
+  once stay a few hundred MB;
+- :func:`gated_rms_norm`: ``w * rmsnorm(y * silu(z))`` over the whole
+  inner width (the gate before the norm).
+
+:class:`Mamba2Mixer` holds the weights and strings the parts together
+under ``jax.named_scope``s ``ssm_in_proj``, ``ssm_conv``, ``ssm_scan``
+(``ssm_scan_bwd`` in the backward), ``ssm_gate_norm``, ``ssm_out_proj``.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from keystone_tpu.core.treenode import static_field, treenode
+from keystone_tpu.ops.flash_attention import (
+    _vmem_limit_bytes,
+    interpret_default,
+    on_tpu,
+)
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+# the step program's counters of its state-space layers: positions
+# scanned and chunks run, summed over layers
+COUNTERS = ("ssm_rows", "ssm_chunks")
+
+# heads a program of the forward kernel runs, and heads whose
+# chunk x chunk blocks the backward holds at once
+_HEAD_BLOCK = 8
+
+
+def causal_conv(x, w, b=None):
+    """Depthwise causal convolution. x: (B, S, C); w: (C, K); b: (C,) or
+    None. ``out[t] = b + sum_j w[:, j] * x[t - (K - 1) + j]``, positions
+    before 0 read as zero. float32 out."""
+    k = w.shape[1]
+    s = x.shape[1]
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+    wf = w.astype(jnp.float32)
+    out = sum(xp[:, j : j + s] * wf[:, j] for j in range(k))
+    return out if b is None else out + b.astype(jnp.float32)
+
+
+def step_size(dt, bias):
+    """A head's step: ``softplus(dt + bias)`` in float32, never clamped."""
+    return jax.nn.softplus(dt.astype(jnp.float32) + bias.astype(jnp.float32))
+
+
+def gated_rms_norm(y, z, scale, eps: float):
+    """``scale * rmsnorm(y * silu(z))`` over the last axis, statistics in
+    float32, in ``y``'s dtype."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return (g * scale.astype(jnp.float32)).astype(y.dtype)
+
+
+# ------------------------------------------------------------ the chunk's sums
+
+def _chunk_sums(x, dt, la, b, c, s_prev):
+    """Every chunk given the state entering it. x: (Z, C, L, E, P); dt,
+    la: (Z, C, L, E) float32 (``la = dt * A``, the log of a step's
+    decay); b, c: (Z, C, L, N); s_prev: (Z, C, E, P, N) float32. Returns
+    (y (Z, C, L, E, P) float32, the state leaving each chunk). Products
+    take operands in ``x``'s dtype and accumulate in float32; decays are
+    float32."""
+    cdt, f32 = x.dtype, jnp.float32
+    n_l = x.shape[2]
+    cum = jnp.cumsum(la, axis=2)  # (Z, C, L, E)
+    cum_e = jnp.moveaxis(cum, 3, 2)  # (Z, C, E, L)
+    tot = cum_e[..., -1]  # (Z, C, E)
+    cb = jnp.einsum("zcln,zcsn->zcls", c, b, preferred_element_type=f32)
+    seen = jnp.tril(jnp.ones((n_l, n_l), bool))
+    diff = cum_e[..., :, None] - cum_e[..., None, :]  # (Z, C, E, t, s)
+    decay = jnp.exp(jnp.where(seen, diff, -jnp.inf))
+    m = (cb[:, :, None] * decay).astype(cdt)
+    xdt = (x.astype(f32) * dt[..., None]).astype(cdt)
+    y = jnp.einsum("zcels,zcsep->zclep", m, xdt, preferred_element_type=f32)
+    y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+        "zcln,zcepn->zclep", c, s_prev.astype(cdt), preferred_element_type=f32
+    )
+    xw = (xdt.astype(f32) * jnp.exp(tot[:, :, None] - cum)[..., None]).astype(cdt)
+    s_next = jnp.exp(tot)[..., None, None] * s_prev + jnp.einsum(
+        "zclep,zcln->zcepn", xw, b, preferred_element_type=f32
+    )
+    return y, s_next
+
+
+def _in_chunks(a, n_l: int):
+    """(Z, S, ...) -> (Z, S / L, L, ...)."""
+    return a.reshape(a.shape[0], a.shape[1] // n_l, n_l, *a.shape[2:])
+
+
+def _forward_jnp(x, dt, la, b, c, n_l: int):
+    """The chunked form a chunk at a time: (y (Z, S, E, P) in x's dtype,
+    the state entering each chunk (Z, C, E, P, N) float32)."""
+    z, _s, e, p = x.shape
+    parts = tuple(
+        jnp.moveaxis(_in_chunks(a, n_l), 1, 0)[:, :, None]
+        for a in (x, dt, la, b, c)
+    )  # each (C, Z, 1, L, ...)
+
+    def step(state, part):
+        y, nxt = _chunk_sums(*part, state[:, None])
+        return nxt[:, 0], (y[:, 0], state)
+
+    start = jnp.zeros((z, e, p, b.shape[-1]), jnp.float32)
+    _last, (y, states) = lax.scan(step, start, parts)
+    y = jnp.moveaxis(y, 0, 1).reshape(x.shape)
+    return y.astype(x.dtype), jnp.moveaxis(states, 0, 1)
+
+
+# ------------------------------------------------------------ the kernel
+
+def _ssd_chunk_kernel(xdt_ref, cumc_ref, cumr_ref, keep_ref, b_ref, c_ref, y_ref,
+                      st_ref, state, *, heads: int):
+    """One program per (sequence, block of ``heads`` heads, chunk); the
+    chunks of a sequence run in order and ``state`` carries each head's
+    (P, N) state from one to the next. ``cumc`` and ``cumr`` hold the
+    running sum of the log decays inside the chunk as columns and as
+    rows, so that ``exp(cum_t - cum_s)`` needs no transpose; ``keep`` is
+    the chunk's whole decay a head, along the state's lanes (Mosaic has
+    no broadcast of one element along sublanes and lanes at once)."""
+    f32 = jnp.float32
+    n_l = xdt_ref.shape[2]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        state[...] = jnp.zeros_like(state)
+
+    bm, cm = b_ref[0], c_ref[0]  # (L, N)
+    cdt = bm.dtype
+    cb = lax.dot_general(cm, bm, _NT, preferred_element_type=f32)  # [t, s]
+    seen = lax.broadcasted_iota(jnp.int32, (n_l, n_l), 0) >= lax.broadcasted_iota(
+        jnp.int32, (n_l, n_l), 1
+    )
+    for h in range(heads):
+        cc = cumc_ref[0, 0, :, h : h + 1]  # (L, 1)
+        cr = cumr_ref[0, 0, h : h + 1, :]  # (1, L)
+        # (1, 1): the chunk's whole log decay
+        tot = cumc_ref[0, 0, n_l - 1 : n_l, h : h + 1]
+        decay = jnp.where(seen, jnp.exp(jnp.minimum(cc - cr, 0.0)), 0.0)
+        xh = xdt_ref[0, h]  # (L, P)
+        s_prev = state[h]  # (P, N)
+        st_ref[0, 0, h] = s_prev
+        y = jnp.dot((cb * decay).astype(cdt), xh, preferred_element_type=f32)
+        y = y + jnp.exp(cc) * lax.dot_general(
+            cm, s_prev.astype(cdt), _NT, preferred_element_type=f32
+        )
+        y_ref[0, h] = y.astype(y_ref.dtype)
+        xw = (xh.astype(f32) * jnp.exp(tot - cc)).astype(cdt)
+        state[h] = keep_ref[0, 0, h : h + 1, :] * s_prev + lax.dot_general(
+            xw, bm, _TN, preferred_element_type=f32
+        )
+
+
+def ssd_chunk(x, dt, la, b, c, n_l: int):
+    """The forward as the Pallas kernel: same arguments and results as
+    :func:`_forward_jnp`. ``S`` is a multiple of ``n_l``."""
+    z, s, e, p = x.shape
+    n = b.shape[-1]
+    hb = _HEAD_BLOCK if e % _HEAD_BLOCK == 0 else e
+    n_c = s // n_l
+    f32 = jnp.float32
+    # what XLA prepares: dt * x with heads in front of positions, and the
+    # running log decay of each chunk, as columns and as rows
+    xdt = jnp.swapaxes((x.astype(f32) * dt[..., None]).astype(x.dtype), 1, 2)
+    cum = jnp.cumsum(_in_chunks(la, n_l), axis=2).reshape(z, s, e // hb, hb)
+    cumc = jnp.moveaxis(cum, 2, 1)  # (Z, E/hb, S, hb)
+    cumr = jnp.swapaxes(cumc, 2, 3)  # (Z, E/hb, hb, S)
+    keep = jnp.exp(jnp.sum(_in_chunks(la, n_l), axis=2))  # (Z, C, E)
+    keep = jnp.broadcast_to(keep[..., None], (z, n_c, e, n))
+    interpret = interpret_default()
+    with jax.named_scope("ssd_chunk"):
+        y, states = pl.pallas_call(
+            functools.partial(_ssd_chunk_kernel, heads=hb),
+            grid=(z, e // hb, n_c),
+            in_specs=[
+                pl.BlockSpec((1, hb, n_l, p), lambda i, j, k: (i, j, k, 0)),
+                pl.BlockSpec((1, 1, n_l, hb), lambda i, j, k: (i, j, k, 0)),
+                pl.BlockSpec((1, 1, hb, n_l), lambda i, j, k: (i, j, 0, k)),
+                pl.BlockSpec((1, 1, hb, n), lambda i, j, k: (i, k, j, 0)),
+                pl.BlockSpec((1, n_l, n), lambda i, j, k: (i, k, 0)),
+                pl.BlockSpec((1, n_l, n), lambda i, j, k: (i, k, 0)),
+            ],
+            out_specs=(
+                pl.BlockSpec((1, hb, n_l, p), lambda i, j, k: (i, j, k, 0)),
+                pl.BlockSpec((1, 1, hb, p, n), lambda i, j, k: (i, k, j, 0, 0)),
+            ),
+            out_shape=(
+                jax.ShapeDtypeStruct((z, e, s, p), x.dtype),
+                jax.ShapeDtypeStruct((z, n_c, e, p, n), f32),
+            ),
+            scratch_shapes=[pltpu.VMEM((hb, p, n), f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=None if interpret else _vmem_limit_bytes(),
+            ),
+            interpret=interpret,
+            name="ssd_chunk",
+        )(xdt, cumc, cumr, keep, b, c)
+    return jnp.swapaxes(y, 1, 2), states
+
+
+# ------------------------------------------------------------ forward, backward
+
+def _use_kernel(n_l: int) -> bool:
+    # the kernel's row blocks are whole lane tiles
+    return on_tpu() and n_l % 128 == 0
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _ssd(x, dt, la, b, c, n_l: int):
+    return _ssd_fwd(x, dt, la, b, c, n_l)[0]
+
+
+def _ssd_fwd(x, dt, la, b, c, n_l: int):
+    forward = ssd_chunk if _use_kernel(n_l) else _forward_jnp
+    y, states = forward(x, dt, la, b, c, n_l)
+    return y, (x, dt, la, b, c, states)
+
+
+def _ssd_bwd(n_l: int, saved, dy):
+    """Gradients from the states that entered the chunks. The state's
+    cotangent obeys ``dS_in[k] = exp(tot_k) dS_in[k + 1]' + G_k`` with
+    ``G_k`` what chunk k's own outputs read of the state; after that
+    short scan every chunk is on its own."""
+    x, dt, la, b, c, states = saved
+    f32 = jnp.float32
+    z, s, e, p = x.shape
+    with jax.named_scope("ssm_scan_bwd"):
+        xs, dts, las, bs, cs, dys = (_in_chunks(a, n_l) for a in (x, dt, la, b, c, dy))
+        cum = jnp.cumsum(las, axis=2)
+        tot = cum[:, :, -1]  # (Z, C, E)
+        # what each chunk's y reads of the state entering it
+        read = jnp.einsum(
+            "zclep,zcln->zcepn",
+            (dys.astype(f32) * jnp.exp(cum)[..., None]).astype(x.dtype), cs,
+            preferred_element_type=f32,
+        )
+
+        def back(d_out, part):
+            t, g = part
+            return jnp.exp(t)[..., None, None] * d_out + g, d_out
+
+        # d_next[k]: cotangent of the state leaving chunk k (zero after
+        # the last: nothing reads the final state)
+        _first, d_next = lax.scan(
+            back, jnp.zeros_like(read[:, 0]),
+            (jnp.moveaxis(tot, 1, 0), jnp.moveaxis(read, 1, 0)), reverse=True,
+        )
+        d_next = jnp.moveaxis(d_next, 0, 1)  # (Z, C, E, P, N)
+
+        hb = _HEAD_BLOCK if e % _HEAD_BLOCK == 0 else e
+
+        def heads_first(a, axis):
+            # (..., E, ...) -> (E / hb, ..., hb, ...)
+            a = a.reshape(*a.shape[:axis], e // hb, hb, *a.shape[axis + 1 :])
+            return jnp.moveaxis(a, axis, 0)
+
+        def group(part):
+            xg, dtg, lag, sg, dyg, dng = part
+            _out, vjp = jax.vjp(
+                lambda xx, dd, ll, bb, cc: _chunk_sums(xx, dd, ll, bb, cc, sg),
+                xg, dtg, lag, bs, cs,
+            )
+            return vjp((dyg.astype(f32), dng))
+
+        dx, ddt, dla, db, dc = lax.map(
+            group,
+            (
+                heads_first(xs, 3), heads_first(dts, 3), heads_first(las, 3),
+                heads_first(states, 2), heads_first(dys, 3), heads_first(d_next, 2),
+            ),
+        )
+
+        def heads_back(a, axis):
+            a = jnp.moveaxis(a, 0, axis)
+            return a.reshape(*a.shape[:axis], e, *a.shape[axis + 2 :])
+
+        dx = heads_back(dx, 3).reshape(x.shape).astype(x.dtype)
+        ddt = heads_back(ddt, 3).reshape(dt.shape).astype(dt.dtype)
+        dla = heads_back(dla, 3).reshape(la.shape).astype(la.dtype)
+        db = jnp.sum(db, axis=0).reshape(b.shape).astype(b.dtype)
+        dc = jnp.sum(dc, axis=0).reshape(c.shape).astype(c.dtype)
+    return dx, ddt, dla, db, dc
+
+
+_ssd.defvjp(_ssd_fwd, _ssd_bwd)
+
+
+def ssd_scan(x, dt, a, b, c, chunk: int = 256):
+    """The selective scan from a zero state. x: (B, S, H, P); dt: (B, S,
+    H) float32, positive; a: (H,) float32, negative; b, c: (B, S, G, N)
+    with H a multiple of G (head ``h`` reads group ``h // (H / G)``).
+    Returns y (B, S, H, P) in x's dtype: ``y_t = S_t C_t``, without the
+    skip ``D x_t``. ``S`` need not be a multiple of ``chunk``: padded
+    positions have ``dt = 0``, which neither decays nor feeds the state."""
+    n, s, h, p = x.shape
+    g, st = b.shape[2], b.shape[3]
+    e = h // g
+    n_l = min(chunk, s)
+    pad = (-s) % n_l
+    la = dt * a  # the log of each step's decay
+
+    def groups_in_front(t, per_head: bool):
+        # (B, S, G * E, ...) or (B, S, G, N) -> (B * G, S, ...), padded
+        t = t.reshape(n, s, g, e, *t.shape[3:]) if per_head else t
+        t = jnp.moveaxis(t, 2, 1).reshape(n * g, s, *t.shape[3:])
+        return jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+
+    y = _ssd(
+        groups_in_front(x, True), groups_in_front(dt, True),
+        groups_in_front(la, True), groups_in_front(b, False),
+        groups_in_front(c, False), n_l,
+    )
+    y = y[:, :s].reshape(n, g, s, e, p)
+    return jnp.moveaxis(y, 1, 2).reshape(n, s, h, p)
+
+
+# ------------------------------------------------------------ the mixer
+
+@treenode
+class Mamba2Mixer:
+    """A Mamba-2 mixer's weights (no projection has a bias) and what is
+    static of it. ``inner = heads * head_dim``; the convolution runs over
+    ``inner + 2 * groups * state`` channels (x, B and C)."""
+
+    w_in: jnp.ndarray  # (d, 2 * inner + 2 * groups * state + heads)
+    conv_w: jnp.ndarray  # (inner + 2 * groups * state, K)
+    conv_b: jnp.ndarray | None
+    dt_bias: jnp.ndarray  # (heads,)
+    A_log: jnp.ndarray  # (heads,): A = -exp(A_log)
+    D: jnp.ndarray  # (heads,): the skip
+    norm: jnp.ndarray  # (inner,): the gated norm's scale
+    w_out: jnp.ndarray  # (inner, d)
+    heads: int = static_field(default=1)
+    head_dim: int = static_field(default=64)
+    state: int = static_field(default=128)
+    groups: int = static_field(default=1)
+    chunk: int = static_field(default=256)
+    eps: float = static_field(default=1e-5)
+
+    @staticmethod
+    def create(key, d: int, *, heads: int, head_dim: int, state: int,
+               groups: int = 1, conv: int = 4, conv_bias: bool = True,
+               chunk: int = 256, eps: float = 1e-5) -> "Mamba2Mixer":
+        """Seeded weights: matrices normal at 1/sqrt(fan_in); the conv
+        uniform in +-1/sqrt(K); ``A_log = log U[1, 16]``; ``dt_bias`` the
+        inverse softplus of a log-uniform dt in [1e-3, 1e-1]; ``D`` and
+        the norm's scale 1."""
+        inner = heads * head_dim
+        channels = inner + 2 * groups * state
+        ks = jax.random.split(key, 6)
+        bound = 1.0 / math.sqrt(conv)
+        dt = jnp.exp(
+            jax.random.uniform(ks[4], (heads,), jnp.float32)
+            * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3)
+        )
+        return Mamba2Mixer(
+            w_in=jax.random.normal(ks[0], (d, 2 * inner + 2 * groups * state + heads))
+            / math.sqrt(d),
+            conv_w=jax.random.uniform(ks[1], (channels, conv), jnp.float32, -bound, bound),
+            conv_b=jax.random.uniform(ks[2], (channels,), jnp.float32, -bound, bound)
+            if conv_bias else None,
+            dt_bias=dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+            A_log=jnp.log(jax.random.uniform(ks[3], (heads,), jnp.float32, 1.0, 16.0)),
+            D=jnp.ones((heads,), jnp.float32),
+            norm=jnp.ones((inner,), jnp.float32),
+            w_out=jax.random.normal(ks[5], (inner, d)) / math.sqrt(inner),
+            heads=heads, head_dim=head_dim, state=state, groups=groups,
+            chunk=chunk, eps=eps,
+        )
+
+    def __call__(self, y, mesh=None, mm_fn=None):
+        """y: (B, S, d) in the compute dtype -> ((B, S, d), the layer's
+        counters). Under a ``mesh`` whose ``data`` axis divides the
+        batch the scan is shard_mapped over it on a TPU (GSPMD cannot
+        partition a Mosaic kernel)."""
+        if mm_fn is None:
+            from keystone_tpu.ops.quantization import mm as mm_fn
+        n, s, _ = y.shape
+        cdt, f32 = y.dtype, jnp.float32
+        inner, gn = self.heads * self.head_dim, self.groups * self.state
+        with jax.named_scope("ssm_in_proj"):
+            zxbcdt = mm_fn(y, self.w_in, cdt)
+            z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * gn], axis=-1)
+        with jax.named_scope("ssm_conv"):
+            xbc = jax.nn.silu(causal_conv(xbc, self.conv_w, self.conv_b)).astype(cdt)
+            x, b, c = jnp.split(xbc, [inner, inner + gn], axis=-1)
+        x = x.reshape(n, s, self.heads, self.head_dim)
+        b = b.reshape(n, s, self.groups, self.state)
+        c = c.reshape(n, s, self.groups, self.state)
+        dt = step_size(dt, self.dt_bias)
+        a = -jnp.exp(self.A_log.astype(f32))
+        scan = functools.partial(ssd_scan, chunk=self.chunk)
+        if mesh is not None and on_tpu():
+            from jax.sharding import PartitionSpec as P
+
+            by_row = P("data" if n % dict(mesh.shape).get("data", n + 1) == 0 else None)
+            scan = jax.shard_map(
+                scan, mesh=mesh, in_specs=(by_row, by_row, P(), by_row, by_row),
+                out_specs=by_row, check_vma=False,  # pallas_call outputs carry no vma
+            )
+        with jax.named_scope("ssm_scan"):
+            out = scan(x, dt, a, b, c)
+            out = out.astype(f32) + self.D.astype(f32)[:, None] * x.astype(f32)
+        with jax.named_scope("ssm_gate_norm"):
+            out = gated_rms_norm(out.reshape(n, s, inner).astype(cdt), z, self.norm, self.eps)
+        with jax.named_scope("ssm_out_proj"):
+            out = mm_fn(out, self.w_out, cdt)
+        n_l = min(self.chunk, s)
+        return out, {
+            "ssm_rows": jnp.int32(n * s),
+            "ssm_chunks": jnp.int32(n * -(-s // n_l)),
+        }

@@ -119,3 +119,40 @@ def test_attention_backward_kernels_compile_at_the_cells_shapes(
     # the forward's readers match attn_full / attn_window in an op's name
     assert not any("attn_full" in c or "attn_window" in c for c in backward)
     assert " while(" not in text
+
+
+def test_the_scan_kernel_compiles_at_the_state_space_cells_shapes(topo, mosaic, monkeypatch):
+    """``jax.grad`` of the scan at granite-4.0-h-micro's shapes (1 x 8192
+    positions, 64 heads of 64, state 128, chunks of 256, bfloat16): the
+    forward is the ``ssd_chunk`` Mosaic call and nothing else is one (the
+    backward is XLA's), within the scoped VMEM limit of the described
+    chip; what the backward holds at once stays under half a GB."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+    from keystone_tpu.ops import ssm
+    from keystone_tpu.plan.costs import device_peaks
+
+    limit = device_peaks(topo.devices[0].device_kind).vmem_limit
+    monkeypatch.setattr(ssm, "interpret_default", lambda: False)
+    monkeypatch.setattr(ssm, "_vmem_limit_bytes", lambda: limit)
+    monkeypatch.setattr(ssm, "_use_kernel", lambda n_l: n_l % 128 == 0)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    x = shape((1, 8192, 64, 64), jnp.bfloat16)
+    dt, a = shape((1, 8192, 64), jnp.float32), shape((64,), jnp.float32)
+    bc = shape((1, 8192, 1, 128), jnp.bfloat16)
+
+    def loss(x, dt, a, b, c):
+        with jax.named_scope("ssm_scan"):
+            return jnp.sum(ssm.ssd_scan(x, dt, a, b, c, chunk=256).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(x, dt, a, bc, bc).compile()
+    calls = re.findall(
+        r"^\s*%(\S+) = .*custom-call\(.*tpu_custom_call", compiled.as_text(), re.M
+    )
+    assert len(calls) == 1 and "ssd_chunk" in calls[0], calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
