@@ -940,6 +940,7 @@ def _record_counters(model: TransformerLM, stats: list) -> None:
     or scans. A model without experts leaves their counters at 0, and
     one without state-space layers theirs."""
     from keystone_tpu.observe import spans as _spans
+    from keystone_tpu.ops.ssm import COUNTERS as SSM_COUNTERS
 
     slots = sum(b.moe.held for b in model.blocks if b.moe is not None)
     sl = _spans.active_span_log()
@@ -954,9 +955,9 @@ def _record_counters(model: TransformerLM, stats: list) -> None:
         steps=len(got),
         routed_rows=sum(routed),
         mm_rows=sum(int(c["mm_rows"]) for c in got),
-        # positions scanned and chunks run, summed over state-space layers
-        ssm_rows=sum(int(c.get("ssm_rows", 0)) for c in got),
-        ssm_chunks=sum(int(c.get("ssm_chunks", 0)) for c in got),
+        # positions scanned, chunks run and positions scanned by the
+        # kernel, summed over state-space layers
+        **{name: sum(int(c.get(name, 0)) for c in got) for name in SSM_COUNTERS},
         # largest load of a held expert over the mean load, a step
         load_max_over_mean=float(
             np.mean(

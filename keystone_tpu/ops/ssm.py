@@ -11,9 +11,11 @@ whose state is a recurrence has three parts, each here once:
   of ``chunk`` positions the decay-masked ``chunk x chunk`` block of
   ``C_t . B_s`` times ``dt x``, across chunks the ``P x N`` state a head
   carries. On a TPU the forward is one Pallas kernel, ``ssd_chunk``
-  (chunks in sequence on the grid's last axis, the state in VMEM scratch,
-  the ``chunk x chunk`` blocks made and dropped in VMEM); off it the same
-  sums run as ``jax.numpy``, a chunk at a time. Both return the state
+  (a sequence's chunks in order on the grid, the state in VMEM scratch,
+  the ``chunk x chunk`` blocks made and dropped in VMEM; it reads x, dt,
+  B and C positions-major, as the mixer holds them, and makes ``dt x``
+  and the running decay itself, so XLA moves nothing around it); off it
+  the same sums run as ``jax.numpy``, a chunk at a time. Both return the state
   entering every chunk, and the backward recomputes each chunk from it: a
   short scan carries the state's cotangent backwards over the chunks, then
   every chunk's gradients come from ``jax.vjp`` of the chunk's own sums, a
@@ -49,12 +51,16 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 # the step program's counters of its state-space layers: positions
-# scanned and chunks run, summed over layers
-COUNTERS = ("ssm_rows", "ssm_chunks")
+# scanned, chunks run, and positions whose scan ran in the kernel on the
+# mixer's own layout (0 on the jax.numpy path), summed over layers
+COUNTERS = ("ssm_rows", "ssm_chunks", "ssm_kernel_rows")
 
-# heads a program of the forward kernel runs, and heads whose
-# chunk x chunk blocks the backward holds at once
+# heads a program of the forward kernel runs (at 8 the grid's steps are a
+# sixth of its time), and heads whose chunk x chunk blocks the backward
+# holds at once
+_KERNEL_HEADS = 16
 _HEAD_BLOCK = 8
+_LANES = 128
 
 
 def causal_conv(x, w, b=None):
@@ -139,21 +145,47 @@ def _forward_jnp(x, dt, la, b, c, n_l: int):
 
 # ------------------------------------------------------------ the kernel
 
-def _ssd_chunk_kernel(xdt_ref, cumc_ref, cumr_ref, keep_ref, b_ref, c_ref, y_ref,
-                      st_ref, state, *, heads: int):
-    """One program per (sequence, block of ``heads`` heads, chunk); the
-    chunks of a sequence run in order and ``state`` carries each head's
-    (P, N) state from one to the next. ``cumc`` and ``cumr`` hold the
-    running sum of the log decays inside the chunk as columns and as
-    rows, so that ``exp(cum_t - cum_s)`` needs no transpose; ``keep`` is
-    the chunk's whole decay a head, along the state's lanes (Mosaic has
-    no broadcast of one element along sublanes and lanes at once)."""
-    f32 = jnp.float32
-    n_l = xdt_ref.shape[2]
+def _running_sum(a):
+    """Running sum down the rows of a float32 (L, E) block in log2(L)
+    shifted adds: every add is a float32 add, on the chip as here (an MXU
+    product of float32 operands would round them to bfloat16 pieces)."""
+    row = lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    k = 1
+    while k < a.shape[0]:
+        a = a + jnp.where(row >= k, pltpu.roll(a, k, 0), 0.0)
+        k *= 2
+    return a
 
-    @pl.when(pl.program_id(2) == 0)
-    def _start():
-        state[...] = jnp.zeros_like(state)
+
+def _ssd_chunk_kernel(x_ref, dt_ref, la_ref, b_ref, c_ref, y_ref, st_ref, state,
+                      dtc, cumc, cumr, *, heads: int, p: int, tile: int):
+    """One program per (sequence, chunk, block of ``heads`` heads); the
+    chunks of a sequence run in order and ``state`` carries every head's
+    (P, N) state from one to the next, heads down its rows. A chunk's
+    first program makes the running sum of the log decays of all its
+    heads, and leaves it (and ``dt``) in scratch a head block apart, as
+    columns (``cumc``, ``dtc``) and as rows (``cumr``), so that
+    ``exp(cum_t - cum_s)`` needs no transpose a head and a program reads
+    its own heads by a leading index (Mosaic slices lanes at static
+    offsets only). The ``tile`` heads whose ``p`` lanes fill a lane tile
+    (two heads of 64) are read, multiplied and written together: each
+    has its own ``chunk x chunk`` block, and ``spread`` picks for every
+    lane the result of the head it belongs to."""
+    f32 = jnp.float32
+    n_l, n, w = x_ref.shape[1], b_ref.shape[2], tile * p
+    k, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _chunk():
+        @pl.when(k == 0)
+        def _start():
+            state[...] = jnp.zeros_like(state)
+
+        cum, dt = _running_sum(la_ref[0]), dt_ref[0]  # (L, E)
+        cumr[...] = cum.T
+        for g in range(dt.shape[1] // heads):
+            cumc[g] = cum[:, g * heads : (g + 1) * heads]
+            dtc[g] = dt[:, g * heads : (g + 1) * heads]
 
     bm, cm = b_ref[0], c_ref[0]  # (L, N)
     cdt = bm.dtype
@@ -161,79 +193,106 @@ def _ssd_chunk_kernel(xdt_ref, cumc_ref, cumr_ref, keep_ref, b_ref, c_ref, y_ref
     seen = lax.broadcasted_iota(jnp.int32, (n_l, n_l), 0) >= lax.broadcasted_iota(
         jnp.int32, (n_l, n_l), 1
     )
-    for h in range(heads):
-        cc = cumc_ref[0, 0, :, h : h + 1]  # (L, 1)
-        cr = cumr_ref[0, 0, h : h + 1, :]  # (1, L)
-        # (1, 1): the chunk's whole log decay
-        tot = cumc_ref[0, 0, n_l - 1 : n_l, h : h + 1]
-        decay = jnp.where(seen, jnp.exp(jnp.minimum(cc - cr, 0.0)), 0.0)
-        xh = xdt_ref[0, h]  # (L, P)
-        s_prev = state[h]  # (P, N)
-        st_ref[0, 0, h] = s_prev
-        y = jnp.dot((cb * decay).astype(cdt), xh, preferred_element_type=f32)
-        y = y + jnp.exp(cc) * lax.dot_general(
-            cm, s_prev.astype(cdt), _NT, preferred_element_type=f32
+    # the first of this program's heads (0 where one block holds them all)
+    first = pl.multiple_of(j * heads, heads) if cumc.shape[0] > 1 else 0
+    cols, dts = cumc[j], dtc[j]  # (L, heads)
+    rows = cumr[pl.ds(first, heads), :]  # (heads, L)
+    lane_head = lax.broadcasted_iota(jnp.int32, (1, w), 1) // p
+    row_head = lax.broadcasted_iota(jnp.int32, (w, 1), 0) // p
+
+    def spread(parts, head_of):
+        out = parts[0]
+        for q in range(1, tile):
+            out = jnp.where(head_of == q, parts[q], out)
+        return out
+
+    for g in range(heads // tile):
+        hs = range(g * tile, (g + 1) * tile)
+        lanes = slice(g * w, (g + 1) * w)
+        cc = [cols[:, h : h + 1] for h in hs]  # (L, 1) each
+        # (1, 1): a head's whole log decay over the chunk, its last running sum
+        tot = [cols[n_l - 1 : n_l, h : h + 1] for h in hs]
+        dt = spread([dts[:, h : h + 1] for h in hs], lane_head)
+        xdt = (x_ref[0, :, lanes].astype(f32) * dt).astype(cdt)  # (L, w)
+        at = pl.multiple_of(first * p + g * w, w)
+        s_prev = state[pl.ds(at, w), :]  # (w, N): these heads' states
+        st_ref[0, 0, lanes, :] = s_prev
+        ys = []
+        for c_t, h in zip(cc, hs):
+            decay = jnp.where(seen, jnp.exp(jnp.minimum(c_t - rows[h : h + 1, :], 0.0)), 0.0)
+            ys.append(jnp.dot((cb * decay).astype(cdt), xdt, preferred_element_type=f32))
+        y = spread(ys, lane_head) + spread([jnp.exp(c_t) for c_t in cc], lane_head) * (
+            lax.dot_general(cm, s_prev.astype(cdt), _NT, preferred_element_type=f32)
         )
-        y_ref[0, h] = y.astype(y_ref.dtype)
-        xw = (xh.astype(f32) * jnp.exp(tot - cc)).astype(cdt)
-        state[h] = keep_ref[0, 0, h : h + 1, :] * s_prev + lax.dot_general(
+        y_ref[0, :, lanes] = y.astype(y_ref.dtype)
+        left = spread([jnp.exp(t - c_t) for t, c_t in zip(tot, cc)], lane_head)
+        xw = (xdt.astype(f32) * left).astype(cdt)
+        # along the state's lanes first: Mosaic has no broadcast of one
+        # element along sublanes and lanes at once
+        keep = spread([jnp.exp(jnp.broadcast_to(t, (1, n))) for t in tot], row_head)
+        state[pl.ds(at, w), :] = keep * s_prev + lax.dot_general(
             xw, bm, _TN, preferred_element_type=f32
         )
 
 
+def _head_block(e: int, p: int) -> tuple[int, int]:
+    """(heads a program of the kernel runs, heads to a lane tile)."""
+    hb = _KERNEL_HEADS if e % _KERNEL_HEADS == 0 else e
+    tile = min(_LANES // p, hb) if p < _LANES else 1
+    return hb, tile if hb % tile == 0 else 1
+
+
 def ssd_chunk(x, dt, la, b, c, n_l: int):
     """The forward as the Pallas kernel: same arguments and results as
-    :func:`_forward_jnp`. ``S`` is a multiple of ``n_l``."""
+    :func:`_forward_jnp`, read and written as the mixer holds them:
+    positions in front of heads, a block of x or y ``n_l`` positions by
+    a head block's ``hb * P`` lanes, ``dt`` and ``la`` a chunk of every
+    head. XLA prepares nothing for it. ``S`` is a multiple of ``n_l``."""
     z, s, e, p = x.shape
     n = b.shape[-1]
-    hb = _HEAD_BLOCK if e % _HEAD_BLOCK == 0 else e
+    hb, tile = _head_block(e, p)
     n_c = s // n_l
     f32 = jnp.float32
-    # what XLA prepares: dt * x with heads in front of positions, and the
-    # running log decay of each chunk, as columns and as rows
-    xdt = jnp.swapaxes((x.astype(f32) * dt[..., None]).astype(x.dtype), 1, 2)
-    cum = jnp.cumsum(_in_chunks(la, n_l), axis=2).reshape(z, s, e // hb, hb)
-    cumc = jnp.moveaxis(cum, 2, 1)  # (Z, E/hb, S, hb)
-    cumr = jnp.swapaxes(cumc, 2, 3)  # (Z, E/hb, hb, S)
-    keep = jnp.exp(jnp.sum(_in_chunks(la, n_l), axis=2))  # (Z, C, E)
-    keep = jnp.broadcast_to(keep[..., None], (z, n_c, e, n))
     interpret = interpret_default()
+
+    def of_chunk(width):
+        return pl.BlockSpec((1, n_l, width), lambda i, k, j: (i, k, 0))
+
+    by_heads = pl.BlockSpec((1, n_l, hb * p), lambda i, k, j: (i, k, j))
     with jax.named_scope("ssd_chunk"):
         y, states = pl.pallas_call(
-            functools.partial(_ssd_chunk_kernel, heads=hb),
-            grid=(z, e // hb, n_c),
-            in_specs=[
-                pl.BlockSpec((1, hb, n_l, p), lambda i, j, k: (i, j, k, 0)),
-                pl.BlockSpec((1, 1, n_l, hb), lambda i, j, k: (i, j, k, 0)),
-                pl.BlockSpec((1, 1, hb, n_l), lambda i, j, k: (i, j, 0, k)),
-                pl.BlockSpec((1, 1, hb, n), lambda i, j, k: (i, k, j, 0)),
-                pl.BlockSpec((1, n_l, n), lambda i, j, k: (i, k, 0)),
-                pl.BlockSpec((1, n_l, n), lambda i, j, k: (i, k, 0)),
-            ],
+            functools.partial(_ssd_chunk_kernel, heads=hb, p=p, tile=tile),
+            grid=(z, n_c, e // hb),
+            in_specs=[by_heads, of_chunk(e), of_chunk(e), of_chunk(n), of_chunk(n)],
             out_specs=(
-                pl.BlockSpec((1, hb, n_l, p), lambda i, j, k: (i, j, k, 0)),
-                pl.BlockSpec((1, 1, hb, p, n), lambda i, j, k: (i, k, j, 0, 0)),
+                by_heads,
+                pl.BlockSpec((1, 1, hb * p, n), lambda i, k, j: (i, k, j, 0)),
             ),
             out_shape=(
-                jax.ShapeDtypeStruct((z, e, s, p), x.dtype),
-                jax.ShapeDtypeStruct((z, n_c, e, p, n), f32),
+                jax.ShapeDtypeStruct((z, s, e * p), x.dtype),
+                jax.ShapeDtypeStruct((z, n_c, e * p, n), f32),
             ),
-            scratch_shapes=[pltpu.VMEM((hb, p, n), f32)],
+            scratch_shapes=[
+                pltpu.VMEM((e * p, n), f32),
+                pltpu.VMEM((e // hb, n_l, hb), f32),
+                pltpu.VMEM((e // hb, n_l, hb), f32),
+                pltpu.VMEM((e, n_l), f32),
+            ],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
                 vmem_limit_bytes=None if interpret else _vmem_limit_bytes(),
             ),
             interpret=interpret,
             name="ssd_chunk",
-        )(xdt, cumc, cumr, keep, b, c)
-    return jnp.swapaxes(y, 1, 2), states
+        )(x.reshape(z, s, e * p), dt, la, b, c)
+    return y.reshape(x.shape), states.reshape(z, n_c, e, p, n)
 
 
 # ------------------------------------------------------------ forward, backward
 
-def _use_kernel(n_l: int) -> bool:
-    # the kernel's row blocks are whole lane tiles
-    return on_tpu() and n_l % 128 == 0
+def _use_kernel(n_l: int, e: int, p: int) -> bool:
+    # the kernel's row blocks, and a head block of x, are whole lane tiles
+    return on_tpu() and n_l % _LANES == 0 and (_head_block(e, p)[0] * p) % _LANES == 0
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -242,7 +301,7 @@ def _ssd(x, dt, la, b, c, n_l: int):
 
 
 def _ssd_fwd(x, dt, la, b, c, n_l: int):
-    forward = ssd_chunk if _use_kernel(n_l) else _forward_jnp
+    forward = ssd_chunk if _use_kernel(n_l, *x.shape[2:]) else _forward_jnp
     y, states = forward(x, dt, la, b, c, n_l)
     return y, (x, dt, la, b, c, states)
 
@@ -437,7 +496,9 @@ class Mamba2Mixer:
         with jax.named_scope("ssm_out_proj"):
             out = mm_fn(out, self.w_out, cdt)
         n_l = min(self.chunk, s)
+        in_kernel = _use_kernel(n_l, self.heads // self.groups, self.head_dim)
         return out, {
             "ssm_rows": jnp.int32(n * s),
             "ssm_chunks": jnp.int32(n * -(-s // n_l)),
+            "ssm_kernel_rows": jnp.int32(n * s if in_kernel else 0),
         }

@@ -126,7 +126,10 @@ def test_the_scan_kernel_compiles_at_the_state_space_cells_shapes(topo, mosaic, 
     positions, 64 heads of 64, state 128, chunks of 256, bfloat16): the
     forward is the ``ssd_chunk`` Mosaic call and nothing else is one (the
     backward is XLA's), within the scoped VMEM limit of the described
-    chip; what the backward holds at once stays under half a GB."""
+    chip; what the backward holds at once stays under half a GB. The
+    kernel reads x, dt, B and C as the mixer holds them, so the compiled
+    forward has nothing of XLA's around it that transposes x or y, adds
+    up the decays or holds x in float32."""
     import re
 
     from jax.sharding import SingleDeviceSharding
@@ -136,7 +139,8 @@ def test_the_scan_kernel_compiles_at_the_state_space_cells_shapes(topo, mosaic, 
     limit = device_peaks(topo.devices[0].device_kind).vmem_limit
     monkeypatch.setattr(ssm, "interpret_default", lambda: False)
     monkeypatch.setattr(ssm, "_vmem_limit_bytes", lambda: limit)
-    monkeypatch.setattr(ssm, "_use_kernel", lambda n_l: n_l % 128 == 0)
+    monkeypatch.setattr(ssm, "on_tpu", lambda: True)
+    assert ssm._use_kernel(256, 64, 64) and not ssm._use_kernel(256, 12, 8)
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def shape(dims, dtype):
@@ -146,13 +150,46 @@ def test_the_scan_kernel_compiles_at_the_state_space_cells_shapes(topo, mosaic, 
     dt, a = shape((1, 8192, 64), jnp.float32), shape((64,), jnp.float32)
     bc = shape((1, 8192, 1, 128), jnp.bfloat16)
 
-    def loss(x, dt, a, b, c):
+    def scan(x, dt, a, b, c):
         with jax.named_scope("ssm_scan"):
-            return jnp.sum(ssm.ssd_scan(x, dt, a, b, c, chunk=256).astype(jnp.float32))
+            return ssm.ssd_scan(x, dt, a, b, c, chunk=256)
+
+    def loss(*args):
+        return jnp.sum(scan(*args).astype(jnp.float32))
+
+    def mosaic_calls(text):
+        return re.findall(r"^\s*%(\S+) = .*custom-call\(.*tpu_custom_call", text, re.M)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(x, dt, a, bc, bc).compile()
-    calls = re.findall(
-        r"^\s*%(\S+) = .*custom-call\(.*tpu_custom_call", compiled.as_text(), re.M
-    )
+    calls = mosaic_calls(compiled.as_text())
     assert len(calls) == 1 and "ssd_chunk" in calls[0], calls
     assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+
+    def ops_of(text):
+        # (result shape, opcode) of every instruction with one result
+        return re.findall(r"^\s*(?:ROOT )?%\S+ = (\S+) ([\w-]+)\(", text, re.M)
+
+    def moved(ops, shapes):
+        return [
+            (shp, op) for shp, op in ops
+            if op in ("copy", "transpose") and re.match(shapes, shp)
+        ]
+
+    forward = jax.jit(scan).lower(x, dt, a, bc, bc).compile().as_text()
+    assert len(mosaic_calls(forward)) == 1
+    ops = ops_of(forward)
+    assert not [op for _shape, op in ops if op == "reduce-window"]
+    assert not moved(ops, r"\w+\[1,(8192,64|64,8192),64\]")
+    wide = [shp for shp, _op in ops if re.match(r"f32\[(1,)?8192,(4096|64,64)\]", shp)]
+    assert not wide, wide
+
+    # and in its place: one mixer's forward moves no array of x's size at
+    # all (a jitted ssd_scan's 4-D parameters get layouts of their own)
+    mixer = jax.eval_shape(
+        lambda: ssm.Mamba2Mixer.create(
+            jax.random.key(0), 2048, heads=64, head_dim=64, state=128, groups=1
+        )
+    )
+    mixer = jax.tree.map(lambda l: shape(l.shape, l.dtype), mixer)
+    layer = jax.jit(lambda m, y: m(y)).lower(mixer, shape((1, 8192, 2048), jnp.bfloat16))
+    assert not moved(ops_of(layer.compile().as_text()), r"\w+\[(1,)?(8192|64),")

@@ -81,18 +81,31 @@ def by_recurrence(x, dt, a, b, c):
 
 
 @pytest.mark.parametrize("kernel", [False, True])
-@pytest.mark.parametrize("s,chunk,groups", [(40, 16, 1), (32, 32, 1), (48, 16, 2)])
-def test_the_chunked_scan_is_the_recurrence(rng, monkeypatch, s, chunk, groups, kernel):
+@pytest.mark.parametrize(
+    "s,chunk,groups,h,p",
+    [
+        (40, 16, 1, 16, 8), (32, 32, 1, 16, 8), (48, 16, 2, 16, 8),
+        # the kernel's lane-sliced heads: head 64, two to a lane tile, in two
+        # head blocks at a length that is no multiple of the chunk; head 128
+        # in two groups; two blocks of sixteen heads to a tile; twelve heads,
+        # one block of 96 lanes
+        (40, 16, 1, 32, 64), (32, 16, 2, 8, 128), (32, 16, 1, 32, 8), (24, 8, 1, 12, 8),
+    ],
+)
+def test_the_chunked_scan_is_the_recurrence(rng, monkeypatch, s, chunk, groups, h, p, kernel):
     """The ``jax.numpy`` chunked form and the ``ssd_chunk`` kernel in
     interpret mode against the scan position by position, forward and
     every gradient: at a length that is no multiple of the chunk, with
-    one chunk the whole length, and with two groups of B and C."""
-    monkeypatch.setattr(ssm, "_use_kernel", lambda n_l: kernel)
-    args = scan_inputs(rng, s, groups)
+    one chunk the whole length, with two groups of B and C, and with
+    heads of 64 and 128 whose block is and is not whole lane tiles."""
+    monkeypatch.setattr(ssm, "_use_kernel", lambda *shape: kernel)
+    args = scan_inputs(rng, s, groups, h=h, p=p)
     ct = jnp.asarray(rng.normal(size=args[0].shape), jnp.float32)
     want, vjp = jax.vjp(by_recurrence, *args)
     got, got_vjp = jax.vjp(lambda *a: ssm.ssd_scan(*a, chunk=chunk), *args)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
+    # 3e-5, or 4e-6 of the largest output where 64 x more outputs reach past 8
+    atol = max(3e-5, 4e-6 * float(jnp.abs(want).max()))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol)
     for a, b in zip(got_vjp(ct), vjp(ct)):
         assert a.shape == b.shape
         np.testing.assert_allclose(
@@ -100,14 +113,43 @@ def test_the_chunked_scan_is_the_recurrence(rng, monkeypatch, s, chunk, groups, 
         )
 
 
-def test_the_kernel_returns_the_states_the_backward_starts_from(rng):
-    x, dt, a, b, c = scan_inputs(rng, 64, n=1)
+@pytest.mark.parametrize("h,p", [(16, 8), (16, 64)])
+def test_the_kernel_returns_the_states_the_backward_starts_from(rng, h, p):
+    """``ssd_chunk`` takes x, dt, ``dt A``, B and C positions-major, as
+    the mixer holds them, and returns y there and the state entering
+    every chunk, as the ``jax.numpy`` path does."""
+    x, dt, a, b, c = scan_inputs(rng, 64, n=1, h=h, p=p)
     la = dt * a
     y, states = ssm.ssd_chunk(x, dt, la, b[:, :, 0], c[:, :, 0], 16)
     want_y, want_states = ssm._forward_jnp(x, dt, la, b[:, :, 0], c[:, :, 0], 16)
-    assert states.shape == (1, 4, 16, 8, 16) and not np.asarray(states[:, 0]).any()
+    assert y.shape == x.shape and y.dtype == x.dtype
+    assert states.shape == (1, 4, h, p, 16) and not np.asarray(states[:, 0]).any()
     np.testing.assert_allclose(np.asarray(states), np.asarray(want_states), atol=2e-5)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=2e-5)
+    # 2e-5, or the running sums' own rounding (1e-5 of a log decay past 100)
+    atol = max(2e-5, 1e-5 * float(jnp.abs(want_y).max()))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=atol)
+
+
+def test_the_kernels_running_sum_is_float32s(rng, published):
+    """The running log decay the kernel makes for itself, a chunk of 256
+    positions of 64 heads drawn where granite's ``A_log`` and ``dt_bias``
+    start: ``jnp.cumsum`` in float32 within 1e-6 relative (the decays
+    feed ``exp``; a bfloat16 pass would be off by 4e-3)."""
+    from jax.experimental import pallas as pl
+
+    heads, n_l = published["mamba_n_heads"], 256
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), size=(n_l, heads)))
+    la = jnp.asarray(-dt * rng.uniform(1.0, 16.0, size=(heads,)), jnp.float32)
+
+    def kernel(la_ref, out_ref):
+        out_ref[...] = ssm._running_sum(la_ref[...])
+
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(la.shape, jnp.float32), interpret=True
+    )(la)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(jnp.cumsum(la, axis=0)), rtol=1e-6)
+    exact = np.cumsum(np.asarray(la, np.float64), axis=0)
+    np.testing.assert_allclose(np.asarray(got, np.float64), exact, rtol=1e-6)
 
 
 def test_the_conv_is_four_shifted_products(rng):
@@ -267,11 +309,19 @@ def test_flops_decode_and_sharding_know_a_state_space_layer(model):
     assert whole == jax.sharding.PartitionSpec()
 
 
-def test_the_tied_head_lies_behind_the_learned_final_norm(model, tokens):
+def test_the_tied_head_lies_behind_the_learned_final_norm(model, tokens, monkeypatch):
     from keystone_tpu.models.lm.model import output_logits
 
     x, counters = model.backbone(tokens[:, :-1])
     assert int(counters["ssm_rows"]) == 2 * 2 * 64 and int(counters["ssm_chunks"]) == 2 * 2 * 4
+    # off the TPU no position is scanned by the kernel; where the kernel
+    # runs (here in interpret mode) every one is
+    assert int(counters["ssm_kernel_rows"]) == 0
+    with monkeypatch.context() as patched:
+        patched.setattr(ssm, "_use_kernel", lambda *shape: True)
+        in_kernel, counters = model.backbone(tokens[:, :-1])
+    assert int(counters["ssm_kernel_rows"]) == int(counters["ssm_rows"]) == 2 * 2 * 64
+    np.testing.assert_allclose(np.asarray(in_kernel), np.asarray(x), atol=1e-5)
     doubled = dataclasses.replace(model, final_norm=2.0 * model.final_norm)
     np.testing.assert_allclose(
         np.asarray(output_logits(doubled, x, jnp.float32)),
@@ -313,6 +363,7 @@ def test_a_second_fit_records_no_jit_span(tmp_path, toy):
     counters = next(r for r in recs if r["name"] == "fit.counters")
     # two state-space layers x 128 positions x 2 steps, in chunks of 16
     assert (counters["ssm_rows"], counters["ssm_chunks"]) == (2 * 2 * 128, 2 * 2 * 8)
+    assert counters["ssm_kernel_rows"] == 0  # the CPU runs the jax.numpy scan
     assert (counters["routed_rows"], counters["mm_rows"]) == (0, 0)
 
 
