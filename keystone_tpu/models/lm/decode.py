@@ -104,7 +104,7 @@ def prefill(model: TransformerLM, tokens, s_max: int,
 
     ks, vs = [], []
     for blk in model.blocks:
-        x, (k, v), _ = _block_apply(
+        x, (k, v), _, _ = _block_apply(
             x, blk, cdt,
             lambda y, b: model._attention(y, b, return_kv=True),
             mm_fn=model_mm(model),
@@ -238,7 +238,7 @@ def decode_step(model: TransformerLM, token, cache: KVCache):
 
     mm_fn = model_mm(model)
     for i, blk in enumerate(model.blocks):
-        x, _, _ = _block_apply(x, blk, cdt, cached_attn(i), mm_fn=mm_fn)
+        x, _, _, _ = _block_apply(x, blk, cdt, cached_attn(i), mm_fn=mm_fn)
     logits = _tied_logits(x, model.embed, cdt)[:, 0]
     # past-capacity poison: at pos >= S_max the cache write would clamp
     # onto S_max-1 and return plausible-but-wrong logits; pos is traced,

@@ -37,7 +37,8 @@ from keystone_tpu.ops.attention import (
     ring_attention,
     ulysses_attention,
 )
-from keystone_tpu.ops.moe import COUNTERS, MoELayer, ffn
+from keystone_tpu.ops.cca import COUNTERS as CCA_COUNTERS, CCAMixer
+from keystone_tpu.ops.moe import COUNTERS, CarriedRouter, MoELayer, ffn
 from keystone_tpu.ops.quantization import QTensor, mm
 from keystone_tpu.ops.ssm import COUNTERS as SSM_COUNTERS, Mamba2Mixer
 from keystone_tpu.ops.vit import _layer_norm
@@ -98,12 +99,15 @@ class LayerSpec:
 
 @treenode
 class LMBlock:
-    """One decoder block: attention or a state-space mixer, then a dense
-    FFN or routed experts. The one block definition: the toy presets of
+    """One decoder block: attention, a state-space mixer or attention in
+    a compressed latent, then a dense FFN or routed experts (scored by
+    the expert layer's own matrix, or by ``router``, which also reads
+    and leaves a state that flows from block to block beside ``x``).
+    The one block definition: the toy presets of
     :meth:`TransformerLM.create` and a public ``config.json``
     (:meth:`TransformerLM.from_config`) fill the same fields."""
 
-    wq: jnp.ndarray  # (d, H·hd); zero-width under a state-space mixer
+    wq: jnp.ndarray  # (d, H·hd); zero-width under another mixer
     wk: jnp.ndarray  # (d, KV·hd)
     wv: jnp.ndarray
     wo: jnp.ndarray  # (H·hd, d)
@@ -115,6 +119,12 @@ class LMBlock:
     norm2: jnp.ndarray | None = None  # for the parameter-free LayerNorm
     moe: object | None = None  # ops.moe.MoELayer in place of the FFN
     ssm: object | None = None  # ops.ssm.Mamba2Mixer in place of attention
+    cca: object | None = None  # ops.cca.CCAMixer in place of attention
+    router: object | None = None  # ops.moe.CarriedRouter scoring for moe
+    # learned scales and biases where a branch joins the stream, rows
+    # (s, b, t, u): x = (s * x + b) + (t * branch + u); None = x + branch
+    scale1: jnp.ndarray | None = None  # (4, d): after the mixer
+    scale2: jnp.ndarray | None = None  # (4, d): after the FFN or experts
     spec: LayerSpec | None = static_field(default=None)
 
 
@@ -195,25 +205,36 @@ def _rope(x, positions, rope: RopeSpec = RopeSpec()):
 
 
 def _block_apply(x, blk: LMBlock, cdt, attn, mm_fn=mm, eps: float = 1e-6,
-                 mesh=None, residual: float = 1.0):
+                 mesh=None, residual: float = 1.0, carried=None):
     """Pre-norm residual block shared by training forward, prefill, and
     decode: ``attn(y, blk) -> (mixer output (N,S,d), aux)``. Routed
     experts (``blk.moe``) take the dense FFN's place, told the ``mesh``
     the activations are split over; each branch joins the stream times
-    ``residual``; returns (x, attn_aux, the expert layer's counters or
-    None)."""
+    ``residual``, or under the block's learned scales and biases where
+    it has them. ``carried`` is the router state the block before left
+    (None where there is none): a block with a ``router`` reads it and
+    leaves its own, any other hands it on untouched. Returns (x,
+    attn_aux, the expert layer's counters or None, carried)."""
 
-    def join(x, branch):
-        return x + (branch if residual == 1.0 else branch * residual)
+    def join(x, branch, scales):
+        if scales is None:
+            return x + (branch if residual == 1.0 else branch * residual)
+        with jax.named_scope("residual_scale"):
+            s, b, t, u = scales.astype(jnp.float32)
+            return ((s * x + b) + (t * branch + u)).astype(x.dtype)
 
     a, aux = attn(_norm(x, blk.norm1, eps, cdt), blk)
-    x = join(x, a)
+    x = join(x, a, blk.scale1)
     y = _norm(x, blk.norm2, eps, cdt)
     if blk.moe is not None:
-        f, counters = blk.moe(y, mesh)
-        return join(x, f), aux, counters
+        scores = None
+        if blk.router is not None:
+            scores, carried = blk.router(y, carried)
+        f, counters = blk.moe(y, mesh, scores)
+        return join(x, f, blk.scale2), aux, counters, carried
     with jax.named_scope("dense_ffn"):
-        return join(x, ffn(y, blk.w1, blk.w2, blk.w3, cdt, mm_fn)), aux, None
+        f = ffn(y, blk.w1, blk.w2, blk.w3, cdt, mm_fn)
+        return join(x, f, blk.scale2), aux, None, carried
 
 
 def _gather_embed(embed, tokens):
@@ -359,6 +380,27 @@ class TransformerLM:
                     "recurrent state",
                 )
                 continue
+            if blk.router is not None:
+                why.setdefault(
+                    "router",
+                    f"layer {i} routes by a state carried from layer to "
+                    "layer: a slot holds nothing for it",
+                )
+            if blk.scale1 is not None or blk.scale2 is not None:
+                why.setdefault(
+                    "residual",
+                    f"layer {i} joins its branches under learned scales "
+                    "and biases",
+                )
+            if blk.cca is not None:
+                why.setdefault(
+                    "cca",
+                    f"layer {i} attends in a compressed latent behind "
+                    "convolutions (CCA): a slot would have to hold latent K "
+                    "and V, the convolutions' tails and the previous "
+                    "position's hidden state",
+                )
+                continue
             spec = self.layer_spec(blk)
             if spec.scale is not None:
                 why.setdefault("scale", f"layer {i} scales its scores by {spec.scale}")
@@ -411,12 +453,30 @@ class TransformerLM:
     def _attention(self, x, blk: LMBlock, return_kv: bool = False):
         n, s, _ = x.shape
         spec = self.layer_spec(blk)
-        h, window = spec.num_heads, spec.window
 
         # x is always the full (global) sequence here — the
         # sequence-parallel paths shard inside ring/ulysses_attention
         q, k, v = self._qkv_heads(x, blk)
         kv_raw = (k, v)  # what the decode cache stores
+        out = self._attend(q, k, v, spec)
+        if blk.wg is not None:
+            # one sigmoid gate a head, on that head's output
+            gate = jax.nn.sigmoid(model_mm(self)(x, blk.wg, x.dtype))
+            out = out * gate.transpose(0, 2, 1)[..., None].astype(out.dtype)
+        proj = model_mm(self)(
+            out.transpose(0, 2, 1, 3).reshape(n, s, -1).astype(x.dtype),
+            blk.wo,
+            x.dtype,
+        )
+        if return_kv:
+            return proj, kv_raw
+        return proj
+
+    def _attend(self, q, k, v, spec: LayerSpec):
+        """Causal attention of q (B, H, S, hd) over grouped k, v
+        (B, KV, S, hd) under the layer's window: the sequence-parallel
+        bodies, the flash kernel on a TPU, dense off it."""
+        n, h, window = q.shape[0], spec.num_heads, spec.window
         # sequence-parallel training runs the custom-VJP bodies: the ring
         # backward circulates dk/dv accumulators around the ring (the
         # per-hop Pallas forward kernels are forward-only), Ulysses
@@ -495,18 +555,7 @@ class TransformerLM:
                     out = dense_attention(
                         q, k, v, causal=True, window=window
                     )
-        if blk.wg is not None:
-            # one sigmoid gate a head, on that head's output
-            gate = jax.nn.sigmoid(model_mm(self)(x, blk.wg, x.dtype))
-            out = out * gate.transpose(0, 2, 1)[..., None].astype(out.dtype)
-        proj = model_mm(self)(
-            out.transpose(0, 2, 1, 3).reshape(n, s, -1).astype(x.dtype),
-            blk.wo,
-            x.dtype,
-        )
-        if return_kv:
-            return proj, kv_raw
-        return proj
+        return out
 
     def __call__(self, tokens):
         """(B, S) int tokens → (B, S, V) float32 logits."""
@@ -514,37 +563,52 @@ class TransformerLM:
 
     def _mixer(self, y, blk: LMBlock):
         """(the block's mixer on ``y``, its counters or None): attention,
-        or the state-space mixer that stands in for it."""
-        if blk.ssm is None:
-            return self._attention(y, blk), None
-        return blk.ssm(y, self.mesh, model_mm(self))
+        or the state-space or compressed-latent mixer that stands in for
+        it."""
+        if blk.ssm is not None:
+            return blk.ssm(y, self.mesh, model_mm(self))
+        if blk.cca is not None:
+            spec = self.layer_spec(blk)
+            positions = jnp.arange(y.shape[1])
+            return blk.cca(
+                y,
+                rotate=lambda t: _rope(t, positions, spec.rope),
+                attend=lambda q, k, v: self._attend(q, k, v, spec),
+                mm_fn=model_mm(self),
+            )
+        return self._attention(y, blk), None
 
     def backbone(self, tokens):
         """(final hidden states (B, S, d) before the final norm and the
         head, the expert layers' counters summed over layers and, where
-        the model has state-space layers, theirs) — the forward minus
-        the logits projection, so losses can choose how (or whether) to
-        materialize logits."""
+        the model has state-space or compressed-latent layers, theirs) —
+        the forward minus the logits projection, so losses can choose
+        how (or whether) to materialize logits. Beside ``x`` the router
+        state flows from block to block (None until a block leaves one)."""
         cdt = jnp.dtype(self.compute_dtype)
         x = _embed(self, tokens, cdt)
 
-        def block_fn(x, blk):
-            out, scanned, counters = _block_apply(
+        def block_fn(x, carried, blk):
+            out, mixed, counters, carried = _block_apply(
                 x, blk, cdt, self._mixer,
                 mm_fn=model_mm(self),
                 eps=self.norm_eps,
                 mesh=self.mesh,
                 residual=self.residual_multiplier,
+                carried=carried,
             )
-            return out, counters, scanned
+            return out, carried, counters, mixed
 
         if self.remat:
             block_fn = remat_wrap(block_fn, self.remat_policy)
         total = {c: jnp.int32(0) for c in COUNTERS}
         if any(blk.ssm is not None for blk in self.blocks):
             total.update({c: jnp.int32(0) for c in SSM_COUNTERS})
+        if any(blk.cca is not None for blk in self.blocks):
+            total.update({c: jnp.int32(0) for c in CCA_COUNTERS})
+        carried = None
         for blk in self.blocks:
-            x, counters, scanned = block_fn(x, blk)
+            x, carried, counters, mixed = block_fn(x, carried, blk)
             if counters is not None:
                 total.update(
                     routed_rows=total["routed_rows"] + counters["routed_rows"],
@@ -553,8 +617,13 @@ class TransformerLM:
                     ),
                     mm_rows=total["mm_rows"] + counters["mm_rows"],
                 )
-            if scanned is not None:
-                total.update({c: total[c] + scanned[c] for c in SSM_COUNTERS})
+                if "gate_sum" in counters:
+                    total["gate_sum"] = (
+                        total.get("gate_sum", 0.0) + counters["gate_sum"]
+                    )
+            if mixed is not None:
+                names = SSM_COUNTERS if blk.ssm is not None else CCA_COUNTERS
+                total.update({c: total[c] + mixed[c] for c in names})
         return x, total
 
     def forward_with_aux(self, tokens):
@@ -676,9 +745,14 @@ class TransformerLM:
         ``rope_parameters`` by layer type, ``num_experts``, or a hybrid's
         ``layer_types`` of "mamba" / "attention" with its ``mamba_*``
         keys, ``position_embedding_type`` "nope" and its four
-        multipliers ...), at the sizes the description gives **as held
-        here**: ``num_hidden_layers`` layers from the front of the
-        per-layer lists, ``num_experts`` routed experts a layer,
+        multipliers, or ``model_type`` "zaya"'s ``layer_types`` of
+        "hybrid": attention in a compressed latent with ``cca_time0`` /
+        ``cca_time1`` convolutions, then one routed expert a token chosen
+        by an MLP of ``router_hidden_size`` fed by a carried state, each
+        branch joining under learned scales ...), at the sizes the
+        description gives **as held here**: ``num_hidden_layers`` layers
+        from the front of the per-layer lists, ``num_experts`` routed
+        experts a layer,
         ``vocab_size`` ids. Where that is one chip's share of a
         deployment, ``published`` gives the model's own counts (the
         router keeps ``published.num_experts`` outputs) and
@@ -726,8 +800,9 @@ class TransformerLM:
                 raise ValueError(f"layer {i}: {h} heads over {kvh} K/V heads")
             sliding = kinds[i] == "sliding_attention"
             ks = jax.random.split(k_layers[i], 9)
-            sparse = mlps[i] == "sparse"
-            ff = c.get("shared_intermediate_size", c["intermediate_size"])
+            hybrid = kinds[i] == "hybrid"
+            sparse = mlps[i] == "sparse" or hybrid
+            ff = c.get("shared_intermediate_size", c.get("intermediate_size"))
             after_mixer = dict(
                 w1=jnp.zeros((d, 0), jnp.float32)
                 if sparse
@@ -746,20 +821,49 @@ class TransformerLM:
                     scoring="sigmoid",
                     routed_scale=c.get("moe_routed_scaling_factor", 1.0),
                     router_std=1.0 / math.sqrt(d),
+                    # one gate a token stays the chosen probability
+                    renormalize=not hybrid or c["num_experts_per_tok"] > 1,
                 )
                 if sparse
                 else None,
             )
-            if kinds[i] == "mamba":
-                # the mixer stands in for the attention weights, which
-                # stay as zero-width placeholders (as w1 / w2 do under
-                # routed experts)
+            # a mixer stands in for the attention weights, which stay as
+            # zero-width placeholders (as w1 / w2 do under routed experts)
+            no_attention = dict(
+                wq=jnp.zeros((d, 0), jnp.float32),
+                wk=jnp.zeros((d, 0), jnp.float32),
+                wv=jnp.zeros((d, 0), jnp.float32),
+                wo=jnp.zeros((0, d), jnp.float32),
+            )
+            if hybrid:
+                # the scores come from the block's router: the expert
+                # layer's own matrix is a zero-height placeholder
+                kc, kr = jax.random.split(ks[0])
+                after_mixer["moe"] = dataclasses.replace(
+                    after_mixer["moe"], w_router=jnp.zeros((0, routed), jnp.float32)
+                )
                 blocks.append(
                     LMBlock(
-                        wq=jnp.zeros((d, 0), jnp.float32),
-                        wk=jnp.zeros((d, 0), jnp.float32),
-                        wv=jnp.zeros((d, 0), jnp.float32),
-                        wo=jnp.zeros((0, d), jnp.float32),
+                        cca=CCAMixer.create(
+                            kc, d, heads=h, kv_heads=kvh, head_dim=hd,
+                            time0=c["cca_time0"], time1=c["cca_time1"],
+                            eps=c.get("rms_norm_eps", 1e-6),
+                        ),
+                        router=CarriedRouter.create(
+                            kr, d, c["router_hidden_size"], routed,
+                            eps=c.get("rms_norm_eps", 1e-6),
+                        ),
+                        scale1=jnp.tile(jnp.array([[1.0], [0.0]]), (2, d)),
+                        scale2=jnp.tile(jnp.array([[1.0], [0.0]]), (2, d)),
+                        spec=LayerSpec(h, kvh, rope=rope_of("hybrid")),
+                        **no_attention,
+                        **after_mixer,
+                    )
+                )
+                continue
+            if kinds[i] == "mamba":
+                blocks.append(
+                    LMBlock(
                         ssm=Mamba2Mixer.create(
                             ks[0], d,
                             heads=c["mamba_n_heads"], head_dim=c["mamba_d_head"],
@@ -769,6 +873,7 @@ class TransformerLM:
                             eps=c.get("rms_norm_eps", 1e-6),
                         ),
                         spec=LayerSpec(0, 0),
+                        **no_attention,
                         **after_mixer,
                     )
                 )
@@ -844,9 +949,10 @@ def train_step_flops(model: TransformerLM, batch: int, seq: int) -> float:
     touches (a routed layer's experts at ``top_k`` times the share held
     here, under even routing; the embedding table is a gather unless the
     logits are tied to it), plus the causal score and value products
-    (a window layer reckoned at its window; a state-space block, whose
-    ``wq`` is zero-width, has none, and its scan's own FLOPs, under 2 % of
-    such a step, are left out). Recomputation not counted."""
+    (a window layer reckoned at its window, a compressed-latent layer at
+    its latent's width; a state-space block, whose ``wq`` is zero-width,
+    has none, and its scan's own FLOPs, under 2 % of such a step, are
+    left out). Recomputation not counted."""
     tokens = batch * seq
     touched = 0.0
     attn = 0.0
@@ -863,7 +969,8 @@ def train_step_flops(model: TransformerLM, batch: int, seq: int) -> float:
         keys = (seq + 1) / 2  # mean keys a causal query sees
         if spec.window:
             keys = min(keys, spec.window)
-        attn += 12 * blk.wq.shape[1] * keys * tokens
+        wq = blk.wq if blk.cca is None else blk.cca.wq
+        attn += 12 * wq.shape[1] * keys * tokens
     head = model.embed if model.head is None else model.head
     touched += int(np.prod(head.shape))
     return 6.0 * touched * tokens + attn

@@ -26,7 +26,11 @@ def shard_params(model: TransformerLM, mesh) -> TransformerLM:
     routed experts are left as they are, and so is a state-space mixer:
     its leaves stay whole on every device under ``model`` (its scan is
     split over ``data`` alone; no head-parallel layout of the mixer is
-    written).
+    written). Left whole too: a compressed-latent mixer (``b.cca``: its
+    four projections, both convolutions and ``tau``; the attention in
+    the latent is still split by head where ``model`` divides both head
+    counts, as any local attention is), a carried router (``b.router``)
+    and a block's joining rows (``scale1``, ``scale2``).
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -63,8 +67,10 @@ def shard_params(model: TransformerLM, mesh) -> TransformerLM:
             # routed experts stay whole on every device (their layer
             # shard_maps its tokens over `data`): the exchange that
             # expert parallelism needs is not written yet (ROADMAP C8);
-            # b.ssm stays whole too: the zero-width wq..wo above are its
-            # placeholders and nothing of the mixer is split over `model`
+            # b.ssm and b.cca stay whole too: the zero-width wq..wo
+            # above are their placeholders and nothing of either mixer's
+            # weights is split over `model`; so do b.router and the rows
+            # of scale1 / scale2
         )
         for b in model.blocks
     )

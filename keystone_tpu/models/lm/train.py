@@ -642,6 +642,7 @@ def train(
             tokens_per_step=batch * seq,
             chips=mesh.size if mesh is not None else 1,
             ssm_layers=ssm_layers(model),
+            cca_layers=cca_layers(model),
         )
         if _spans.current() is None
         else _contextlib.nullcontext()
@@ -915,6 +916,12 @@ def _layer_identity(model: TransformerLM, blk) -> list:
     if blk.ssm is not None:
         x = blk.ssm
         return ["ssm", x.heads, x.head_dim, x.state, x.groups, x.chunk]
+    if blk.cca is not None:
+        x = blk.cca
+        return [
+            "cca", x.heads, x.kv_heads, x.head_dim, repr(spec.rope),
+            [m.num_experts, m.held, m.first_expert, m.top_k, m.renormalize],
+        ]
     return [
         spec.num_heads,
         spec.num_kv_heads,
@@ -933,21 +940,40 @@ def ssm_layers(model: TransformerLM) -> int:
     return sum(b.ssm is not None for b in model.blocks)
 
 
+def cca_layers(model: TransformerLM) -> int:
+    """How many of the model's layers attend in a compressed latent."""
+    return sum(b.cca is not None for b in model.blocks)
+
+
 def _record_counters(model: TransformerLM, stats: list) -> None:
-    """The expert and state-space layers' counters of this fit as one
-    zero-length ``fit.counters`` span under the open one, read from the
-    device once, and only while spans are recorded and the model routes
-    or scans. A model without experts leaves their counters at 0, and
-    one without state-space layers theirs."""
+    """The expert, state-space and compressed-latent layers' counters of
+    this fit as one zero-length ``fit.counters`` span under the open
+    one, read from the device once, and only while spans are recorded
+    and the model routes, scans or mixes. A model without experts leaves
+    their counters at 0, and one without state-space layers theirs;
+    ``cca_rows`` and ``router_gate_mean`` (the mean gate of the rows
+    routed to held experts, where the gates are not renormalised) are
+    there only for a model that counts them."""
     from keystone_tpu.observe import spans as _spans
     from keystone_tpu.ops.ssm import COUNTERS as SSM_COUNTERS
 
     slots = sum(b.moe.held for b in model.blocks if b.moe is not None)
     sl = _spans.active_span_log()
-    if sl is None or not (slots or ssm_layers(model)) or not stats:
+    if (
+        sl is None
+        or not (slots or ssm_layers(model) or cca_layers(model))
+        or not stats
+    ):
         return
     got = jax.device_get([s.counters for s in stats])
     routed = [int(c["routed_rows"]) for c in got]
+    more = {}
+    if "cca_rows" in got[0]:
+        more["cca_rows"] = sum(int(c["cca_rows"]) for c in got)
+    if "gate_sum" in got[0]:
+        more["router_gate_mean"] = float(
+            sum(float(c["gate_sum"]) for c in got) / max(sum(routed), 1)
+        )
     sl.record_span(
         "fit.counters",
         wall_s=0.0,
@@ -958,6 +984,7 @@ def _record_counters(model: TransformerLM, stats: list) -> None:
         # positions scanned, chunks run and positions scanned by the
         # kernel, summed over state-space layers
         **{name: sum(int(c.get(name, 0)) for c in got) for name in SSM_COUNTERS},
+        **more,
         # largest load of a held expert over the mean load, a step
         load_max_over_mean=float(
             np.mean(

@@ -47,7 +47,11 @@ from keystone_tpu.models.lm.decode import _filter_logits  # noqa: F401
 from keystone_tpu.models.lm.model import (  # noqa: F401
     has_quantized_leaves as _has_quantized_leaves,
 )
-from keystone_tpu.models.lm.train import _step_batch, ssm_layers  # noqa: F401
+from keystone_tpu.models.lm.train import (  # noqa: F401
+    _step_batch,
+    cca_layers,
+    ssm_layers,
+)
 
 logger = get_logger("keystone_tpu.models.lm_transformer")
 
@@ -198,6 +202,7 @@ def fit(conf: LMConfig, mesh=None, history: dict | None = None):
         chips=mesh.size if mesh is not None else 1,
         # known when the span closes: the model is made inside it
         ssm_layers=lambda: found.get("ssm_layers", 0),
+        cca_layers=lambda: found.get("cca_layers", 0),
     ):
         valid = None
         with span("fit.init"):
@@ -211,6 +216,7 @@ def fit(conf: LMConfig, mesh=None, history: dict | None = None):
                 conf = dataclasses.replace(conf, vocab=BYTE_VOCAB)
             model = build_model(conf, mesh)
             found["ssm_layers"] = ssm_layers(model)
+            found["cca_layers"] = cca_layers(model)
             if not conf.corpus:
                 corpus = synthetic_corpus(
                     200_000, model.embed.shape[0], seed=conf.seed

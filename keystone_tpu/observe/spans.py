@@ -554,6 +554,17 @@ def _render_node(node: dict, depth: int, lines: list[str]) -> None:
     ):
         if key in rec:
             extras.append(f"{key}={rec[key]}")
+    # what an LM fit says of its layers and its step's counters, where
+    # the model has such layers
+    for key in (
+        "ssm_layers", "cca_layers", "routed_rows", "mm_rows",
+        "load_max_over_mean", "router_gate_mean", "ssm_rows", "cca_rows",
+    ):
+        if rec.get(key):
+            value = rec[key]
+            extras.append(
+                f"{key}={value:.4g}" if isinstance(value, float) else f"{key}={value}"
+            )
     tag = f"  [{', '.join(extras)}]" if extras else ""
     lines.append(
         f"{'  ' * depth}{rec.get('name', '?'):{max(34 - 2 * depth, 8)}} "

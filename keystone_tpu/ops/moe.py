@@ -7,9 +7,18 @@ solver's one-class-per-partition solves,
 ``ops/weighted_linear.py``). This layer is what a sparse LM block calls
 in place of its dense FFN:
 
-- the router scores every token against **every** expert of the model
-  (``w_router`` keeps the published width) and keeps the ``top_k``
-  largest, renormalised and scaled by ``routed_scale``;
+- every token is scored against **every** expert of the model and the
+  ``top_k`` largest are kept. The scores are the layer's own (one matrix
+  ``w_router`` at the published width, then a softmax or a sigmoid) or
+  the caller's: ``__call__(x, mesh, scores=(p, select))`` takes the
+  probabilities ``p`` and what the choice is made by, ``select`` (``p``
+  plus a balancing bias, say), from a router that lives outside the
+  layer, such as :class:`CarriedRouter`, an MLP fed by a state carried
+  from layer to layer. The kept weights are renormalised to sum to one
+  (``renormalize``, the default) or left as the chosen probabilities
+  themselves (with one expert a token renormalising would make every
+  gate 1 and cut the router off from the loss), then scaled by
+  ``routed_scale``;
 - the layer holds a contiguous share of the experts,
   ``first_expert .. first_expert + held``: one chip's share of an
   expert-parallel deployment, or all of them. It computes the part of
@@ -27,7 +36,9 @@ in place of its dense FFN:
 
 ``__call__`` also returns three counters of the step program: the rows
 routed to held experts, the largest load of a held expert, and the rows
-the grouped product ran over (whole row tiles).
+the grouped product ran over (whole row tiles); a layer that does not
+renormalise adds ``gate_sum``, the sum of its gates over the rows routed
+to held experts.
 """
 
 from __future__ import annotations
@@ -140,10 +151,13 @@ class MoELayer:
     shared_w3: jnp.ndarray | None = None
     top_k: int = static_field(default=2)
     first_expert: int = static_field(default=0)
-    # "softmax" over all experts, or "sigmoid" of each score; either way
-    # the top_k kept are renormalised to sum to one
+    # "softmax" over all experts, or "sigmoid" of each score (the
+    # layer's own scores; a caller's come as they are)
     scoring: str = static_field(default="softmax")
     routed_scale: float = static_field(default=1.0)
+    # the top_k kept are renormalised to sum to one, or stay the chosen
+    # probabilities themselves
+    renormalize: bool = static_field(default=True)
 
     @property
     def num_experts(self) -> int:
@@ -158,7 +172,7 @@ class MoELayer:
                held: int | None = None, first_expert: int = 0,
                top_k: int = 2, swiglu: bool = False, shared_ff: int = 0,
                scoring: str = "softmax", routed_scale: float = 1.0,
-               router_std: float = 0.02) -> "MoELayer":
+               router_std: float = 0.02, renormalize: bool = True) -> "MoELayer":
         held = num_experts if held is None else held
         if not 0 <= first_expert <= num_experts - held:
             raise ValueError(
@@ -188,37 +202,50 @@ class MoELayer:
             first_expert=first_expert,
             scoring=scoring,
             routed_scale=routed_scale,
+            renormalize=renormalize,
         )
 
-    def route(self, xf):
+    def route(self, xf, scores=()):
         """(weights (T, k) f32, expert ids (T, k)) of every token: f32
-        throughout, the sums are cheap and the ordering is sensitive."""
-        logits = xf.astype(jnp.float32) @ self.w_router.astype(jnp.float32)
-        scores = (
-            jax.nn.sigmoid(logits)
-            if self.scoring == "sigmoid"
-            else jax.nn.softmax(logits, axis=-1)
-        )
-        top, idx = jax.lax.top_k(scores, self.top_k)
-        weights = top / jnp.sum(top, axis=-1, keepdims=True)
-        return weights * self.routed_scale, idx
+        throughout, the sums are cheap and the ordering is sensitive.
+        ``scores``: the caller's ``(p, select)``, each (T, E) f32, in
+        place of the layer's own matrix: the ``top_k`` largest of
+        ``select`` are chosen and weighted by their ``p``."""
+        if not scores:
+            logits = xf.astype(jnp.float32) @ self.w_router.astype(jnp.float32)
+            scores = (
+                jax.nn.sigmoid(logits)
+                if self.scoring == "sigmoid"
+                else jax.nn.softmax(logits, axis=-1)
+            )
+            top, idx = jax.lax.top_k(scores, self.top_k)
+        else:
+            p, select = scores
+            _, idx = jax.lax.top_k(select, self.top_k)
+            top = jnp.take_along_axis(p, idx, axis=-1)
+        if self.renormalize:
+            top = top / jnp.sum(top, axis=-1, keepdims=True)
+        return top * self.routed_scale, idx
 
-    def __call__(self, x, mesh=None):
+    def __call__(self, x, mesh=None, scores=None):
         """``mesh``: the mesh the caller's arrays are split over, if
         any. GSPMD cannot partition a Mosaic kernel, so under a mesh of
         more than one device the routed part runs in a ``shard_map``:
         the batch split over ``data`` (whole where ``data`` does not
         divide it), every device routing its own tokens through the
-        experts, which it holds whole."""
+        experts, which it holds whole. ``scores``: ``(p, select)``, each
+        (B, S, E) f32, from a router outside the layer (see
+        :meth:`route`); None = the layer's own ``w_router``."""
+        scores = tuple(scores or ())
         if mesh is not None and mesh.size > 1:
             from jax.sharding import PartitionSpec as P
 
             n_data = mesh.shape.get("data", 1)
             axis = "data" if n_data > 1 and x.shape[0] % n_data == 0 else None
             routed = jax.shard_map(
-                lambda m, xs: m._routed(xs, axis),
+                lambda m, xs, *sc: m._routed(xs, axis, sc),
                 mesh=mesh,
-                in_specs=(P(), P(axis)),
+                in_specs=(P(), P(axis)) + (P(axis),) * len(scores),
                 out_specs=(P(axis), P()),
                 check_vma=False,  # pallas_call outputs carry no vma
             )
@@ -228,9 +255,10 @@ class MoELayer:
                     self, shared_w1=None, shared_w2=None, shared_w3=None
                 ),
                 x,
+                *scores,
             )
         else:
-            out, counters = self._routed(x)
+            out, counters = self._routed(x, scores=scores)
         if self.shared_w1 is not None:
             with jax.named_scope("moe_shared_expert"):
                 out = out + ffn(
@@ -238,7 +266,7 @@ class MoELayer:
                 )
         return out, counters
 
-    def _routed(self, x, axis: str | None = None):
+    def _routed(self, x, axis: str | None = None, scores=()):
         """The held experts' part of the routed sum for these tokens,
         and the counters; under a ``shard_map`` that split the tokens
         over ``axis`` the counters are summed over it."""
@@ -246,8 +274,9 @@ class MoELayer:
         t, k = b * s, self.top_k
         xf = x.reshape(t, d)
         cdt = x.dtype
+        scores = tuple(sc.reshape(t, sc.shape[-1]) for sc in scores)
         with jax.named_scope("moe_router"):
-            weights, idx = self.route(xf)
+            weights, idx = self.route(xf, scores)
             flat = idx.reshape(t * k)
             order = jnp.argsort(flat, stable=True).astype(jnp.int32)
             inv = (
@@ -292,4 +321,77 @@ class MoELayer:
             "max_expert_rows": jnp.max(held),
             "mm_rows": mm_rows,
         }
+        if not self.renormalize:
+            # what the router gave the rows routed here (renormalised
+            # gates sum to the rows themselves and say nothing)
+            here = (idx >= first) & (idx < first + self.held)
+            gate_sum = jnp.sum(jnp.where(here, weights, 0.0))
+            counters["gate_sum"] = (
+                gate_sum if axis is None else jax.lax.psum(gate_sum, axis)
+            )
         return out.reshape(b, s, d), counters
+
+
+@treenode
+class CarriedRouter:
+    """A router outside the expert layer: an MLP fed by a state carried
+    from layer to layer. ``r = y w_down + b_down + gamma * r_prev`` is
+    this layer's state and goes on to the next layer's router;
+    ``p = softmax(w3 gelu(w2 gelu(w1 rms(r))))`` over every expert of
+    the model; the choice is made by ``p + beta``, a balancing bias that
+    no gradient reaches (an update rule between steps would move it;
+    none is applied here). float32 throughout at the highest matmul
+    precision: with one expert a token the choice is discrete."""
+
+    w_down: jnp.ndarray  # (d, R)
+    b_down: jnp.ndarray  # (R,)
+    gamma: jnp.ndarray  # (): the weight of the previous layer's state
+    norm: jnp.ndarray  # (R,)
+    w1: jnp.ndarray  # (R, R)
+    b1: jnp.ndarray
+    w2: jnp.ndarray  # (R, R)
+    b2: jnp.ndarray
+    w3: jnp.ndarray  # (R, E)
+    b3: jnp.ndarray
+    beta: jnp.ndarray  # (E,)
+    eps: float = static_field(default=1e-5)
+
+    @staticmethod
+    def create(key, d: int, hidden: int, num_experts: int, *,
+               gamma: float = 0.5, eps: float = 1e-5) -> "CarriedRouter":
+        """Seeded weights: matrices normal at 1/sqrt(fan_in), biases and
+        ``beta`` zero, the norm's scale 1, ``gamma`` as given."""
+        ks = jax.random.split(key, 4)
+
+        def init(k, shape):
+            return jax.random.normal(k, shape, jnp.float32) / math.sqrt(shape[0])
+
+        zeros = functools.partial(jnp.zeros, dtype=jnp.float32)
+        return CarriedRouter(
+            w_down=init(ks[0], (d, hidden)), b_down=zeros((hidden,)),
+            gamma=jnp.asarray(gamma, jnp.float32),
+            norm=jnp.ones((hidden,), jnp.float32),
+            w1=init(ks[1], (hidden, hidden)), b1=zeros((hidden,)),
+            w2=init(ks[2], (hidden, hidden)), b2=zeros((hidden,)),
+            w3=init(ks[3], (hidden, num_experts)), b3=zeros((num_experts,)),
+            beta=zeros((num_experts,)),
+            eps=eps,
+        )
+
+    def __call__(self, y, r_prev=None):
+        """y: (B, S, d); r_prev: (B, S, R) f32, or None before the first
+        such layer (a zero state). Returns ((p, select), r): the scores
+        for :meth:`MoELayer.__call__` and the state to carry on."""
+        f32 = jnp.float32
+        dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+        with jax.named_scope("moe_router_mlp"):
+            r = dot(y.astype(f32), self.w_down) + self.b_down
+            if r_prev is not None:
+                r = r + self.gamma * r_prev
+            z = r * jax.lax.rsqrt(jnp.mean(r * r, axis=-1, keepdims=True) + self.eps)
+            z = z * self.norm
+            z = jax.nn.gelu(dot(z, self.w1) + self.b1, approximate=False)
+            z = jax.nn.gelu(dot(z, self.w2) + self.b2, approximate=False)
+            p = jax.nn.softmax(dot(z, self.w3) + self.b3, axis=-1)
+            select = p + jax.lax.stop_gradient(self.beta)
+        return (p, select), r
