@@ -84,38 +84,46 @@ def enable_compilation_cache() -> str | None:
 def init_backend() -> dict:
     """Bring the backend up NOW under the platform rule and log what it
     is: one ``device {json}`` line (platform, device_kind, count, compile
-    cache) that a supervising process can read from outside, and one
-    ``compile {json}`` line at exit (backend compile seconds, persistent
-    cache hits and misses). Raises the backend's own error when the
-    selected platform cannot initialise. Idempotent."""
+    cache) that a supervising process can read from outside, one
+    ``startup {json}`` line when the process's first unit of work has
+    ended (where the time before it went: ``observe/spans.py``'s startup
+    period, which opens here), and one ``compile {json}`` line at exit
+    (backend compile seconds, persistent cache hits and misses). Raises
+    the backend's own error when the selected platform cannot
+    initialise. Idempotent."""
     global _backend
     if _backend is not None:
         return _backend
-    select_platform()
-    cache_dir = enable_compilation_cache()
-    import jax
+    from keystone_tpu.observe import spans
 
-    compiles = {"backend_compile_s": 0.0, "cache_hits": 0, "cache_misses": 0}
+    up: dict = {}  # what the spans say when they close: the backend, once up
+    process = spans.open_startup(
+        attrs=lambda: {
+            "platform": up.get("platform"),
+            "chips": up.get("count"),
+            "compile_cache": up.get("compile_cache"),
+        },
+        report=lambda summary: logger.info("startup %s", json.dumps(summary)),
+    )
+    with spans.span(
+        "runtime.init_backend",
+        parent=process,
+        platform=lambda: up.get("platform"),
+        device_kind=lambda: up.get("device_kind"),
+        count=lambda: up.get("count"),
+    ):
+        select_platform()
+        cache_dir = enable_compilation_cache()
+        import jax
 
-    def on_duration(event: str, duration: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            compiles["backend_compile_s"] += duration
-
-    def on_event(event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            compiles["cache_hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            compiles["cache_misses"] += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
-    devs = jax.devices()
-    _backend = {
-        "platform": devs[0].platform,
-        "device_kind": devs[0].device_kind,
-        "count": len(devs),
-        "compile_cache": cache_dir,
-    }
+        devs = jax.devices()
+        up.update(
+            platform=devs[0].platform,
+            device_kind=devs[0].device_kind,
+            count=len(devs),
+            compile_cache=cache_dir,
+        )
+    _backend = up
     logger.info("device %s", json.dumps(_backend))
 
     def report_compiles() -> None:
@@ -124,7 +132,7 @@ def init_backend() -> dict:
         logging.raiseExceptions = False
         logger.info(
             "compile %s",
-            json.dumps({k: round(v, 3) for k, v in compiles.items()}),
+            json.dumps({k: round(v, 3) for k, v in spans.compile_counts().items()}),
         )
 
     atexit.register(report_compiles)

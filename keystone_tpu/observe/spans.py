@@ -19,8 +19,33 @@ memory-only :class:`SpanLog` that :func:`profiled_spans` still returns
 after the session stops; a new session starts a new list (the profiler
 has no public session id, so "new" means: seen on after it was seen off
 — see :func:`profiled_spans`). With neither, :func:`active_span_log` /
-:func:`span` cost two reads (the event sink,
-``TraceAnnotation.is_enabled()``), build nothing and yield None.
+:func:`span` cost three reads (the event sink,
+``TraceAnnotation.is_enabled()``, the startup period), build nothing and
+yield None.
+
+The startup period is the third, bounded, activation: what a process
+does before its first window. It opens when
+``core/runtime.py::init_backend`` is first entered (:func:`open_startup`;
+a process that never calls it, as a test process, sees none of this) and
+closes, for good, at the end of the process's first unit of work: the
+first parentless :func:`span` named in :data:`_STARTUP_UNITS` (a ``fit``
+root, a served request). Two bounds close it earlier, whichever is
+first: a unit that is still stepping after :data:`_STARTUP_STEPS`
+``train.step`` is a training run and no warm-up (``closed_by``
+``steps``: a trainer's steady steps are neither recorded nor waited
+for), and a period holds at most :data:`_MAX_STARTUP_SPANS` records
+(``records``). While it is open and neither a sink nor a session is on,
+:func:`active_span_log` returns its memory-only :class:`SpanLog`, so the
+first fit is recorded as a traced fit is; every span that asked for no
+parent (a ``plan.segment`` before the fit closes nothing), and a
+``jit.*`` record made outside any span, parent on the ``process`` root,
+whose ids are made when the period opens and which is emitted when it
+closes: ``t0_ns`` back-dated to the process's start
+(``t0_source`` ``proc``: ``/proc/self/stat`` and the boot clock; else
+``import``: when this module was loaded), ``t1_ns`` the end of that
+first unit of work. :func:`startup_spans` returns the records,
+:func:`startup_summary` reduces them to the one ``startup {json}`` line
+``init_backend`` logs when the period closes.
 
 The shared clock: while a profiler session is on, a live :func:`span`
 also enters ``jax.profiler.TraceAnnotation(name, span=, parent=,
@@ -32,13 +57,16 @@ stats. Host spans and device ops are then on one timeline
 both hold.
 
 Which call recompiled: the first time spans come on, one
-``jax.monitoring`` listener is registered. While spans are on, every
-jaxpr trace, lowering and backend compile becomes a ``jit.trace`` /
-``jit.lower`` / ``jit.backend_compile`` child (attr ``fun``) of whatever
-span is ambient; a backend compile that the persistent cache answered
-is recorded as ``jit.cache_read`` instead (jax times the cache read
-inside the backend-compile bracket: one request, one span). Outside any
-span nothing is recorded: a child needs a parent.
+``jax.monitoring`` duration listener is registered (the program's only
+one: it also keeps the counts behind the ``compile {json}`` exit line,
+:func:`compile_counts`). While spans are on, every jaxpr trace, lowering
+and backend compile becomes a ``jit.trace`` / ``jit.lower`` /
+``jit.backend_compile`` child (attr ``fun``) of whatever span is
+ambient; a backend compile that the persistent cache answered is
+recorded as ``jit.cache_read`` instead (jax times the cache read inside
+the backend-compile bracket: one request, one span). Outside any span
+nothing is recorded, a child needs a parent, except while the startup
+period is open: ``process`` is the parent then.
 
 Span record schema (one JSON object per line; extra fields free-form):
 
@@ -100,6 +128,10 @@ from jax.profiler import TraceAnnotation as _TraceAnnotation
 from keystone_tpu.observe import events as _events
 
 SPANS_FILE = "spans.jsonl"
+
+# when this module was loaded, on the span clock: what a startup record
+# dates the process from where /proc cannot say
+_T_IMPORT_NS = time.perf_counter_ns()
 
 #: the goodput taxonomy — every classified span names one of these
 BUCKETS = (
@@ -163,8 +195,10 @@ def force(x):
     """``jax.block_until_ready(x)`` when, and only when, a span is being
     recorded around the caller; returns ``x``. A phase boundary calls it
     on the phase's outputs so that a recorded span ends when its device
-    work has, while an untraced run keeps its asynchronous dispatch."""
-    if _current.get() is not None:
+    work has, while an untraced run keeps its asynchronous dispatch. A
+    span that outlives what recorded it (the ``fit`` root of a trainer
+    whose startup period a bound closed) waits for nothing more."""
+    if _current.get() is not None and active_span_log() is not None:
         jax.block_until_ready(x)
     return x
 
@@ -177,11 +211,14 @@ class SpanLog:
     failure degrades with one warning, same rule as the event log.
     """
 
-    def __init__(self, run_dir: str | None = None, run_id: str | None = None):
+    def __init__(
+        self,
+        run_dir: str | None = None,
+        run_id: str | None = None,
+        max_records: int = _MAX_MEMORY_SPANS,
+    ):
         self.run_id = run_id
-        self.records: collections.deque = collections.deque(
-            maxlen=_MAX_MEMORY_SPANS
-        )
+        self.records: collections.deque = collections.deque(maxlen=max_records)
         self._lock = threading.Lock()
         self._sink: _events.JsonlSink | None = None
         if run_dir:
@@ -300,18 +337,168 @@ def profiled_spans() -> list[dict]:
     return list(sl.records) if sl is not None else []
 
 
+# ------------------------------------------------------ the startup period
+
+# what a unit of work is: the first span of these names under no other
+# span of this process closes the period when it ends. Any other
+# parentless span of the period (``plan.segment``, ``serve.stream``,
+# ``fleet.request`` ...) is a child of ``process`` and closes nothing
+_STARTUP_UNITS = frozenset({"fit", "serve.request"})
+# a unit still stepping after this many ``train.step`` is a training run
+# (its ``fit`` root is the whole of it) and no warm-up: the period closes
+# at that step's end, and the steps after it are neither recorded nor
+# waited for (:func:`force`). A cell's warm-up fit makes 8
+_STARTUP_STEPS = 16
+# a period that holds this many records closes. A first fit of the LM
+# makes 3 000 to 9 000: jax times every inner jitted function it traces
+# inside the step, some 600 ``jit.trace`` a layer
+_MAX_STARTUP_SPANS = 32768
+
+
+class _Startup:
+    """One process's startup period: the ``process`` root's ids and
+    start, the memory-only log, and what ``init_backend`` gave to say of
+    the root (``attrs()``) and to hear when the period closes
+    (``report(summary)``)."""
+
+    def __init__(self, attrs, report):
+        # twice the bound: the spans in flight when it closes the period
+        # still end in this log, and push nothing out
+        self.log = SpanLog(max_records=2 * _MAX_STARTUP_SPANS)
+        self.ctx = make_context()
+        self.t0_ns, self.t0_source = _process_start_ns()
+        self.attrs, self.report = attrs, report
+        self.unit: str | None = None  # the span whose end closes the period
+        self.steps = 0  # `train.step` spans ended so far
+        # span id -> the record so far of a span that has not ended: what
+        # the summary says of a unit that a bound cut short
+        self.in_flight: dict[str, dict] = {}
+
+    def enter(
+        self, name: str, ctx: SpanContext, parent: SpanContext, t0_ns: int, unit: bool
+    ) -> None:
+        self.in_flight[ctx.span] = {
+            "name": name,
+            "trace": ctx.trace,
+            "span": ctx.span,
+            "parent": parent.span,
+            "t0_ns": t0_ns,
+        }
+        if unit:
+            with _bind_lock:
+                if self.unit is None:
+                    self.unit = ctx.span
+
+    def leave(self, name: str, ctx: SpanContext, t1_ns: int) -> None:
+        self.in_flight.pop(ctx.span, None)
+        if ctx.span == self.unit:
+            _close_startup(self, t1_ns, "unit")
+        elif name == "train.step":
+            with _bind_lock:
+                self.steps += 1
+                enough = self.steps >= _STARTUP_STEPS
+            if enough:
+                _close_startup(self, t1_ns, "steps")
+
+
+# the process's period, kept for good once opened (startup_spans), and
+# the same object while it is open: the one read a steady span() adds
+_startup: _Startup | None = None
+_startup_open: _Startup | None = None
+
+
+def _process_start_ns() -> tuple[int, str]:
+    """When this process started, on ``time.perf_counter_ns()``, and how
+    that is known: ``proc`` (field 22 of ``/proc/self/stat``, ticks
+    since boot, against the boot clock now) or ``import`` (when this
+    module was loaded)."""
+    now = time.perf_counter_ns()
+    try:
+        with open("/proc/self/stat") as f:
+            # the fields after the command, which may hold spaces
+            ticks = int(f.read().rpartition(")")[2].split()[19])
+        age = time.clock_gettime_ns(time.CLOCK_BOOTTIME) - (
+            ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+        )
+        if age >= now - _T_IMPORT_NS:  # a start after the import is no start
+            return now - age, "proc"
+    except (OSError, ValueError, IndexError, AttributeError):
+        pass
+    return _T_IMPORT_NS, "import"
+
+
+def open_startup(attrs, report) -> SpanContext:
+    """Open the startup period (module docstring) and return the
+    ``process`` root's context; a later call returns the same context
+    and opens nothing. ``attrs()`` gives the root's attributes when the
+    period closes, ``report(summary)`` hears :func:`startup_summary` of
+    its records then. ``core/runtime.py::init_backend`` is the caller."""
+    global _startup, _startup_open
+    with _bind_lock:
+        if _startup is None:
+            _startup = _startup_open = _Startup(attrs, report)
+            _listen_for_compiles()
+    return _startup.ctx
+
+
+def _close_startup(st: _Startup, end_ns: int, closed_by: str) -> None:
+    """Close the period, once: emit the ``process`` root into the log
+    that is in use and report the summary of what lies under it."""
+    global _startup_open
+    with _bind_lock:
+        if _startup_open is not st:
+            return
+        _startup_open = None
+    sl = active_span_log() or st.log
+    sl.record_span(
+        "process",
+        wall_s=(end_ns - st.t0_ns) / 1e9,
+        end_ns=end_ns,
+        ctx=st.ctx,
+        t0_source=st.t0_source,
+        closed_by=closed_by,
+        **st.attrs(),
+    )
+    # with a sink or a session on, part of the period lies in its log
+    recs = list(st.log.records)
+    if sl is not st.log:
+        recs += list(sl.records)
+    # what a bound cut short (the unit, its phase) counts up to here; it
+    # records itself when it ends
+    recs += [{**r, "t1_ns": end_ns} for r in list(st.in_flight.values())]
+    st.report(startup_summary(recs))
+
+
+def startup_spans() -> list[dict]:
+    """The records of the startup period, during it and after it closed
+    ([] if ``init_backend`` never ran, or for what went to an active
+    sink's ``spans.jsonl`` or a profiler session's list instead). The
+    one way into them from outside this module, beside
+    :func:`profiled_spans` (the benchmark's ``setup_*`` readers use it)."""
+    st = _startup
+    return list(st.log.records) if st is not None else []
+
+
 def active_span_log() -> SpanLog | None:
     """The :class:`SpanLog` riding the active event sink, else the one
-    of the profiler session that is on, else None.
+    of the profiler session that is on, else the startup period's while
+    it is open, else None.
 
-    The ONLY check the hot paths make: with neither this is one global
-    read (``events.active()``) and one static call
-    (``TraceAnnotation.is_enabled()``) and constructs nothing — the
-    same overhead contract as
-    :func:`keystone_tpu.observe.telemetry.active_step_log`."""
+    The ONLY check the hot paths make: with none of them this is one
+    global read (``events.active()``), one static call
+    (``TraceAnnotation.is_enabled()``) and one more global read (the
+    startup period) and constructs nothing — the same overhead contract
+    as :func:`keystone_tpu.observe.telemetry.active_step_log`."""
     log = _events.active()
     if log is None:
-        return _profiler_span_log()
+        sl = _profiler_span_log()
+        if sl is None:
+            st = _startup_open
+            if st is not None:
+                if len(st.log.records) < _MAX_STARTUP_SPANS:
+                    return st.log
+                _close_startup(st, time.perf_counter_ns(), "records")
+        return sl
     sl = log.__dict__.get("_spanlog")
     if sl is None:
         with _bind_lock:
@@ -325,27 +512,53 @@ def active_span_log() -> SpanLog | None:
 
 # ------------------------------------------------- which call recompiled
 
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _JIT_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
-    "/jax/core/compile/backend_compile_duration": "jit.backend_compile",
+    _BACKEND_COMPILE_EVENT: "jit.backend_compile",
 }
 # emitted on a persistent-cache hit only, inside the backend-compile
 # bracket of the same request and before that bracket's own event
 _CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_COUNT_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
 _listening = False
 _cache_hit = threading.local()
+# counted from the first time the listeners are on, spans or no spans
+_compiles = {"backend_compile_s": 0.0, "cache_hits": 0, "cache_misses": 0}
+
+
+def compile_counts() -> dict:
+    """Backend compile seconds and persistent-cache hits and misses of
+    this process since its listeners were registered (``init_backend``
+    registers them): the ``compile {json}`` exit line."""
+    return dict(_compiles)
+
+
+def _on_jit_event(event: str, **_kw: Any) -> None:
+    key = _CACHE_COUNT_EVENTS.get(event)
+    if key is not None:
+        _compiles[key] += 1
 
 
 def _on_jit_duration(event: str, duration: float, **kw: Any) -> None:
-    """The ``jax.monitoring`` duration listener: inside a recorded span,
-    one post-hoc child span per compile step (start = now - duration);
-    nothing (one context read) outside one."""
+    """The ``jax.monitoring`` duration listener: counts backend compile
+    seconds always; inside a recorded span, or under ``process`` while
+    the startup period is open, one post-hoc child span per compile step
+    (start = now - duration); else nothing more (two reads)."""
     if event != _CACHE_READ_EVENT and event not in _JIT_EVENTS:
         return
+    if event == _BACKEND_COMPILE_EVENT:
+        _compiles["backend_compile_s"] += duration
     parent = _current.get()
-    if parent is None:  # spans off, or no span to parent on
-        return
+    if parent is None:
+        st = _startup_open
+        if st is None:  # spans off, or no span to parent on
+            return
+        parent = st.ctx
     sl = active_span_log()
     if sl is None:
         return
@@ -360,12 +573,13 @@ def _on_jit_duration(event: str, duration: float, **kw: Any) -> None:
 
 
 def _listen_for_compiles() -> None:
-    """Register the compile listener, once per process (jax keeps
+    """Register the compile listeners, once per process (jax keeps
     listeners for good, hence the gate inside the callback)."""
     global _listening
     if not _listening:
         _listening = True
         jax.monitoring.register_event_duration_secs_listener(_on_jit_duration)
+        jax.monitoring.register_event_listener(_on_jit_event)
 
 
 @contextlib.contextmanager
@@ -387,7 +601,8 @@ def span(
 
     While a profiler session is on the block is also bracketed by a
     ``TraceAnnotation`` of the same name (module docstring). With no
-    sink and no session this yields None after two reads — pass ``log=``
+    sink, no session and no startup period this yields None after three
+    reads — pass ``log=``
     (a :class:`SpanLog` or None) to skip even those when the caller
     already looked it up once for a whole batch/stream.
     """
@@ -395,7 +610,15 @@ def span(
     if sl is None:
         yield None
         return
-    pctx = _current.get() if parent is _UNSET else parent
+    ambient = _current.get()
+    pctx = ambient if parent is _UNSET else parent
+    st = _startup_open
+    if st is not None:
+        # a unit of work: asked for no parent, or brought one from
+        # another process (a request behind the fleet's router)
+        unit = name in _STARTUP_UNITS and (pctx is None or ambient is None)
+        if pctx is None:
+            pctx = st.ctx  # no parent, in the startup period: `process`
     ctx = make_context(pctx, trace)
     token = _current.set(ctx)
     twin = None
@@ -407,6 +630,8 @@ def span(
         twin = _TraceAnnotation(name, **ids)
         twin.__enter__()
     t0 = time.perf_counter_ns()
+    if st is not None:
+        st.enter(name, ctx, pctx, t0, unit)
     status = None
     try:
         yield ctx
@@ -428,6 +653,8 @@ def span(
             status=status,
             **{k: v() if callable(v) else v for k, v in attrs.items()},
         )
+        if st is not None:
+            st.leave(name, ctx, t1)
 
 
 # --------------------------------------------------------------- analysis
@@ -535,6 +762,99 @@ def goodput_summary(spans: list[dict]) -> dict[str, Any]:
         "traces": len(trees),
         "spans": len(spans),
         "critical_path_s": round(cp, 6),
+    }
+
+
+def self_ns(recs: list[dict], root: dict) -> dict[str, int]:
+    """span id -> nanoseconds of ``root``'s wall that belong to that
+    span, for ``root`` and every record that leads to it by its parents:
+    every instant of ``[root.t0_ns, root.t1_ns]`` goes to the innermost
+    span over it (deepest under ``root``, then the one that started
+    last), so a span keeps its wall less what its children cover, an
+    inner ``jit.trace`` is not counted again in the outer one that holds
+    it, and the values add up to the root's wall, to the nanosecond. The
+    one sweep behind :func:`startup_summary` and the benchmark's
+    ``setup_*`` readers."""
+    by_id = {r["span"]: r for r in recs if "t0_ns" in r}
+    by_id[root["span"]] = root
+
+    def depth_of(sid: str) -> int | None:
+        """Steps up to the root; None where the parents do not lead there."""
+        seen: set[str] = set()
+        while sid != root["span"]:
+            if sid not in by_id or sid in seen:
+                return None
+            seen.add(sid)
+            sid = by_id[sid].get("parent")
+        return len(seen)
+
+    depth = {sid: d for sid in by_id if (d := depth_of(sid)) is not None}
+    lo, hi = root["t0_ns"], root["t1_ns"]
+    edges = []
+    for sid in depth:
+        r = by_id[sid]
+        t0, t1 = min(max(r["t0_ns"], lo), hi), min(max(r["t1_ns"], lo), hi)
+        if t1 > t0:
+            edges += [(t0, 1, sid), (t1, 0, sid)]
+    edges.sort()
+    out = dict.fromkeys(depth, 0)
+    over: set[str] = set()
+    at = lo
+    for t, opens, sid in edges:
+        if t > at:
+            inner = max(over, key=lambda i: (depth[i], by_id[i]["t0_ns"]))
+            out[inner] += t - at
+            at = t
+        (over.add if opens else over.discard)(sid)
+    return out
+
+
+def startup_summary(recs: list[dict]) -> dict | None:
+    """The ``startup {json}`` line of a closed startup period: seconds
+    from the process's start to the backend's (``import_s``), in
+    ``init_backend`` (``backend_s``), tracing, lowering, reading the
+    compile cache and compiling (self time of every ``jit.*`` record
+    under ``process``), in the first unit of work less those
+    (``first_run_s``), the programs made (cache reads + compiles), the
+    root's wall, and the three ``fun`` with the most ``jit.*`` time.
+    None without a ``process`` root."""
+    roots = [r for r in recs if r.get("name") == "process" and "parent" not in r]
+    if not roots:
+        return None
+    root = roots[-1]
+    mine = {sid: ns / 1e9 for sid, ns in self_ns(recs, root).items()}
+    by_id = {r["span"]: r for r in recs if r.get("span") in mine}
+    parts = {
+        "runtime.init_backend": "backend_s",
+        "jit.trace": "trace_s",
+        "jit.lower": "lower_s",
+        "jit.cache_read": "cache_read_s",
+        "jit.backend_compile": "compile_s",
+    }
+    out = dict.fromkeys(("import_s", *parts.values(), "first_run_s"), 0.0)
+    funs: dict[str, float] = {}
+    for sid, s in mine.items():
+        r = by_id[sid]
+        if r is root:
+            continue
+        out[parts.get(r["name"], "first_run_s")] += s
+        if r["name"].startswith("jit."):
+            fun = str(r.get("fun") or "?")
+            if fun.startswith("jit(") and fun.endswith(")"):
+                fun = fun[4:-1]  # the compile's name for what was traced
+            funs[fun] = funs.get(fun, 0.0) + s
+    backend = [r["t0_ns"] for r in by_id.values() if r["name"] == "runtime.init_backend"]
+    if backend:
+        out["import_s"] = (min(backend) - root["t0_ns"]) / 1e9
+    top = sorted(funs.items(), key=lambda kv: -kv[1])[:3]
+    return {
+        **{k: round(v, 3) for k, v in out.items()},
+        "programs": sum(
+            r["name"] in ("jit.cache_read", "jit.backend_compile")
+            for r in by_id.values()
+        ),
+        "total_s": round((root["t1_ns"] - root["t0_ns"]) / 1e9, 3),
+        "top": [{"fun": f, "s": round(s, 3)} for f, s in top],
     }
 
 

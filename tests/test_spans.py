@@ -1110,3 +1110,368 @@ def test_observe_idle_cli_on_a_cpu_trace_says_it_has_no_device_plane(
     with pytest.raises(SystemExit) as e:
         report.main(["--help"])
     assert "observe idle <profile-dir>" in str(e.value)
+
+
+# ---------------------------------------------------------------------------
+# the startup period (PR 38): from init_backend to the end of the first fit
+
+
+@pytest.fixture
+def no_startup(monkeypatch):
+    """A process whose startup period has not opened yet (and whatever a
+    test opens is gone after it)."""
+    monkeypatch.setattr(spans_mod, "_startup", None)
+    monkeypatch.setattr(spans_mod, "_startup_open", None)
+    assert events.active() is None
+
+
+def _fresh_jit(tag):
+    import jax
+
+    def plus(x):
+        return x * 3.0 + tag
+    plus.__name__ = f"startup_plus_{tag}"
+    return jax.jit(plus)
+
+
+def test_the_startup_period_opens_when_asked_and_not_at_import(no_startup):
+    assert spans_mod.active_span_log() is None and spans_mod.startup_spans() == []
+    with spans_mod.span("before.the.period") as ctx:
+        assert ctx is None
+    said = []
+    process = spans_mod.open_startup(attrs=dict, report=said.append)
+    sl = spans_mod.active_span_log()
+    assert isinstance(sl, spans_mod.SpanLog) and sl._sink is None  # memory only
+    # a second call opens nothing and hands back the same root
+    assert spans_mod.open_startup(attrs=dict, report=said.append) == process
+    assert spans_mod.active_span_log() is sl
+    # a span with a parent of its own is no unit of work: the period stays open
+    with spans_mod.span("runtime.init_backend", parent=process):
+        pass
+    assert spans_mod.active_span_log() is sl and said == []
+    assert [r["name"] for r in spans_mod.startup_spans()] == ["runtime.init_backend"]
+
+
+def test_a_first_fit_in_the_startup_period_is_one_tree_under_process(no_startup, monkeypatch):
+    import jax.numpy as jnp
+
+    said = []
+    process = spans_mod.open_startup(
+        attrs=lambda: {"platform": "cpu", "chips": 8, "compile_cache": None},
+        report=said.append)
+    with spans_mod.span("runtime.init_backend", parent=process, platform="cpu"):
+        pass
+    x = jnp.ones(3)
+    _fresh_jit(101)(x)  # outside any span: a child of `process`
+    assert spans_mod.force(5) == 5  # no span around it: nothing to wait for
+    with spans_mod.span("fit", parent=None, steps=1) as fit_ctx:
+        assert fit_ctx.trace == process.trace
+        with spans_mod.span("fit.solve"):
+            _fresh_jit(102)(x)
+        with spans_mod.span("second.root", parent=None):
+            pass  # parentless while the unit is in flight: closes nothing
+        with spans_mod.span("fit", parent=None):
+            pass  # and so does a second fit inside the first
+        assert spans_mod.active_span_log() is not None and said == []
+    # closed, once, at the root's end
+    assert spans_mod.active_span_log() is None and len(said) == 1
+    recs = spans_mod.startup_spans()
+    by_id = {r["span"]: r for r in recs}
+    (root,) = [r for r in recs if r["name"] == "process"]
+    assert "parent" not in root and root["span"] == process.span
+    assert (root["platform"], root["chips"], root["closed_by"]) == ("cpu", 8, "unit")
+    assert root["t0_source"] == "proc" and "compile_cache" not in root
+    (backend,) = [r for r in recs if r["name"] == "runtime.init_backend"]
+    inner_fit, fit = [r for r in recs if r["name"] == "fit"]
+    (second,) = [r for r in recs if r["name"] == "second.root"]
+    assert backend["parent"] == fit["parent"] == second["parent"] == root["span"]
+    assert inner_fit["parent"] == root["span"] and fit["span"] == fit_ctx.span
+    assert root["t0_ns"] <= spans_mod._T_IMPORT_NS < backend["t0_ns"]
+    assert root["t1_ns"] == fit["t1_ns"]
+    jit = [r for r in recs if r["name"].startswith("jit.")]
+    outside = [r for r in jit if "startup_plus_101" in r["fun"]]
+    inside = [r for r in jit if "startup_plus_102" in r["fun"]]
+    assert {r["name"] for r in outside} == {r["name"] for r in inside} >= {
+        "jit.trace", "jit.lower"}
+    assert {r["parent"] for r in outside} == {root["span"]}
+    assert {by_id[r["parent"]]["name"] for r in inside} == {"fit.solve"}
+    assert {r["trace"] for r in recs} == {process.trace}
+    summary = said[0]
+    assert summary["total_s"] == pytest.approx(root["wall_s"], abs=1e-3)
+    assert summary["programs"] == sum(
+        r["name"] in ("jit.backend_compile", "jit.cache_read") for r in jit) >= 2
+    # after it: a second fit records nothing, span() builds nothing
+    monkeypatch.setattr(
+        spans_mod.SpanLog, "__init__",
+        lambda *a, **k: (_ for _ in ()).throw(AssertionError("built a SpanLog")))
+    with spans_mod.span("fit", parent=None) as ctx:
+        assert ctx is None
+        _fresh_jit(103)(x)
+    assert spans_mod.startup_spans() == recs and len(said) == 1
+
+
+def test_a_toy_fit_after_open_startup_keeps_its_own_spans(no_startup):
+    said = []
+    spans_mod.open_startup(attrs=dict, report=said.append)
+    _toy_fit()
+    recs = spans_mod.startup_spans()
+    by_id = {r["span"]: r for r in recs}
+    assert recs[-1]["name"] == "process" and len(said) == 1
+    (fit,) = [r for r in recs if r["name"] == "fit"]
+    assert fit["parent"] == recs[-1]["span"] and fit["blocks"] == 2
+    for name, parent in FIT_SPANS.items():
+        mine = [r for r in recs if r["name"] == name]
+        assert mine and {by_id[r["parent"]]["name"] for r in mine} == {parent}, name
+    n = len(recs)
+    _toy_fit()
+    assert len(spans_mod.startup_spans()) == n and spans_mod.active_span_log() is None
+
+
+@pytest.mark.parametrize("bound, closed_by", [
+    ("_MAX_STARTUP_SPANS", "records"), ("_STARTUP_STEPS", "steps")])
+def test_a_bound_closes_the_startup_period_in_mid_fit(no_startup, monkeypatch, bound, closed_by):
+    """A trainer's first `fit` is its whole run: after five steps' worth
+    the period closes inside the open root, the steps after it are
+    neither recorded nor waited for, and the summary counts the root and
+    the phase in flight up to there."""
+    monkeypatch.setattr(spans_mod, bound, 5)
+    waited = []
+    monkeypatch.setattr(spans_mod.jax, "block_until_ready", waited.append)
+    said = []
+    process = spans_mod.open_startup(attrs=dict, report=said.append)
+    with spans_mod.span("fit", parent=None):
+        time.sleep(0.003)
+        with spans_mod.span("fit.solve"):
+            for i in range(9):
+                with spans_mod.span("train.step", step=i):
+                    spans_mod.force(i)
+    recs = spans_mod.startup_spans()
+    # five steps, then the bound: the root, and what was in flight
+    assert [r["name"] for r in recs] == ["train.step"] * 5 + ["process", "fit.solve", "fit"]
+    assert recs[5]["closed_by"] == closed_by and recs[5]["span"] == process.span
+    assert recs[5]["t1_ns"] < recs[6]["t1_ns"] <= recs[7]["t1_ns"]
+    assert len(said) == 1 and spans_mod.active_span_log() is None
+    # force() waited in the recorded steps only (the records' bound is seen
+    # by the sixth step's own span(), the steps' bound at the fifth's end)
+    assert waited == [0, 1, 2, 3, 4]
+    # the root and its phase were in flight: counted up to the close
+    assert said[0]["first_run_s"] >= 0.003
+    assert said[0]["total_s"] == pytest.approx(recs[5]["wall_s"], abs=1e-3)
+
+
+def test_force_waits_only_while_what_is_around_it_is_being_recorded(no_startup, monkeypatch):
+    waited = []
+    monkeypatch.setattr(spans_mod.jax, "block_until_ready", waited.append)
+    spans_mod.force("no span")
+    spans_mod.open_startup(attrs=dict, report=lambda s: None)
+    spans_mod.force("no span, period open")
+    with spans_mod.span("fit", parent=None):
+        spans_mod.force("recorded")
+        # the period closes under the open root (a bound, here by hand)
+        spans_mod._close_startup(spans_mod._startup_open, 0, "records")
+        assert spans_mod.current() is not None
+        spans_mod.force("root open, nothing on")
+    assert waited == ["recorded"]
+
+
+@pytest.mark.parametrize("first", ["plan.segment", "plan.fit_stream", "serve.stream",
+                                   "multihost.rollup_gather", "fleet.request"])
+def test_a_parentless_span_that_is_no_unit_of_work_closes_nothing(no_startup, first):
+    said = []
+    process = spans_mod.open_startup(attrs=dict, report=said.append)
+    with spans_mod.span(first):
+        with spans_mod.span("fit"):
+            pass  # a fit under another span is no unit either
+    assert said == [] and spans_mod.active_span_log() is not None
+    with spans_mod.span("fit", parent=None):
+        pass
+    assert len(said) == 1 and spans_mod.active_span_log() is None
+    names = [r["name"] for r in spans_mod.startup_spans()]
+    assert names == ["fit", first, "fit", "process"]
+    by_name = {r["name"]: r for r in spans_mod.startup_spans()}
+    assert by_name[first]["parent"] == by_name["fit"]["parent"] == process.span
+    assert by_name["process"]["t1_ns"] == by_name["fit"]["t1_ns"]
+
+
+@pytest.mark.parametrize("upstream", [False, True])
+def test_the_first_served_request_closes_the_startup_period(no_startup, upstream):
+    """Behind the fleet's router a request brings its parent from another
+    process: it is this process's unit of work all the same."""
+    said = []
+    process = spans_mod.open_startup(attrs=dict, report=said.append)
+    with spans_mod.span("serve.batch"):  # the server's own warm-up
+        pass
+    kw = {"parent": spans_mod.make_context()} if upstream else {}
+    with spans_mod.span("serve.request", rid="r1", **kw) as ctx:
+        assert (ctx.trace == process.trace) is not upstream
+    assert len(said) == 1 and spans_mod.active_span_log() is None
+    recs = spans_mod.startup_spans()
+    assert [r["name"] for r in recs] == ["serve.batch", "serve.request", "process"]
+    assert recs[2]["closed_by"] == "unit" and recs[2]["t1_ns"] == recs[1]["t1_ns"]
+    assert (recs[1]["parent"] == process.span) is not upstream
+
+
+def test_requests_on_many_threads_close_the_startup_period_once(no_startup):
+    """More threads than cores, each a few requests with steps inside,
+    a short switch interval: one `process` root, one report, every span
+    that began in the period recorded once, none after it."""
+    import sys
+
+    said, failed = [], []
+    process = spans_mod.open_startup(attrs=dict, report=said.append)
+    go = threading.Event()
+
+    def client(k):
+        try:
+            go.wait(10)
+            for i in range(20):
+                with spans_mod.span("serve.request", rid=f"{k}.{i}"):
+                    with spans_mod.span("train.step", step=i):
+                        spans_mod.force(i)
+        except Exception as e:  # noqa: BLE001 - the test reports it
+            failed.append(repr(e))
+
+    threads = [threading.Thread(target=client, args=(k,)) for k in range(4 * (os.cpu_count() or 4))]
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        go.set()
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(t.is_alive() for t in threads) and failed == []
+    assert len(said) == 1 and spans_mod.active_span_log() is None
+    recs = spans_mod.startup_spans()
+    roots = [r for r in recs if r["name"] == "process"]
+    assert len(roots) == 1 and roots[0]["span"] == process.span
+    assert roots[0]["closed_by"] in ("unit", "steps")
+    assert len({r["span"] for r in recs}) == len(recs)
+    assert spans_mod._startup.in_flight == {}
+    assert len(recs) < 3 * len(threads) + 2 * spans_mod._STARTUP_STEPS
+
+
+def test_with_a_sink_active_the_startup_records_go_to_its_file(no_startup, tmp_path):
+    import jax.numpy as jnp
+
+    said = []
+    with events.run(str(tmp_path)) as log:
+        process = spans_mod.open_startup(attrs=dict, report=said.append)
+        _fresh_jit(104)(jnp.ones(3))
+        with spans_mod.span("fit", parent=None):
+            pass
+        run_dir = log.run_dir
+    assert spans_mod.startup_spans() == [] and spans_mod._startup_open is None
+    recs = spans_mod.read_spans(run_dir)
+    names = [r["name"] for r in recs]
+    assert names[-2:] == ["fit", "process"] and "jit.trace" in names
+    assert {r.get("parent") for r in recs[:-1]} == {process.span}
+    assert said[0]["total_s"] > 0 and said[0]["programs"] >= 1
+
+
+def test_process_start_falls_back_to_the_import_when_proc_is_unreadable(monkeypatch):
+    t0, source = spans_mod._process_start_ns()
+    assert source == "proc" and t0 <= spans_mod._T_IMPORT_NS
+    import builtins
+
+    real = builtins.open
+
+    def no_proc(path, *a, **k):
+        if str(path).startswith("/proc/"):
+            raise OSError("no /proc here")
+        return real(path, *a, **k)
+
+    monkeypatch.setattr(builtins, "open", no_proc)
+    assert spans_mod._process_start_ns() == (spans_mod._T_IMPORT_NS, "import")
+
+
+def test_startup_summary_on_hand_built_records():
+    """A process of 10 s: the backend 2-3 s, a compile outside any span
+    3.2-3.4 s, a fit 4-10 s whose `fit.init` 4-7 s holds a trace 4.5-6.5
+    (an inner trace 5-5.5 inside it), a lowering 6.5-6.9 and a cache read
+    6.9-7; a foreign root beside it."""
+    s = 1_000_000_000
+
+    def rec(name, span, parent, t0, t1, **attrs):
+        r = {"name": name, "span": span, "trace": "t", "t0_ns": int(t0 * s),
+             "t1_ns": int(t1 * s), **attrs}
+        if parent:
+            r["parent"] = parent
+        return r
+
+    recs = [
+        rec("runtime.init_backend", "b", "p", 2, 3),
+        rec("jit.backend_compile", "c0", "p", 3.2, 3.4, fun="jit(iota)"),
+        rec("fit.init", "i", "f", 4, 7),
+        rec("jit.trace", "t1", "i", 4.5, 6.5, fun="_train_step"),
+        rec("jit.trace", "t2", "i", 5, 5.5, fun="gmm"),
+        rec("jit.lower", "l1", "i", 6.5, 6.9, fun="jit(_train_step)"),
+        rec("jit.cache_read", "r1", "i", 6.9, 7, fun="jit(_train_step)"),
+        rec("fit", "f", "p", 4, 10),
+        rec("serve.request", "x", None, 0, 10),
+        rec("jit.trace", "t9", "x", 0, 10, fun="other"),
+        rec("process", "p", None, 0, 10),
+    ]
+    assert spans_mod.startup_summary(recs[:-1]) is None
+    got = spans_mod.startup_summary(recs)
+    assert got == {
+        "import_s": 2.0, "backend_s": 1.0, "trace_s": 2.0, "lower_s": 0.4,
+        "cache_read_s": 0.1, "compile_s": 0.2, "first_run_s": 3.5, "programs": 2,
+        "total_s": 10.0,
+        "top": [{"fun": "_train_step", "s": 2.0}, {"fun": "gmm", "s": 0.5},
+                {"fun": "iota", "s": 0.2}],
+    }
+    mine = spans_mod.self_ns(recs, recs[-1])
+    assert sum(mine.values()) == 10 * s and "x" not in mine
+    assert mine["p"] == int(2.0 * s) + int(0.2 * s) + int(0.6 * s)  # what only the root covers
+
+
+def test_init_backend_opens_the_period_and_logs_device_startup_and_compile(tmp_path):
+    """One subprocess: nothing is on at import; `init_backend` opens the
+    period; the first parentless span closes it and the `startup` line
+    follows; the `compile` line at exit keeps its three fields."""
+    import subprocess
+    import sys
+
+    code = (
+        "from keystone_tpu.observe import spans\n"
+        "from keystone_tpu.core import runtime\n"
+        "assert spans.active_span_log() is None and spans.startup_spans() == []\n"
+        "runtime.init_backend()\n"
+        "assert spans.active_span_log() is not None\n"
+        "assert [r['name'] for r in spans.startup_spans()] == ['runtime.init_backend']\n"
+        "import jax, jax.numpy as jnp\n"
+        "with spans.span('fit', parent=None):\n"
+        "    jax.jit(lambda x: x + 1.0)(jnp.ones(3))\n"
+        "assert spans.active_span_log() is None\n"
+        "names = [r['name'] for r in spans.startup_spans()]\n"
+        "assert names[0] == 'runtime.init_backend' and names[-2:] == ['fit', 'process']\n"
+        "from jax._src import monitoring\n"
+        "print('listeners', sum(f.__module__.startswith('keystone_tpu') for f in "
+        "monitoring.get_event_duration_listeners()))\n"
+    )
+    root = str(pathlib.Path(__file__).resolve().parents[1])
+    env = {**os.environ, "PYTHONPATH": root, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    r = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
+                       text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    # the program registers one duration listener where it had two
+    assert r.stdout.split() == ["listeners", "1"]
+    said = {}
+    for line in r.stderr.splitlines():
+        for kind in ("device", "startup", "compile"):
+            if f" keystone_tpu.runtime: {kind} {{" in line:
+                said.setdefault(kind, []).append(json.loads(line.split(f": {kind} ", 1)[1]))
+    assert {k: len(v) for k, v in said.items()} == {"device": 1, "startup": 1, "compile": 1}
+    assert set(said["device"][0]) == {"platform", "device_kind", "count", "compile_cache"}
+    assert set(said["compile"][0]) == {"backend_compile_s", "cache_hits", "cache_misses"}
+    assert said["compile"][0]["backend_compile_s"] > 0
+    startup = said["startup"][0]
+    assert list(startup) == ["import_s", "backend_s", "trace_s", "lower_s", "cache_read_s",
+                             "compile_s", "first_run_s", "programs", "total_s", "top"]
+    assert startup["programs"] >= 1 and startup["compile_s"] > 0
+    assert startup["import_s"] > 0 and startup["total_s"] >= startup["import_s"]
+    assert len(startup["top"]) <= 3 and all(set(t) == {"fun", "s"} for t in startup["top"])
