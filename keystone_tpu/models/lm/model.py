@@ -616,6 +616,8 @@ class TransformerLM:
                         total["max_expert_rows"], counters["max_expert_rows"]
                     ),
                     mm_rows=total["mm_rows"] + counters["mm_rows"],
+                    dispatch_rows=total["dispatch_rows"] + counters["dispatch_rows"],
+                    extra_windows=total["extra_windows"] + counters["extra_windows"],
                 )
                 if "gate_sum" in counters:
                     total["gate_sum"] = (

@@ -981,6 +981,10 @@ def _record_counters(model: TransformerLM, stats: list) -> None:
         steps=len(got),
         routed_rows=sum(routed),
         mm_rows=sum(int(c["mm_rows"]) for c in got),
+        # rows put through the grouped products, and windows run beyond
+        # each expert layer's first (ops/moe.py)
+        dispatch_rows=sum(int(c["dispatch_rows"]) for c in got),
+        extra_windows=sum(int(c["extra_windows"]) for c in got),
         # positions scanned, chunks run and positions scanned by the
         # kernel, summed over state-space layers
         **{name: sum(int(c.get(name, 0)) for c in got) for name in SSM_COUNTERS},

@@ -32,13 +32,22 @@ in place of its dense FFN:
   the held experts alone, so the work follows the rows routed here, not
   the static ``tokens x top_k`` bound); the results are brought back to
   token order and summed with the routing weights;
+- where the held experts' share of the ``tokens x top_k`` rows is small
+  (:func:`window_rows`), only a window of the sorted order moves: ``W``
+  rows from the held experts' first, gathered, multiplied and added back
+  into the tokens with their weights (:func:`_expert_windows`, one
+  ``custom_vjp`` whose forward and backward each run a ``while_loop``
+  over as many windows as the rows routed here need: no row is dropped,
+  and no ``tokens x top_k`` array of width ``dim`` is made);
 - an optional shared expert runs on every token beside the routed ones.
 
-``__call__`` also returns three counters of the step program: the rows
-routed to held experts, the largest load of a held expert, and the rows
-the grouped product ran over (whole row tiles); a layer that does not
-renormalise adds ``gate_sum``, the sum of its gates over the rows routed
-to held experts.
+``__call__`` also returns five counters of the step program: the rows
+routed to held experts, the largest load of a held expert, the rows the
+grouped product ran over (whole row tiles), the rows put through the
+grouped products (``dispatch_rows``: ``tokens x top_k``, or the windows
+run times ``W``) and the windows run beyond the first
+(``extra_windows``); a layer that does not renormalise adds
+``gate_sum``, the sum of its gates over the rows routed to held experts.
 """
 
 from __future__ import annotations
@@ -52,7 +61,16 @@ import jax.numpy as jnp
 
 from keystone_tpu.core.treenode import static_field, treenode
 
-COUNTERS = ("routed_rows", "max_expert_rows", "mm_rows")
+COUNTERS = (
+    "routed_rows", "max_expert_rows", "mm_rows", "dispatch_rows", "extra_windows",
+)
+
+# The window is WINDOW_C times the held experts' even share of the
+# ``tokens x top_k`` rows, in whole row tiles of the grouped product
+# (PERF.md section 6, PR 39, says how it was chosen from the chip's
+# counters). Where that is over half the rows, every row moves as before.
+WINDOW_C = 1.375
+_TM = 512  # the grouped product's largest row tile
 
 
 def ffn(y, w1, w2, w3, cdt, mm_fn=None):
@@ -113,6 +131,21 @@ def _row_tile(m: int, most: int = 512) -> int:
     return t
 
 
+def window_rows(rows: int, held: int, num_experts: int) -> int:
+    """Rows of the window the expert layer moves, from its shapes: 0
+    where every one of the ``rows`` (``tokens x top_k``) moves, as it
+    does where the held share is large or the rows are few (decode)."""
+    w = -(-math.ceil(WINDOW_C * rows * held / num_experts) // _TM) * _TM
+    return w if w <= rows // 2 else 0
+
+
+def _tiling(w, tm: int):
+    """The grouped product's tiles for a weight stack (held, K, N), the
+    same for its transposed product and its weight gradient, as
+    ``megablox``'s own backward has them."""
+    return (tm, min(w.shape[1], 1024), min(w.shape[2], 1024))
+
+
 def grouped_mm(xs, w, group_sizes, first: int, tm: int):
     """``xs[rows of expert e] @ w[e - first]`` for the held experts,
     zeros elsewhere. xs: (M, K) sorted by expert; w: (held, K, N);
@@ -121,19 +154,157 @@ def grouped_mm(xs, w, group_sizes, first: int, tm: int):
 
     from keystone_tpu.ops.flash_attention import interpret_default
 
-    k_dim, n_dim = w.shape[1], w.shape[2]
     with jax.named_scope("moe_grouped_mm"):
         return gmm(
             xs,
             w,
             group_sizes,
             xs.dtype,
-            (tm, min(k_dim, 1024), min(n_dim, 1024)),
+            _tiling(w, tm),
             jnp.asarray(first, jnp.int32),
             None,
             False,
             interpret_default(),
         )
+
+
+def _gmm(xs, w, sizes, transpose_rhs: bool = False):
+    """One window's grouped product over the held experts (``sizes``:
+    their rows in the window, then the rest, which comes out zero): the
+    kernel itself, as :func:`_expert_windows` is its own ``custom_vjp``."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    from keystone_tpu.ops.flash_attention import interpret_default
+
+    with jax.named_scope("moe_grouped_mm"):
+        return gmm(
+            xs, w, sizes, xs.dtype, _tiling(w, _TM), jnp.asarray(0, jnp.int32),
+            None, transpose_rhs=transpose_rhs, interpret=interpret_default(),
+        )
+
+
+def _tgmm(xs, g, w, sizes):
+    """``xs[rows of e].T @ g[rows of e]`` for each held expert: the
+    gradient of ``w`` (held, K, N), in float32 to sum over windows."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    from keystone_tpu.ops.flash_attention import interpret_default
+
+    with jax.named_scope("moe_grouped_mm"):
+        return tgmm(
+            xs.swapaxes(0, 1), g, sizes, w.dtype, _tiling(w, _TM),
+            jnp.asarray(0, jnp.int32), w.shape[0],
+            interpret=interpret_default(),
+        ).astype(jnp.float32)
+
+
+def _act(h1, h3):
+    return jax.nn.gelu(h1) if h3 is None else jax.nn.silu(h1) * h3
+
+
+def _window(i, weights, order, starts, ends, k: int, w: int):
+    """Window ``i`` of the held experts' sorted rows: the assignments it
+    covers (``order`` is padded by a window, so the slice never shifts),
+    their tokens, their weights (0 past the rows routed here) and the
+    held experts' rows in it, then the rest."""
+    lo = starts[0] + i * w
+    rows = jax.lax.dynamic_slice_in_dim(order, lo, w)
+    here = jnp.clip(jnp.minimum(ends, lo + w) - jnp.maximum(starts, lo), 0, w)
+    sizes = jnp.concatenate([here, (w - jnp.sum(here))[None]])
+    valid = jnp.arange(w) < ends[-1] - lo
+    wts = jnp.where(valid, weights.reshape(-1)[rows], 0.0)
+    return rows, rows // k, wts, sizes, valid
+
+
+def _num_windows(starts, ends, rows: int):
+    return (ends[-1] - starts[0] + rows - 1) // rows
+
+
+def _forward(xf, weights, w1, w3, w2, order, starts, ends, k: int, w: int):
+    """The held experts' weighted sum (T, d) f32 over every window the
+    rows routed here need."""
+
+    def body(i, out):
+        _rows, tok, wts, sizes, _valid = _window(i, weights, order, starts, ends, k, w)
+        xs = xf[tok]
+        h3 = None if w3 is None else _gmm(xs, w3, sizes)
+        a = _act(_gmm(xs, w1, sizes), h3)
+        y = _gmm(a, w2, sizes).astype(jnp.float32)
+        wts = wts.astype(xf.dtype).astype(jnp.float32)
+        return out.at[tok].add(wts[:, None] * y)
+
+    return jax.lax.fori_loop(
+        0, _num_windows(starts, ends, w), body, jnp.zeros(xf.shape, jnp.float32)
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _expert_windows(xf, weights, w1, w3, w2, order, starts, ends, k, w):
+    """xf (T, d), weights (T, k) f32, the held experts' stacks in the
+    compute dtype, ``order`` the sorted assignments padded by a window,
+    ``starts`` / ``ends`` the held experts' first and past-last sorted
+    rows, ``k`` assignments a token, ``w`` rows a window: (T, d) f32.
+    The backward runs the windows again and makes each window's hidden
+    rows anew rather than keeping them."""
+    return _forward(xf, weights, w1, w3, w2, order, starts, ends, k, w)
+
+
+def _expert_windows_fwd(xf, weights, w1, w3, w2, order, starts, ends, k, w):
+    res = (xf, weights, w1, w3, w2, order, starts, ends)
+    return _forward(*res, k, w), res
+
+
+def _expert_windows_bwd(k, w, res, g):
+    xf, weights, w1, w3, w2, order, starts, ends = res
+    f32 = jnp.float32
+
+    def body(i, acc):
+        dx, dwts, dw1, dw3, dw2 = acc
+        rows, tok, wts, sizes, valid = _window(i, weights, order, starts, ends, k, w)
+        xs = xf[tok]
+        h3 = None if w3 is None else _gmm(xs, w3, sizes)
+        a, act_vjp = jax.vjp(_act, _gmm(xs, w1, sizes), h3)
+        y = _gmm(a, w2, sizes).astype(f32)
+        gt = g[tok]
+        dwts = dwts.at[rows].add(jnp.where(valid, jnp.sum(gt * y, axis=-1), 0.0))
+        dy = (wts.astype(xf.dtype).astype(f32)[:, None] * gt).astype(xf.dtype)
+        dw2 = dw2 + _tgmm(a, dy, w2, sizes)
+        dh1, dh3 = act_vjp(_gmm(dy, w2, sizes, transpose_rhs=True))
+        dxs = _gmm(dh1, w1, sizes, transpose_rhs=True).astype(f32)
+        dw1 = dw1 + _tgmm(xs, dh1, w1, sizes)
+        if w3 is not None:
+            dxs = dxs + _gmm(dh3, w3, sizes, transpose_rhs=True).astype(f32)
+            dw3 = dw3 + _tgmm(xs, dh3, w3, sizes)
+        return dx.at[tok].add(dxs), dwts, dw1, dw3, dw2
+
+    zeros = lambda m: None if m is None else jnp.zeros(m.shape, f32)  # noqa: E731
+    dx, dwts, dw1, dw3, dw2 = jax.lax.fori_loop(
+        0, _num_windows(starts, ends, w), body,
+        (jnp.zeros(xf.shape, f32), jnp.zeros(weights.size, f32),
+         zeros(w1), zeros(w3), zeros(w2)),
+    )
+    cast = lambda d, m: None if m is None else d.astype(m.dtype)  # noqa: E731
+    return (
+        dx.astype(xf.dtype), dwts.reshape(weights.shape), cast(dw1, w1),
+        cast(dw3, w3), cast(dw2, w2), None, None, None,
+    )
+
+
+_expert_windows.defvjp(_expert_windows_fwd, _expert_windows_bwd)
+
+
+def _window_counters(starts, ends, w: int, total_rows: int):
+    """``dispatch_rows``, ``extra_windows`` and the rows of whole tiles
+    the windows' products visit (``mm_rows``), from the held experts'
+    sorted rows: every window that can run is reckoned, those that do
+    not run count nothing."""
+    n = _num_windows(starts, ends, w)
+    lo = starts[0] + w * jnp.arange(-(-total_rows // w))[:, None]
+    s = jnp.clip(starts - lo, 0, w)
+    e = jnp.clip(ends - lo, 0, w)
+    tiles = jnp.where(e > s, -(-e // _TM) - s // _TM, 0)
+    mm_rows = _TM * jnp.sum(jnp.where(jnp.arange(tiles.shape[0]) < n, tiles.sum(1), 0))
+    return n * w, jnp.maximum(n - 1, 0), mm_rows
 
 
 @treenode
@@ -275,16 +446,64 @@ class MoELayer:
         xf = x.reshape(t, d)
         cdt = x.dtype
         scores = tuple(sc.reshape(t, sc.shape[-1]) for sc in scores)
+        window = window_rows(t * k, self.held, self.num_experts)
         with jax.named_scope("moe_router"):
             weights, idx = self.route(xf, scores)
             flat = idx.reshape(t * k)
             order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-            inv = (
-                jnp.zeros(t * k, jnp.int32)
-                .at[order]
-                .set(jnp.arange(t * k, dtype=jnp.int32))
-            )
+            if not window:
+                inv = (
+                    jnp.zeros(t * k, jnp.int32)
+                    .at[order]
+                    .set(jnp.arange(t * k, dtype=jnp.int32))
+                )
             group_sizes = jnp.zeros(self.num_experts, jnp.int32).at[flat].add(1)
+        first = self.first_expert
+        if window:
+            held = jax.lax.dynamic_slice_in_dim(group_sizes, first, self.held)
+            end = jax.lax.dynamic_slice_in_dim(jnp.cumsum(group_sizes), first, self.held)
+            start = end - held
+            with jax.named_scope("moe_experts"):
+                w3 = None if self.w3 is None else self.w3.astype(cdt)
+                out = _expert_windows(
+                    xf, weights, self.w1.astype(cdt), w3, self.w2.astype(cdt),
+                    jnp.pad(order, (0, window)), start, end, k, window,
+                ).astype(cdt)
+            dispatch_rows, extra_windows, mm_rows = _window_counters(
+                start, end, window, t * k
+            )
+        else:
+            out, held, mm_rows = self._every_row(
+                xf, weights, order, inv, group_sizes, cdt
+            )
+            dispatch_rows, extra_windows = jnp.int32(t * k), jnp.int32(0)
+        if axis is not None:
+            held, mm_rows, dispatch_rows, extra_windows = jax.lax.psum(
+                (held, mm_rows, dispatch_rows, extra_windows), axis
+            )
+        counters = {
+            "routed_rows": jnp.sum(held),
+            "max_expert_rows": jnp.max(held),
+            "mm_rows": mm_rows,
+            "dispatch_rows": dispatch_rows,
+            "extra_windows": extra_windows,
+        }
+        if not self.renormalize:
+            # what the router gave the rows routed here (renormalised
+            # gates sum to the rows themselves and say nothing)
+            here = (idx >= first) & (idx < first + self.held)
+            gate_sum = jnp.sum(jnp.where(here, weights, 0.0))
+            counters["gate_sum"] = (
+                gate_sum if axis is None else jax.lax.psum(gate_sum, axis)
+            )
+        return out.reshape(b, s, d), counters
+
+    def _every_row(self, xf, weights, order, inv, group_sizes, cdt):
+        """Every one of the ``tokens x top_k`` rows through the grouped
+        products and back (the rows of experts held elsewhere come out
+        zero): (T, d), the held experts' rows and ``mm_rows``."""
+        t, d = xf.shape
+        k = self.top_k
         # a handful of decode rows is padded up to the kernel's sublane
         # tile; the pad rows are no expert's and are cut off again
         pad = -(t * k) % 8
@@ -313,23 +532,7 @@ class MoELayer:
         start = end - held
         # the product visits every row tile a held expert's rows touch
         tiles = jnp.where(held > 0, -(-end // tm) - start // tm, 0)
-        mm_rows = tm * jnp.sum(tiles)
-        if axis is not None:
-            held, mm_rows = jax.lax.psum((held, mm_rows), axis)
-        counters = {
-            "routed_rows": jnp.sum(held),
-            "max_expert_rows": jnp.max(held),
-            "mm_rows": mm_rows,
-        }
-        if not self.renormalize:
-            # what the router gave the rows routed here (renormalised
-            # gates sum to the rows themselves and say nothing)
-            here = (idx >= first) & (idx < first + self.held)
-            gate_sum = jnp.sum(jnp.where(here, weights, 0.0))
-            counters["gate_sum"] = (
-                gate_sum if axis is None else jax.lax.psum(gate_sum, axis)
-            )
-        return out.reshape(b, s, d), counters
+        return out, held, tm * jnp.sum(tiles)
 
 
 @treenode

@@ -11,8 +11,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from keystone_tpu.ops.moe import COUNTERS, MoELayer
+from keystone_tpu.ops.moe import COUNTERS, WINDOW_C, MoELayer, window_rows
 
+# 2 of 16 experts held, 512 tokens of top 2: the held share's window is
+# 512 of the 1 024 rows
+_WINDOWED = dict(
+    num_experts=16, held=2, first_expert=6, swiglu=True, shared_ff=32,
+    scoring="sigmoid", routed_scale=2.5, tokens=(2, 256),
+)
+# ``tokens`` (batch, sequence) where a case wants more than the default;
+# ``force``: a constant first feature and that router weight on the held
+# experts (every token chooses them, or none does)
 CASES = {
     "toy_top2_softmax_gelu": dict(num_experts=4),
     "share_top2_sigmoid_swiglu_shared": dict(
@@ -20,11 +29,35 @@ CASES = {
         scoring="sigmoid", routed_scale=2.5,
     ),
     "top3_of_all_held": dict(num_experts=8, top_k=3, swiglu=True),
+    "window_one": _WINDOWED,
+    "window_gelu_first_share": dict(num_experts=16, held=2, tokens=(2, 256)),
+    "window_overflow": dict(_WINDOWED, force=9.0),
+    "window_none_routed_here": dict(_WINDOWED, force=-9.0),
+    "every_row_large_share": dict(
+        num_experts=4, held=2, first_expert=2, swiglu=True, tokens=(2, 256)
+    ),
 }
 
 
 def _layer(seed=0, dim=16, ff=32, **kw):
     return MoELayer.create(jax.random.key(seed), dim, ff, **kw)
+
+
+def _case(case, rng, seed, tokens):
+    """The case's layer and an input of its tokens (else ``tokens``)."""
+    kw = dict(CASES[case])
+    b, s = kw.pop("tokens", tokens)
+    force = kw.pop("force", None)
+    layer = _layer(seed=seed, **kw)
+    x = jnp.asarray(rng.normal(size=(b, s, 16)).astype(np.float32))
+    if force is not None:
+        x = x.at[..., 0].set(1.0)
+        lo = layer.first_expert
+        layer = dataclasses.replace(
+            layer,
+            w_router=layer.w_router.at[0, lo : lo + layer.held].set(force),
+        )
+    return layer, x
 
 
 def loop_over_experts(m: MoELayer, x):
@@ -71,8 +104,7 @@ def test_single_expert_matches_dense_ffn(rng):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_layer_matches_the_loop_over_experts(rng, case):
-    layer = _layer(seed=1, **CASES[case])
-    x = jnp.asarray(rng.normal(size=(2, 16, 16)).astype(np.float32))
+    layer, x = _case(case, rng, 1, (2, 16))
     out, _ = jax.jit(lambda m, t: m(t))(layer, x)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(loop_over_experts(layer, x)), atol=2e-5
@@ -83,14 +115,56 @@ def test_layer_matches_the_loop_over_experts(rng, case):
 def test_gradients_match_the_loop_over_experts(rng, case):
     """Through the sort, the gathers (whose backward is a gather too)
     and the grouped products: weights, router and input."""
-    layer = _layer(seed=2, **CASES[case])
-    x = jnp.asarray(rng.normal(size=(1, 16, 16)).astype(np.float32))
+    layer, x = _case(case, rng, 2, (1, 16))
     got = jax.grad(lambda m, t: jnp.sum(jnp.sin(m(t)[0])), argnums=(0, 1))(layer, x)
     want = jax.grad(
         lambda m, t: jnp.sum(jnp.sin(loop_over_experts(m, t))), argnums=(0, 1)
     )(layer, x)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+        # sums over 512 tokens reach ~100: float32 holds them to 1e-5 of themselves
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_counters_sum_as_stated(rng, case):
+    """``dispatch_rows`` is every one of the ``tokens x top_k`` rows, or
+    the windows run times the window; ``extra_windows`` the windows run
+    beyond the first, as many as the rows routed here need."""
+    layer, x = _case(case, rng, 1, (2, 16))
+    _, c = jax.jit(lambda m, t: m(t))(layer, x)
+    c = {k: int(v) for k, v in c.items()}
+    rows = x.shape[0] * x.shape[1] * layer.top_k
+    window = window_rows(rows, layer.held, layer.num_experts)
+    assert case.startswith("window") == bool(window)
+    if not window:
+        assert (c["dispatch_rows"], c["extra_windows"]) == (rows, 0)
+        return
+    runs = -(-c["routed_rows"] // window)
+    assert (c["dispatch_rows"], c["extra_windows"]) == (runs * window, max(runs - 1, 0))
+    assert c["routed_rows"] <= c["mm_rows"] <= c["dispatch_rows"] + 2 * 512
+    expected = {"window_overflow": (rows, 2), "window_none_routed_here": (0, 0)}
+    if case in expected:  # every row routed here (two windows), or none
+        assert (c["routed_rows"], runs) == expected[case]
+
+
+@pytest.mark.parametrize(
+    "shape,window",
+    [
+        ((2 * 8192 * 8, 32, 256), True),  # laguna_xs2.train_8k: 32 of 256 held
+        ((4 * 8192 * 1, 8, 16), False),  # zaya1_8b.train_8k: top 1, 8 of 16
+        ((8 * 1 * 8, 32, 256), False),  # laguna decoding 8 slots
+        ((2 * 64 * 2, 1, 2), False),  # the toy presets
+    ],
+    ids=["laguna_xs2", "zaya1_8b", "decode", "toy"],
+)
+def test_the_shape_rule_picks_the_window_only_for_a_small_share(shape, window):
+    rows, held, experts = shape
+    got = window_rows(rows, held, experts)
+    if window:
+        assert got == -(-round(WINDOW_C * rows * held / experts) // 512) * 512
+        assert 0 < got <= rows // 2 and got % 512 == 0
+    else:
+        assert got == 0
 
 
 def test_no_token_is_dropped_when_every_token_picks_one_expert(rng):
@@ -149,17 +223,23 @@ def test_the_product_runs_over_whole_row_tiles_of_the_held_experts(rng):
     assert routed < 1024  # six of eight experts are held elsewhere
 
 
-@pytest.mark.parametrize("batch", [8, 6], ids=["split_over_data", "whole"])
-def test_on_a_mesh_the_layer_is_the_unsharded_one(mesh4x2, batch):
+@pytest.mark.parametrize(
+    "batch,seq", [(8, 4), (6, 4), (8, 256)],
+    ids=["split_over_data", "whole", "window_split_over_data"],
+)
+def test_on_a_mesh_the_layer_is_the_unsharded_one(mesh4x2, batch, seq):
     """Told its mesh, the layer shard_maps the routed part (GSPMD
     cannot partition the grouped kernel): the batch over ``data`` where
     it divides, whole where not. Output, every gradient and the counters
-    are the one-device layer's."""
+    are the one-device layer's. At 8 x 256 tokens each device moves a
+    window of its own 1 024 rows (the shape rule follows its tokens)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     rng = np.random.default_rng(0)
     layer = _layer(**CASES["share_top2_sigmoid_swiglu_shared"])
-    x = jnp.asarray(rng.normal(size=(batch, 4, 16)).astype(np.float32))
+    if seq > 4:
+        layer = _case("window_one", rng, 0, None)[0]
+    x = jnp.asarray(rng.normal(size=(batch, seq, 16)).astype(np.float32))
 
     def loss(m, t, mesh=None):
         out, counters = m(t, mesh)
@@ -174,13 +254,19 @@ def test_on_a_mesh_the_layer_is_the_unsharded_one(mesh4x2, batch):
         jax.value_and_grad(lambda m, t: loss(m, t, mesh4x2), (0, 1), has_aux=True)
     )(layer, xs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
-    assert {k: int(v) for k, v in got_c.items() if k != "mm_rows"} == {
-        k: int(v) for k, v in want_c.items() if k != "mm_rows"
+    # each device's rows, windows and tiles are its own
+    own = ("mm_rows", "dispatch_rows", "extra_windows")
+    assert {k: int(v) for k, v in got_c.items() if k not in own} == {
+        k: int(v) for k, v in want_c.items() if k not in own
     }
+    assert int(got_c["dispatch_rows"]) == (
+        int(want_c["dispatch_rows"]) if seq == 4 else 4 * 512
+    )
     # each device's product runs over its own whole row tiles
     assert int(got_c["mm_rows"]) >= int(got_c["routed_rows"])
     for got, ref in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-4)
+        # 2 048 tokens' sums reach ~700: float32 holds them to 1e-5 of themselves
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-4, rtol=1e-5)
 
 
 def test_create_refuses_a_share_outside_the_model():

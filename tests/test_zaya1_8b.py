@@ -596,9 +596,13 @@ def test_a_second_fit_records_no_jit_span(tmp_path, toy):
     assert counters["mm_rows"] >= counters["routed_rows"] and counters["mm_rows"] % 8 == 0
     assert 1 / 16 < counters["router_gate_mean"] < 1.0
     assert counters["load_max_over_mean"] >= 1.0 and counters["ssm_rows"] == 0
+    # half the experts held, top 1: every one of the 128 rows of a layer
+    # and step moves (no window), so nothing runs beyond it
+    assert (counters["dispatch_rows"], counters["extra_windows"]) == (2 * 128 * 2, 0)
     # and `observe trace` prints them
     shown = spans.render_traces(recs)
     assert "cca_layers=2" in shown and "cca_rows=512" in shown and "ssm_rows" not in shown
+    assert "dispatch_rows=512" in shown and "extra_windows=0" in shown
     assert "router_gate_mean=0." in shown and f"routed_rows={counters['routed_rows']}" in shown
 
 
