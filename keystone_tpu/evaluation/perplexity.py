@@ -10,6 +10,7 @@ bits-per-byte, the enwik8 headline metric).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -44,8 +45,12 @@ def evaluate_perplexity(
     the previous window — the standard simple protocol); a ragged tail
     shorter than S+1 is dropped. Returns {loss, perplexity,
     bits_per_token, tokens_scored}. ``logit_chunk`` evaluates the CE in
-    S-chunks (see ``models/lm``) — identical numbers up to FP order.
+    S-chunks (see ``models/lm``) — identical numbers up to FP order. A
+    model's multi-token prediction module is left out: the perplexity
+    is the next token's.
     """
+    if getattr(model, "mtp", None) is not None:
+        model = dataclasses.replace(model, mtp=None)
     window = seq + 1
     n_win = len(tokens) // window
     if n_win == 0:

@@ -9,6 +9,8 @@ context that tensor is the step's single largest HBM object
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
@@ -71,15 +73,41 @@ def next_token_loss_and_counters(
     tokens of an S+1 window. The loss is the cross-entropy alone: no
     auxiliary term the model's description does not name.
     ``logit_chunk > 0`` computes the CE in S-chunks so the full (B, S, V)
-    f32 logits never materialize (see chunked_token_cross_entropy)."""
-    if logit_chunk:
-        cdt = jnp.dtype(model.compute_dtype)
-        x, counters = model.backbone(tokens[:, :-1])
-        with jax.named_scope("loss"):
-            ce = chunked_token_cross_entropy(
-                x, model, tokens[:, 1:], cdt, logit_chunk
-            )
-        return ce, counters
-    logits, counters = model.forward_with_aux(tokens[:, :-1])
+    f32 logits never materialize (see chunked_token_cross_entropy). A
+    model with an MTP module reads windows of S+2 tokens and adds its
+    term (:func:`_loss_with_mtp`)."""
+    if model.mtp is not None:
+        return _loss_with_mtp(model, tokens, logit_chunk)
+    x, counters = model.backbone(tokens[:, :-1])
     with jax.named_scope("loss"):
-        return token_cross_entropy(logits, tokens[:, 1:]), counters
+        return _cross_entropy(model, x, tokens[:, 1:], logit_chunk), counters
+
+
+def _cross_entropy(model, x, targets, logit_chunk: int):
+    """Mean CE of ``targets`` from hidden states through the model's
+    final norm and head, chunked or dense."""
+    cdt = jnp.dtype(model.compute_dtype)
+    if logit_chunk:
+        return chunked_token_cross_entropy(x, model, targets, cdt, logit_chunk)
+    return token_cross_entropy(output_logits(model, x, cdt), targets)
+
+
+def _loss_with_mtp(model: TransformerLM, tokens, logit_chunk: int):
+    """``CE_1 + weight * CE_2`` over (B, S+2) windows: the main stack on
+    the first S ids predicts ``t_(i+1)``; the MTP module reads its hidden
+    states and the ids one ahead and predicts ``t_(i+2)`` through its own
+    final norm and the model's head. The counters gain ``mtp_rows``
+    (positions the second term covers) and ``mtp_ce`` (that term)."""
+    s = tokens.shape[1] - 2
+    x, counters = model.backbone(tokens[:, :s])
+    h, counters = model.mtp_hidden(x, tokens[:, 1 : s + 1], counters)
+    with jax.named_scope("loss"):
+        ce = _cross_entropy(model, x, tokens[:, 1 : s + 1], logit_chunk)
+        ahead = dataclasses.replace(model, final_norm=model.mtp.final_norm)
+        ce_mtp = _cross_entropy(ahead, h, tokens[:, 2:], logit_chunk)
+    counters = {
+        **counters,
+        "mtp_rows": jnp.int32(tokens.shape[0] * s),
+        "mtp_ce": ce_mtp,
+    }
+    return ce + model.mtp.weight * ce_mtp, counters

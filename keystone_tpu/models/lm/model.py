@@ -103,16 +103,22 @@ class LMBlock:
     a compressed latent, then a dense FFN or routed experts (scored by
     the expert layer's own matrix, or by ``router``, which also reads
     and leaves a state that flows from block to block beside ``x``).
+    A block may be one part alone: a mixer with no FFN after it
+    (``w1`` / ``w2`` None and no ``moe``), or an FFN or expert layer with
+    no mixer before it (``wq`` .. ``wo`` None and no other mixer); the
+    absent part runs nothing, not even its norm.
     The one block definition: the toy presets of
     :meth:`TransformerLM.create` and a public ``config.json``
     (:meth:`TransformerLM.from_config`) fill the same fields."""
 
-    wq: jnp.ndarray  # (d, H·hd); zero-width under another mixer
-    wk: jnp.ndarray  # (d, KV·hd)
-    wv: jnp.ndarray
-    wo: jnp.ndarray  # (H·hd, d)
-    w1: jnp.ndarray  # (d, ff); zero-width under routed experts
-    w2: jnp.ndarray  # (ff, d)
+    # (d, H·hd); zero-width under another mixer, None with no mixer
+    wq: jnp.ndarray | None
+    wk: jnp.ndarray | None  # (d, KV·hd)
+    wv: jnp.ndarray | None
+    wo: jnp.ndarray | None  # (H·hd, d)
+    # (d, ff); zero-width under routed experts, None with no FFN part
+    w1: jnp.ndarray | None
+    w2: jnp.ndarray | None  # (ff, d)
     w3: jnp.ndarray | None = None  # (d, ff): SwiGLU's second input
     wg: jnp.ndarray | None = None  # (d, H): one sigmoid gate a head
     norm1: jnp.ndarray | None = None  # learned RMSNorm scales, or None
@@ -126,6 +132,31 @@ class LMBlock:
     scale1: jnp.ndarray | None = None  # (4, d): after the mixer
     scale2: jnp.ndarray | None = None  # (4, d): after the FFN or experts
     spec: LayerSpec | None = static_field(default=None)
+
+    @property
+    def has_mixer(self) -> bool:
+        return self.wq is not None or self.ssm is not None or self.cca is not None
+
+    @property
+    def has_ffn(self) -> bool:
+        return self.w1 is not None or self.moe is not None
+
+
+@treenode
+class MTPModule:
+    """Multi-token prediction of depth one: from the main stack's hidden
+    state ``x_i`` (before its final norm) and the embedding of the next
+    token ``t_(i+1)``, ``h = [rms(E[t_(i+1)]; enorm) | rms(x_i; hnorm)]
+    eh_proj``, then the module's own blocks and ``final_norm``, then the
+    model's own head, trained on ``t_(i+2)``. Its cross-entropy joins the
+    loss times ``weight``."""
+
+    enorm: jnp.ndarray  # (d,)
+    hnorm: jnp.ndarray  # (d,)
+    eh_proj: jnp.ndarray  # (2d, d)
+    blocks: tuple  # of LMBlock
+    final_norm: jnp.ndarray  # (d,)
+    weight: float = static_field(default=0.3)
 
 
 def _ln(x, cdt):
@@ -223,8 +254,12 @@ def _block_apply(x, blk: LMBlock, cdt, attn, mm_fn=mm, eps: float = 1e-6,
             s, b, t, u = scales.astype(jnp.float32)
             return ((s * x + b) + (t * branch + u)).astype(x.dtype)
 
-    a, aux = attn(_norm(x, blk.norm1, eps, cdt), blk)
-    x = join(x, a, blk.scale1)
+    aux = None
+    if blk.has_mixer:
+        a, aux = attn(_norm(x, blk.norm1, eps, cdt), blk)
+        x = join(x, a, blk.scale1)
+    if not blk.has_ffn:
+        return x, aux, None, carried
     y = _norm(x, blk.norm2, eps, cdt)
     if blk.moe is not None:
         scores = None
@@ -301,6 +336,9 @@ class TransformerLM:
     # final RMSNorm's scale; None = the parameter-free LayerNorm
     head: jnp.ndarray | None = None
     final_norm: jnp.ndarray | None = None
+    # a multi-token prediction module (MTPModule) trained beside the
+    # next-token head, or None
+    mtp: object | None = None
     num_heads: int = static_field(default=8)
     # attention strategy: "local" (dense or Pallas flash on TPU),
     # "ring" / "ulysses" (sequence-parallel over `seq_axis` of `mesh`).
@@ -372,7 +410,18 @@ class TransformerLM:
         it cannot serve, by name: the first layer of each kind it has no
         cache or step for."""
         why: dict[str, str] = {}
+        if self.mtp is not None:
+            why["mtp"] = (
+                "the model predicts a second token ahead (multi-token "
+                "prediction): decode has no step that drafts with it"
+            )
         for i, blk in enumerate(self.blocks):
+            if not (blk.has_mixer and blk.has_ffn):
+                why.setdefault(
+                    "parts",
+                    f"layer {i} is one part alone (a mixer with no FFN after "
+                    "it, or an expert layer with no mixer before it)",
+                )
             if blk.ssm is not None:
                 why.setdefault(
                     "ssm",
@@ -392,6 +441,8 @@ class TransformerLM:
                     f"layer {i} joins its branches under learned scales "
                     "and biases",
                 )
+            if not blk.has_mixer:
+                continue
             if blk.cca is not None:
                 why.setdefault(
                     "cca",
@@ -587,6 +638,29 @@ class TransformerLM:
         state flows from block to block (None until a block leaves one)."""
         cdt = jnp.dtype(self.compute_dtype)
         x = _embed(self, tokens, cdt)
+        return self._run_blocks(x, self.blocks, {c: jnp.int32(0) for c in COUNTERS})
+
+    def _mtp_blocks(self) -> tuple:
+        return () if self.mtp is None else self.mtp.blocks
+
+    def mtp_hidden(self, x, next_tokens, counters):
+        """The MTP module's hidden states (B, S, d) before its final
+        norm, from the main stack's ``x`` and the ids one position ahead
+        (B, S), and ``counters`` with its layers' added."""
+        cdt = jnp.dtype(self.compute_dtype)
+        m = self.mtp
+        with jax.named_scope("mtp_embed_proj"):
+            e = _norm(_embed(self, next_tokens, cdt), m.enorm, self.norm_eps, cdt)
+            h = _norm(x, m.hnorm, self.norm_eps, cdt)
+            h = model_mm(self)(jnp.concatenate([e, h], axis=-1), m.eh_proj, cdt)
+        with jax.named_scope("mtp"):
+            return self._run_blocks(h, m.blocks, counters)
+
+    def _run_blocks(self, x, blocks, total):
+        """``blocks`` in order on ``x``, their counters added to a copy
+        of ``total`` (which gains the state-space and compressed-latent
+        ones where ``blocks`` has such layers)."""
+        cdt = jnp.dtype(self.compute_dtype)
 
         def block_fn(x, carried, blk):
             out, mixed, counters, carried = _block_apply(
@@ -601,13 +675,15 @@ class TransformerLM:
 
         if self.remat:
             block_fn = remat_wrap(block_fn, self.remat_policy)
-        total = {c: jnp.int32(0) for c in COUNTERS}
-        if any(blk.ssm is not None for blk in self.blocks):
-            total.update({c: jnp.int32(0) for c in SSM_COUNTERS})
-        if any(blk.cca is not None for blk in self.blocks):
-            total.update({c: jnp.int32(0) for c in CCA_COUNTERS})
+        total = dict(total)
+        if any(blk.ssm is not None for blk in blocks):
+            for c in SSM_COUNTERS:
+                total.setdefault(c, jnp.int32(0))
+        if any(blk.cca is not None for blk in blocks):
+            for c in CCA_COUNTERS:
+                total.setdefault(c, jnp.int32(0))
         carried = None
-        for blk in self.blocks:
+        for blk in blocks:
             x, carried, counters, mixed = block_fn(x, carried, blk)
             if counters is not None:
                 total.update(
@@ -759,8 +835,14 @@ class TransformerLM:
         deployment, ``published`` gives the model's own counts (the
         router keeps ``published.num_experts`` outputs) and
         ``deployment.expert_shard`` says which share of the experts this
-        is. Seeded random weights: no checkpoint is read."""
+        is. Seeded random weights: no checkpoint is read. A
+        ``hybrid_override_pattern`` (one character a layer) builds its
+        layers one part alone: see :func:`_from_hybrid_pattern`."""
         c = config
+        if "hybrid_override_pattern" in c:
+            return _from_hybrid_pattern(
+                key, c, mesh=mesh, compute_dtype=compute_dtype, remat=remat
+            )
         d = c["hidden_size"]
         hd = c.get("head_dim") or d // c["num_attention_heads"]
         depth, vocab = c["num_hidden_layers"], c["vocab_size"]
@@ -922,6 +1004,116 @@ class TransformerLM:
         )
 
 
+def _from_hybrid_pattern(key, c: dict, *, mesh, compute_dtype: str,
+                         remat: bool) -> TransformerLM:
+    """A model whose ``hybrid_override_pattern`` gives each layer as one
+    part alone (``model_type`` "nemotron_h"), at the counts held here:
+    ``M`` a Mamba-2 mixer of ``mamba_num_heads`` heads in ``n_groups``
+    groups; ``*`` attention with no positional encoding; ``E`` an expert
+    layer whose ``n_routed_experts`` held experts (of
+    ``published.n_routed_experts``, from ``deployment.expert_shard`` on)
+    are relu² in a latent of ``moe_latent_size``, beside a relu² shared
+    expert of ``moe_shared_expert_intermediate_size`` over
+    ``deployment.tensor_parallel`` (the columns one chip of that many
+    holds); and ``num_nextn_predict_layers`` = 1 an MTP module whose
+    layers follow ``mtp_hybrid_override_pattern``, weighted by
+    ``mtp_loss_scaling_factor``. RMSNorm everywhere, an untied head."""
+    d, hd = c["hidden_size"], c["head_dim"]
+    depth, vocab = c["num_hidden_layers"], c["vocab_size"]
+    heads, kvh = c["num_attention_heads"], c["num_key_value_heads"]
+    eps = c.get("layer_norm_epsilon", 1e-5)
+    deployment = c.get("deployment", {})
+    held = c["n_routed_experts"]
+    routed = c.get("published", {}).get("n_routed_experts", held)
+    pattern = c["hybrid_override_pattern"][:depth]
+    if len(pattern) != depth or c.get("mlp_hidden_act") != "relu2":
+        raise ValueError(
+            f"{depth} layers of pattern {pattern!r}, mlp_hidden_act "
+            f"{c.get('mlp_hidden_act')!r}: expected relu2"
+        )
+
+    def ones():  # a buffer of its own: the step donates every leaf
+        return jnp.ones((d,), jnp.float32)
+
+    absent = dict(wq=None, wk=None, wv=None, wo=None, w1=None, w2=None)
+
+    def init(k, shape, fan_in):
+        return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
+
+    def layer(kind: str, k) -> LMBlock:
+        if kind == "M":
+            mixer = Mamba2Mixer.create(
+                k, d, heads=c["mamba_num_heads"], head_dim=c["mamba_head_dim"],
+                state=c["ssm_state_size"], groups=c["n_groups"],
+                conv=c["conv_kernel"], conv_bias=c["use_conv_bias"],
+                chunk=c["chunk_size"], eps=eps,
+            )
+            return LMBlock(**absent, ssm=mixer, norm1=ones(), spec=LayerSpec(0, 0))
+        if kind == "*":
+            ks = jax.random.split(k, 4)
+            return LMBlock(
+                **{
+                    **absent,
+                    "wq": init(ks[0], (d, heads * hd), d),
+                    "wk": init(ks[1], (d, kvh * hd), d),
+                    "wv": init(ks[2], (d, kvh * hd), d),
+                    "wo": init(ks[3], (heads * hd, d), heads * hd),
+                },
+                norm1=ones(),
+                spec=LayerSpec(heads, kvh),
+            )
+        if kind == "E":
+            shared = c["moe_shared_expert_intermediate_size"] * c.get("n_shared_experts", 1)
+            experts = MoELayer.create(
+                k, d, c["moe_intermediate_size"], routed,
+                held=held, first_expert=deployment.get("expert_shard", 0) * held,
+                top_k=c["num_experts_per_tok"],
+                shared_ff=shared // deployment.get("tensor_parallel", 1),
+                scoring="sigmoid", routed_scale=c["routed_scaling_factor"],
+                router_std=1.0 / math.sqrt(d), renormalize=c["norm_topk_prob"],
+                latent=c.get("moe_latent_size") or 0, activation="relu2",
+            )
+            return LMBlock(**absent, moe=experts, norm2=ones(), spec=LayerSpec(0, 0))
+        raise ValueError(f"layer kind {kind!r} of {pattern!r}")
+
+    k_embed, k_head, *k_layers = jax.random.split(key, 2 + depth)
+    mtp = None
+    if c.get("num_nextn_predict_layers", 0):
+        if c["num_nextn_predict_layers"] != 1:
+            raise ValueError("multi-token prediction of depth 1 only")
+        kinds = c["mtp_hybrid_override_pattern"]
+        k_proj, *k_mtp = jax.random.split(jax.random.fold_in(key, 7919), 1 + len(kinds))
+        mtp = MTPModule(
+            enorm=ones(), hnorm=ones(),
+            eh_proj=init(k_proj, (2 * d, d), 2 * d),
+            blocks=tuple(layer(kind, k) for kind, k in zip(kinds, k_mtp)),
+            final_norm=ones(),
+            weight=float(c["mtp_loss_scaling_factor"]),
+        )
+    return TransformerLM(
+        embed=0.02 * jax.random.normal(k_embed, (vocab, d)),
+        pos_embed=jnp.zeros((0, d), jnp.float32),
+        blocks=tuple(layer(kind, k) for kind, k in zip(pattern, k_layers)),
+        head=init(k_head, (d, vocab), d),
+        final_norm=ones(),
+        mtp=mtp,
+        num_heads=heads,
+        mesh=mesh,
+        remat=remat,
+        compute_dtype=compute_dtype,
+        pos_encoding="nope",
+        num_kv_heads=0 if kvh == heads else kvh,
+        embed_scale=False,
+        norm_eps=eps,
+    )
+
+
+def mtp_depth(model: TransformerLM) -> int:
+    """How many positions beyond the next the model's loss reads: the
+    MTP module's depth, 0 without one."""
+    return 0 if model.mtp is None else 1
+
+
 def remat_wrap(fn, policy: str):
     """``jax.checkpoint`` under the model's remat policy (shared by the
     layer loop and the pipeline-parallel stage chain)."""
@@ -950,15 +1142,21 @@ def train_step_flops(model: TransformerLM, batch: int, seq: int) -> float:
     """Model FLOPs of one train step: six times the parameters a token
     touches (a routed layer's experts at ``top_k`` times the share held
     here, under even routing; the embedding table is a gather unless the
-    logits are tied to it), plus the causal score and value products
+    logits are tied to it; an MTP module's layers and projection, and
+    the head a second time), plus the causal score and value products
     (a window layer reckoned at its window, a compressed-latent layer at
-    its latent's width; a state-space block, whose ``wq`` is zero-width,
-    has none, and its scan's own FLOPs, under 2 % of such a step, are
-    left out). Recomputation not counted."""
+    its latent's width; a state-space block, whose ``wq`` is zero-width
+    or absent, has none, and its scan's own FLOPs, under 2 % of such a
+    step, are left out). Recomputation not counted."""
     tokens = batch * seq
     touched = 0.0
     attn = 0.0
-    for blk in model.blocks:
+    if model.mtp is not None:
+        touched += sum(
+            int(np.prod(l.shape))
+            for l in (model.mtp.enorm, model.mtp.hnorm, model.mtp.eh_proj)
+        )
+    for blk in model.blocks + model._mtp_blocks():
         n = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(blk))
         m = blk.moe
         if m is not None:
@@ -972,7 +1170,8 @@ def train_step_flops(model: TransformerLM, batch: int, seq: int) -> float:
         if spec.window:
             keys = min(keys, spec.window)
         wq = blk.wq if blk.cca is None else blk.cca.wq
-        attn += 12 * wq.shape[1] * keys * tokens
+        if wq is not None:
+            attn += 12 * wq.shape[1] * keys * tokens
     head = model.embed if model.head is None else model.head
-    touched += int(np.prod(head.shape))
+    touched += int(np.prod(head.shape)) * (1 + mtp_depth(model))
     return 6.0 * touched * tokens + attn

@@ -30,7 +30,9 @@ def shard_params(model: TransformerLM, mesh) -> TransformerLM:
     four projections, both convolutions and ``tau``; the attention in
     the latent is still split by head where ``model`` divides both head
     counts, as any local attention is), a carried router (``b.router``)
-    and a block's joining rows (``scale1``, ``scale2``).
+    and a block's joining rows (``scale1``, ``scale2``), and an MTP
+    module (``model.mtp``). A block of one part alone has None for the
+    absent part's weights, and None stays None.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -57,12 +59,12 @@ def shard_params(model: TransformerLM, mesh) -> TransformerLM:
     blocks = tuple(
         dataclasses.replace(
             b,
-            wq=put(b.wq, P(None, "model")),
-            wk=put(b.wk, P(None, "model")),
-            wv=put(b.wv, P(None, "model")),
-            wo=put(b.wo, P("model", None)),
-            w1=put(b.w1, P(None, "model")),
-            w2=put(b.w2, P("model", None)),
+            wq=opt(b.wq, P(None, "model")),
+            wk=opt(b.wk, P(None, "model")),
+            wv=opt(b.wv, P(None, "model")),
+            wo=opt(b.wo, P("model", None)),
+            w1=opt(b.w1, P(None, "model")),
+            w2=opt(b.w2, P("model", None)),
             w3=opt(b.w3, P(None, "model")),
             # routed experts stay whole on every device (their layer
             # shard_maps its tokens over `data`): the exchange that

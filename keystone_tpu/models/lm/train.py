@@ -28,6 +28,7 @@ from keystone_tpu.models.lm.model import (
     _embed,
     _tied_logits,
     has_quantized_leaves,
+    mtp_depth,
 )
 
 logger = get_logger("keystone_tpu.models.lm_transformer")
@@ -247,9 +248,11 @@ def _bind_step(optimizer, logit_chunk: int, skip_nonfinite: bool):
 
 
 def _step_batch(corpus, seed: int, i: int, batch: int, seq: int):
-    """Step ``i``'s token windows, derived from ``(seed, i)`` alone — no
-    sequential RNG state, so a resumed run regenerates the exact batch
-    sequence an uninterrupted run would have seen."""
+    """Step ``i``'s token windows of ``seq + 1`` ids, derived from
+    ``(seed, i)`` alone — no sequential RNG state, so a resumed run
+    regenerates the exact batch sequence an uninterrupted run would have
+    seen. A model whose loss reads further ahead asks for a longer
+    ``seq``."""
     rng = np.random.default_rng(np.random.SeedSequence((seed, i)))
     starts = rng.integers(0, len(corpus) - seq - 1, size=batch)
     return np.stack([corpus[s : s + seq + 1] for s in starts])
@@ -643,6 +646,8 @@ def train(
             chips=mesh.size if mesh is not None else 1,
             ssm_layers=ssm_layers(model),
             cca_layers=cca_layers(model),
+            mtp_depth=mtp_depth(model),
+            moe_latent=moe_latent(model),
         )
         if _spans.current() is None
         else _contextlib.nullcontext()
@@ -672,7 +677,10 @@ def train(
             t_step0 = _time.perf_counter()
             with _spans.span("train.step", step=i + 1) as s_ctx:
                 with _spans.span("fit.load", bucket="wait_host"):
-                    windows = _step_batch(corpus, seed, i, batch, seq)
+                    # an MTP module's targets lie one more position ahead
+                    windows = _step_batch(
+                        corpus, seed, i, batch, seq + mtp_depth(model)
+                    )
                 if history is not None:
                     seen.append(windows)
                 with _spans.span(
@@ -945,6 +953,19 @@ def cca_layers(model: TransformerLM) -> int:
     return sum(b.cca is not None for b in model.blocks)
 
 
+def moe_latent(model: TransformerLM) -> int:
+    """The width of the latent the model's routed experts live in, 0
+    where they read the full width."""
+    return max(
+        (
+            b.moe.latent_down.shape[1]
+            for b in model.blocks
+            if b.moe is not None and b.moe.latent_down is not None
+        ),
+        default=0,
+    )
+
+
 def _record_counters(model: TransformerLM, stats: list) -> None:
     """The expert, state-space and compressed-latent layers' counters of
     this fit as one zero-length ``fit.counters`` span under the open
@@ -974,6 +995,9 @@ def _record_counters(model: TransformerLM, stats: list) -> None:
         more["router_gate_mean"] = float(
             sum(float(c["gate_sum"]) for c in got) / max(sum(routed), 1)
         )
+    if "mtp_rows" in got[0]:
+        # positions the MTP module's loss covered
+        more["mtp_rows"] = sum(int(c["mtp_rows"]) for c in got)
     sl.record_span(
         "fit.counters",
         wall_s=0.0,

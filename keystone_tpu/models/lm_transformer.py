@@ -47,9 +47,11 @@ from keystone_tpu.models.lm.decode import _filter_logits  # noqa: F401
 from keystone_tpu.models.lm.model import (  # noqa: F401
     has_quantized_leaves as _has_quantized_leaves,
 )
+from keystone_tpu.models.lm.model import mtp_depth
 from keystone_tpu.models.lm.train import (  # noqa: F401
     _step_batch,
     cca_layers,
+    moe_latent,
     ssm_layers,
 )
 
@@ -203,6 +205,8 @@ def fit(conf: LMConfig, mesh=None, history: dict | None = None):
         # known when the span closes: the model is made inside it
         ssm_layers=lambda: found.get("ssm_layers", 0),
         cca_layers=lambda: found.get("cca_layers", 0),
+        mtp_depth=lambda: found.get("mtp_depth", 0),
+        moe_latent=lambda: found.get("moe_latent", 0),
     ):
         valid = None
         with span("fit.init"):
@@ -217,6 +221,8 @@ def fit(conf: LMConfig, mesh=None, history: dict | None = None):
             model = build_model(conf, mesh)
             found["ssm_layers"] = ssm_layers(model)
             found["cca_layers"] = cca_layers(model)
+            found["mtp_depth"] = mtp_depth(model)
+            found["moe_latent"] = moe_latent(model)
             if not conf.corpus:
                 corpus = synthetic_corpus(
                     200_000, model.embed.shape[0], seed=conf.seed

@@ -877,9 +877,9 @@ def _render_node(node: dict, depth: int, lines: list[str]) -> None:
     # what an LM fit says of its layers and its step's counters, where
     # the model has such layers
     for key in (
-        "ssm_layers", "cca_layers", "routed_rows", "mm_rows", "dispatch_rows",
-        "extra_windows", "load_max_over_mean", "router_gate_mean", "ssm_rows",
-        "cca_rows",
+        "ssm_layers", "cca_layers", "mtp_depth", "moe_latent", "routed_rows",
+        "mm_rows", "dispatch_rows", "extra_windows", "load_max_over_mean",
+        "router_gate_mean", "ssm_rows", "cca_rows", "mtp_rows",
     ):
         # no extra window is worth saying where the layers dispatched
         if rec.get(key) or (key == "extra_windows" and rec.get("dispatch_rows")):

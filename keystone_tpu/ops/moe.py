@@ -39,7 +39,13 @@ in place of its dense FFN:
   ``custom_vjp`` whose forward and backward each run a ``while_loop``
   over as many windows as the rows routed here need: no row is dropped,
   and no ``tokens x top_k`` array of width ``dim`` is made);
-- an optional shared expert runs on every token beside the routed ones.
+- an optional shared expert runs on every token beside the routed ones;
+- the experts may live in a latent (``latent_down`` / ``latent_up``):
+  the router reads the full-width row, the routed rows go down to the
+  latent width, through the experts and, weighted and summed, back up;
+  the shared expert stays at full width;
+- an expert is SwiGLU (three matrices), GELU or, with ``activation``
+  ``"relu2"``, ``relu(h w1)^2 w2`` (two matrices).
 
 ``__call__`` also returns five counters of the step program: the rows
 routed to held experts, the largest load of a held expert, the rows the
@@ -73,14 +79,18 @@ WINDOW_C = 1.375
 _TM = 512  # the grouped product's largest row tile
 
 
-def ffn(y, w1, w2, w3, cdt, mm_fn=None):
+def ffn(y, w1, w2, w3, cdt, mm_fn=None, activation: str = ""):
     """One feed-forward: SwiGLU ``(silu(y w1) * (y w3)) w2`` when ``w3``
-    is there, else ``gelu(y w1) w2``. Shared by the block's dense FFN
-    and the shared expert."""
+    is there, else ``gelu(y w1) w2``, or ``relu(y w1)^2 w2`` under
+    ``activation="relu2"``. Shared by the block's dense FFN and the
+    shared expert."""
     if mm_fn is None:
         from keystone_tpu.ops.quantization import mm as mm_fn
     h = mm_fn(y, w1, cdt)
-    h = jax.nn.gelu(h) if w3 is None else jax.nn.silu(h) * mm_fn(y, w3, cdt)
+    if w3 is None:
+        h = _act(h, None, activation)
+    else:
+        h = jax.nn.silu(h) * mm_fn(y, w3, cdt)
     return mm_fn(h, w2, cdt)
 
 
@@ -139,11 +149,18 @@ def window_rows(rows: int, held: int, num_experts: int) -> int:
     return w if w <= rows // 2 else 0
 
 
+def _tile(n: int, most: int = 1024) -> int:
+    """The largest multiple of 128 up to ``most`` that divides ``n`` (896
+    for an expert of 2688), else ``min(n, most)``: the kernel masks a
+    last partial tile, but a whole one wastes nothing."""
+    return next((t for t in range(most, 0, -128) if n % t == 0), min(n, most))
+
+
 def _tiling(w, tm: int):
     """The grouped product's tiles for a weight stack (held, K, N), the
     same for its transposed product and its weight gradient, as
     ``megablox``'s own backward has them."""
-    return (tm, min(w.shape[1], 1024), min(w.shape[2], 1024))
+    return (tm, _tile(w.shape[1]), _tile(w.shape[2]))
 
 
 def grouped_mm(xs, w, group_sizes, first: int, tm: int):
@@ -198,7 +215,9 @@ def _tgmm(xs, g, w, sizes):
         ).astype(jnp.float32)
 
 
-def _act(h1, h3):
+def _act(h1, h3, activation: str = ""):
+    if activation == "relu2":
+        return jnp.square(jax.nn.relu(h1))
     return jax.nn.gelu(h1) if h3 is None else jax.nn.silu(h1) * h3
 
 
@@ -220,7 +239,8 @@ def _num_windows(starts, ends, rows: int):
     return (ends[-1] - starts[0] + rows - 1) // rows
 
 
-def _forward(xf, weights, w1, w3, w2, order, starts, ends, k: int, w: int):
+def _forward(xf, weights, w1, w3, w2, order, starts, ends, k: int, w: int,
+             activation: str = ""):
     """The held experts' weighted sum (T, d) f32 over every window the
     rows routed here need."""
 
@@ -228,7 +248,7 @@ def _forward(xf, weights, w1, w3, w2, order, starts, ends, k: int, w: int):
         _rows, tok, wts, sizes, _valid = _window(i, weights, order, starts, ends, k, w)
         xs = xf[tok]
         h3 = None if w3 is None else _gmm(xs, w3, sizes)
-        a = _act(_gmm(xs, w1, sizes), h3)
+        a = _act(_gmm(xs, w1, sizes), h3, activation)
         y = _gmm(a, w2, sizes).astype(jnp.float32)
         wts = wts.astype(xf.dtype).astype(jnp.float32)
         return out.at[tok].add(wts[:, None] * y)
@@ -238,23 +258,24 @@ def _forward(xf, weights, w1, w3, w2, order, starts, ends, k: int, w: int):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _expert_windows(xf, weights, w1, w3, w2, order, starts, ends, k, w):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _expert_windows(xf, weights, w1, w3, w2, order, starts, ends, k, w, activation=""):
     """xf (T, d), weights (T, k) f32, the held experts' stacks in the
     compute dtype, ``order`` the sorted assignments padded by a window,
     ``starts`` / ``ends`` the held experts' first and past-last sorted
-    rows, ``k`` assignments a token, ``w`` rows a window: (T, d) f32.
-    The backward runs the windows again and makes each window's hidden
-    rows anew rather than keeping them."""
-    return _forward(xf, weights, w1, w3, w2, order, starts, ends, k, w)
+    rows, ``k`` assignments a token, ``w`` rows a window, the experts'
+    ``activation``: (T, d) f32. The backward runs the windows again and
+    makes each window's hidden rows anew rather than keeping them."""
+    return _forward(xf, weights, w1, w3, w2, order, starts, ends, k, w, activation)
 
 
-def _expert_windows_fwd(xf, weights, w1, w3, w2, order, starts, ends, k, w):
+def _expert_windows_fwd(xf, weights, w1, w3, w2, order, starts, ends, k, w,
+                        activation=""):
     res = (xf, weights, w1, w3, w2, order, starts, ends)
-    return _forward(*res, k, w), res
+    return _forward(*res, k, w, activation), res
 
 
-def _expert_windows_bwd(k, w, res, g):
+def _expert_windows_bwd(k, w, activation, res, g):
     xf, weights, w1, w3, w2, order, starts, ends = res
     f32 = jnp.float32
 
@@ -263,7 +284,9 @@ def _expert_windows_bwd(k, w, res, g):
         rows, tok, wts, sizes, valid = _window(i, weights, order, starts, ends, k, w)
         xs = xf[tok]
         h3 = None if w3 is None else _gmm(xs, w3, sizes)
-        a, act_vjp = jax.vjp(_act, _gmm(xs, w1, sizes), h3)
+        a, act_vjp = jax.vjp(
+            functools.partial(_act, activation=activation), _gmm(xs, w1, sizes), h3
+        )
         y = _gmm(a, w2, sizes).astype(f32)
         gt = g[tok]
         dwts = dwts.at[rows].add(jnp.where(valid, jnp.sum(gt * y, axis=-1), 0.0))
@@ -320,6 +343,11 @@ class MoELayer:
     shared_w1: jnp.ndarray | None = None
     shared_w2: jnp.ndarray | None = None
     shared_w3: jnp.ndarray | None = None
+    # the latent the routed rows pass through: (d, L) down, (L, d) up;
+    # the expert stacks are then (held, L, ff) and (held, ff, L). None =
+    # the experts read and write the full width
+    latent_down: jnp.ndarray | None = None
+    latent_up: jnp.ndarray | None = None
     top_k: int = static_field(default=2)
     first_expert: int = static_field(default=0)
     # "softmax" over all experts, or "sigmoid" of each score (the
@@ -329,6 +357,9 @@ class MoELayer:
     # the top_k kept are renormalised to sum to one, or stay the chosen
     # probabilities themselves
     renormalize: bool = static_field(default=True)
+    # "" = SwiGLU where w3 is held, else GELU; "relu2" = relu(h w1)^2 w2,
+    # the routed experts and the shared expert alike
+    activation: str = static_field(default="")
 
     @property
     def num_experts(self) -> int:
@@ -343,7 +374,12 @@ class MoELayer:
                held: int | None = None, first_expert: int = 0,
                top_k: int = 2, swiglu: bool = False, shared_ff: int = 0,
                scoring: str = "softmax", routed_scale: float = 1.0,
-               router_std: float = 0.02, renormalize: bool = True) -> "MoELayer":
+               router_std: float = 0.02, renormalize: bool = True,
+               latent: int = 0, activation: str = "") -> "MoELayer":
+        """Seeded weights: matrices normal at 1/sqrt(fan_in), the router
+        at ``router_std``. ``latent > 0`` puts the routed experts in a
+        latent of that width; ``activation="relu2"`` makes every expert
+        two matrices."""
         held = num_experts if held is None else held
         if not 0 <= first_expert <= num_experts - held:
             raise ValueError(
@@ -352,16 +388,19 @@ class MoELayer:
             )
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring={scoring!r}; expected softmax|sigmoid")
+        if activation not in ("", "relu2") or (activation and swiglu):
+            raise ValueError(f"activation={activation!r} with swiglu={swiglu}")
         ks = jax.random.split(key, 7)
+        width = latent or dim
 
         def init(k, shape, fan_in):
             return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
 
         return MoELayer(
             w_router=router_std * jax.random.normal(ks[0], (dim, num_experts)),
-            w1=init(ks[1], (held, dim, ff), dim),
-            w2=init(ks[2], (held, ff, dim), ff),
-            w3=init(ks[3], (held, dim, ff), dim) if swiglu else None,
+            w1=init(ks[1], (held, width, ff), width),
+            w2=init(ks[2], (held, ff, width), ff),
+            w3=init(ks[3], (held, width, ff), width) if swiglu else None,
             shared_w1=init(ks[4], (dim, shared_ff), dim) if shared_ff else None,
             shared_w2=init(ks[5], (shared_ff, dim), shared_ff)
             if shared_ff
@@ -369,11 +408,18 @@ class MoELayer:
             shared_w3=init(ks[6], (dim, shared_ff), dim)
             if shared_ff and swiglu
             else None,
+            latent_down=init(jax.random.fold_in(key, 7), (dim, latent), dim)
+            if latent
+            else None,
+            latent_up=init(jax.random.fold_in(key, 8), (latent, dim), latent)
+            if latent
+            else None,
             top_k=top_k,
             first_expert=first_expert,
             scoring=scoring,
             routed_scale=routed_scale,
             renormalize=renormalize,
+            activation=activation,
         )
 
     def route(self, xf, scores=()):
@@ -433,7 +479,8 @@ class MoELayer:
         if self.shared_w1 is not None:
             with jax.named_scope("moe_shared_expert"):
                 out = out + ffn(
-                    x, self.shared_w1, self.shared_w2, self.shared_w3, x.dtype
+                    x, self.shared_w1, self.shared_w2, self.shared_w3, x.dtype,
+                    activation=self.activation,
                 )
         return out, counters
 
@@ -459,6 +506,11 @@ class MoELayer:
                 )
             group_sizes = jnp.zeros(self.num_experts, jnp.int32).at[flat].add(1)
         first = self.first_expert
+        if self.latent_down is not None:
+            # the router has read the full width; the rows go down to
+            # the latent the experts live in
+            with jax.named_scope("moe_latent_down"):
+                xf = jnp.matmul(xf, self.latent_down.astype(cdt))
         if window:
             held = jax.lax.dynamic_slice_in_dim(group_sizes, first, self.held)
             end = jax.lax.dynamic_slice_in_dim(jnp.cumsum(group_sizes), first, self.held)
@@ -468,6 +520,7 @@ class MoELayer:
                 out = _expert_windows(
                     xf, weights, self.w1.astype(cdt), w3, self.w2.astype(cdt),
                     jnp.pad(order, (0, window)), start, end, k, window,
+                    self.activation,
                 ).astype(cdt)
             dispatch_rows, extra_windows, mm_rows = _window_counters(
                 start, end, window, t * k
@@ -477,6 +530,9 @@ class MoELayer:
                 xf, weights, order, inv, group_sizes, cdt
             )
             dispatch_rows, extra_windows = jnp.int32(t * k), jnp.int32(0)
+        if self.latent_up is not None:
+            with jax.named_scope("moe_latent_up"):
+                out = jnp.matmul(out, self.latent_up.astype(cdt))
         if axis is not None:
             held, mm_rows, dispatch_rows, extra_windows = jax.lax.psum(
                 (held, mm_rows, dispatch_rows, extra_windows), axis
@@ -513,7 +569,7 @@ class MoELayer:
             xs = jnp.pad(_dispatch(xf, order, inv, k), ((0, pad), (0, 0)))
             h = grouped_mm(xs, self.w1.astype(cdt), group_sizes, first, tm)
             if self.w3 is None:
-                h = jax.nn.gelu(h)
+                h = _act(h, None, self.activation)
             else:
                 h = jax.nn.silu(h) * grouped_mm(
                     xs, self.w3.astype(cdt), group_sizes, first, tm

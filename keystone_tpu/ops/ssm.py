@@ -21,8 +21,9 @@ whose state is a recurrence has three parts, each here once:
   every chunk's gradients come from ``jax.vjp`` of the chunk's own sums, a
   group of heads at a time so that the ``chunk x chunk`` blocks alive at
   once stay a few hundred MB;
-- :func:`gated_rms_norm`: ``w * rmsnorm(y * silu(z))`` over the whole
-  inner width (the gate before the norm).
+- :func:`gated_rms_norm`: ``w * rmsnorm(y * silu(z))`` over each of
+  the mixer's groups of ``inner / groups`` channels (the gate before the
+  norm; one group is the whole inner width).
 
 :class:`Mamba2Mixer` holds the weights and strings the parts together
 under ``jax.named_scope``s ``ssm_in_proj``, ``ssm_conv``, ``ssm_scan``
@@ -80,12 +81,15 @@ def step_size(dt, bias):
     return jax.nn.softplus(dt.astype(jnp.float32) + bias.astype(jnp.float32))
 
 
-def gated_rms_norm(y, z, scale, eps: float):
-    """``scale * rmsnorm(y * silu(z))`` over the last axis, statistics in
-    float32, in ``y``'s dtype."""
+def gated_rms_norm(y, z, scale, eps: float, groups: int = 1):
+    """``scale * rmsnorm(y * silu(z))`` over each of ``groups`` equal
+    parts of the last axis on its own (one group: the whole axis),
+    statistics in float32, in ``y``'s dtype."""
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    if groups > 1:
+        g = g.reshape(*g.shape[:-1], groups, g.shape[-1] // groups)
     g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
-    return (g * scale.astype(jnp.float32)).astype(y.dtype)
+    return (g.reshape(y.shape) * scale.astype(jnp.float32)).astype(y.dtype)
 
 
 # ------------------------------------------------------------ the chunk's sums
@@ -492,7 +496,11 @@ class Mamba2Mixer:
             out = scan(x, dt, a, b, c)
             out = out.astype(f32) + self.D.astype(f32)[:, None] * x.astype(f32)
         with jax.named_scope("ssm_gate_norm"):
-            out = gated_rms_norm(out.reshape(n, s, inner).astype(cdt), z, self.norm, self.eps)
+            # one group is the whole width: the norm called as it always was
+            groups = (self.groups,) if self.groups > 1 else ()
+            out = gated_rms_norm(
+                out.reshape(n, s, inner).astype(cdt), z, self.norm, self.eps, *groups
+            )
         with jax.named_scope("ssm_out_proj"):
             out = mm_fn(out, self.w_out, cdt)
         n_l = min(self.chunk, s)

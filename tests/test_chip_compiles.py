@@ -193,3 +193,54 @@ def test_the_scan_kernel_compiles_at_the_state_space_cells_shapes(topo, mosaic, 
     mixer = jax.tree.map(lambda l: shape(l.shape, l.dtype), mixer)
     layer = jax.jit(lambda m, y: m(y)).lower(mixer, shape((1, 8192, 2048), jnp.bfloat16))
     assert not moved(ops_of(layer.compile().as_text()), r"\w+\[(1,)?(8192|64),")
+
+
+def test_the_grouped_mixer_and_the_latent_experts_compile_at_their_cells_shapes(
+    topo, mosaic, monkeypatch
+):
+    """``jax.grad`` of ``nemotron_3_super``'s two new parts at the cell's
+    shapes (2 x 8192 positions of width 4096, bfloat16): a Mamba-2 mixer
+    of 32 heads of 64 in 2 groups, state 128, chunks of 128, whose scan
+    runs in ``ssd_chunk`` (16 heads a group: a head block of 1024 lanes);
+    and an expert layer of 8 of 512 relu² experts of 2688 in a latent of
+    1024, 22 a token, on the window path: two grouped products a pass
+    (two ``gmm`` forward, four backward, two ``tgmm``), no third."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+    from keystone_tpu.ops import moe, ssm
+    from keystone_tpu.plan.costs import device_peaks
+
+    limit = device_peaks(topo.devices[0].device_kind).vmem_limit
+    monkeypatch.setattr(ssm, "interpret_default", lambda: False)
+    monkeypatch.setattr(ssm, "_vmem_limit_bytes", lambda: limit)
+    monkeypatch.setattr(ssm, "on_tpu", lambda: True)
+    assert ssm._use_kernel(128, 16, 64)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), tree)
+
+    y = jax.ShapeDtypeStruct((2, 8192, 4096), jnp.bfloat16, sharding=one_chip)
+    mixer = placed(jax.eval_shape(lambda: ssm.Mamba2Mixer.create(
+        jax.random.key(0), 4096, heads=32, head_dim=64, state=128, groups=2, chunk=128)))
+    experts = placed(jax.eval_shape(lambda: moe.MoELayer.create(
+        jax.random.key(0), 4096, 2688, 512, held=8, top_k=22, shared_ff=1344,
+        scoring="sigmoid", routed_scale=5.0, latent=1024, activation="relu2")))
+    assert moe.window_rows(16384 * 22, 8, 512) == 8192
+
+    def loss(m, y):
+        out, _counters = m(y)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    def mosaic_calls(text):
+        return re.findall(r"^\s*%(\S+) = .*custom-call\(.*tpu_custom_call", text, re.M)
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    calls = mosaic_calls(grad.lower(mixer, y).compile().as_text())
+    assert len(calls) == 1 and "ssd_chunk" in calls[0], calls
+    compiled = grad.lower(experts, y).compile()
+    calls = [re.sub(r"\.\d+$", "", c) for c in mosaic_calls(compiled.as_text())]
+    assert sorted(calls) == ["gmm"] * 6 + ["tgmm"] * 2, calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
