@@ -26,8 +26,8 @@ from keystone_tpu.models.lm.model import (
     _block_apply,
     _embed,
     _gather_embed,
-    _tied_logits,
     model_mm,
+    output_logits,
 )
 from keystone_tpu.ops.quantization import quantize_int8
 
@@ -112,14 +112,14 @@ def prefill(model: TransformerLM, tokens, s_max: int,
         ks.append(k)
         vs.append(v)
     if lengths is None:
-        logits = _tied_logits(x[:, -1:], model.embed, cdt)[:, 0]
+        logits = output_logits(model, x[:, -1:], cdt)[:, 0]
         pos = jnp.asarray(s, jnp.int32)
     else:
         lengths = jnp.asarray(lengths, jnp.int32)
         last = jnp.take_along_axis(
             x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1
         )  # (B, 1, d) — each row's own final real token
-        logits = _tied_logits(last, model.embed, cdt)[:, 0]
+        logits = output_logits(model, last, cdt)[:, 0]
         pos = lengths
     pad = [(0, 0), (0, 0), (0, s_max - s), (0, 0)]
     k_stack = jnp.stack([jnp.pad(k, pad) for k in ks])
@@ -239,7 +239,7 @@ def decode_step(model: TransformerLM, token, cache: KVCache):
     mm_fn = model_mm(model)
     for i, blk in enumerate(model.blocks):
         x, _, _, _ = _block_apply(x, blk, cdt, cached_attn(i), mm_fn=mm_fn)
-    logits = _tied_logits(x, model.embed, cdt)[:, 0]
+    logits = output_logits(model, x, cdt)[:, 0]
     # past-capacity poison: at pos >= S_max the cache write would clamp
     # onto S_max-1 and return plausible-but-wrong logits; pos is traced,
     # so the honest device-side failure is loud NaNs, not an exception

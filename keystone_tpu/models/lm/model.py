@@ -294,18 +294,40 @@ def _embed(model, tokens, cdt):
     return x.astype(cdt)
 
 
-def _tied_logits(x, embed, cdt):
-    # bf16 operands, f32 accumulate/output: the logits feed a logsumexp —
-    # bf16 logits would cost real perplexity precision
-    if isinstance(embed, QTensor):
-        # (V, 1) row scales become per-output-channel under the transpose
-        return jnp.matmul(
-            _ln(x, cdt), embed.q.T.astype(cdt),
-            preferred_element_type=jnp.float32,
-        ) * embed.scale[:, 0]
-    return jnp.matmul(
-        _ln(x, cdt), embed.T.astype(cdt), preferred_element_type=jnp.float32
-    )
+def final_rows(model, x, cdt):
+    """The rows the output head reads: ``x`` through the learned final
+    RMSNorm where the model has one, else the parameter-free LayerNorm,
+    in the compute dtype."""
+    return _norm(x, model.final_norm, model.norm_eps, cdt)
+
+
+def head_matrix(model):
+    """The output head (d, V): the model's own ``head``, else the
+    embedding transposed (tied). An int8 embedding stays a QTensor (V, d)."""
+    if model.head is None:
+        return model.embed if isinstance(model.embed, QTensor) else model.embed.T
+    return model.head
+
+
+def scaled_product(xn, w, scale: float, cdt):
+    """``xn @ w`` with operands in ``cdt`` and an f32 result, times
+    ``scale``: the head's one product, for a float head (d, V)."""
+    logits = jnp.matmul(xn, w.astype(cdt), preferred_element_type=jnp.float32)
+    return logits if scale == 1.0 else logits * scale
+
+
+def head_logits(model, xn, cdt):
+    """The output head on the final norm's rows, times ``logits_scale``.
+    bf16 operands, f32 accumulate/output: the logits feed a logsumexp —
+    bf16 logits would cost real perplexity precision."""
+    w = head_matrix(model)
+    if not isinstance(w, QTensor):
+        return scaled_product(xn, w, model.logits_scale, cdt)
+    # (V, 1) row scales become per-output-channel under the transpose
+    logits = jnp.matmul(
+        xn, w.q.T.astype(cdt), preferred_element_type=jnp.float32
+    ) * w.scale[:, 0]
+    return logits if model.logits_scale == 1.0 else logits * model.logits_scale
 
 
 def output_logits(model, x, cdt):
@@ -313,12 +335,7 @@ def output_logits(model, x, cdt):
     when it has one, else the embedding transposed (tied: behind the
     learned final norm where the model has one, else behind the
     parameter-free LayerNorm), times ``logits_scale``. f32 out."""
-    if model.head is None and model.final_norm is None:
-        return _tied_logits(x, model.embed, cdt)
-    xn = _norm(x, model.final_norm, model.norm_eps, cdt)
-    head = model.embed.T if model.head is None else model.head
-    logits = jnp.matmul(xn, head.astype(cdt), preferred_element_type=jnp.float32)
-    return logits if model.logits_scale == 1.0 else logits * model.logits_scale
+    return head_logits(model, final_rows(model, x, cdt), cdt)
 
 
 @treenode

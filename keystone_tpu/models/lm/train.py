@@ -26,9 +26,9 @@ from keystone_tpu.models.lm.model import (
     TransformerLM,
     _block_apply,
     _embed,
-    _tied_logits,
     has_quantized_leaves,
     mtp_depth,
+    output_logits,
 )
 
 logger = get_logger("keystone_tpu.models.lm_transformer")
@@ -102,7 +102,7 @@ def pp_forward(model: TransformerLM, tokens, mesh, *, n_micro: int,
 
     out = gpipe(stage_fn, stacked, x, mesh, axis=axis, data_axis=data_axis)
     out = out.reshape(b, *out.shape[2:])
-    return _tied_logits(out, model.embed, cdt)
+    return output_logits(model, out, cdt)
 
 
 def next_token_loss_pp(model: TransformerLM, tokens, mesh, *,
